@@ -44,6 +44,7 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..kernels.engine import ppac_matmul
 from ..obs import ledger as _flight
@@ -143,6 +144,12 @@ def qat_dense(x, w, *, weight_bits: int, act_bits: int,
     return jnp.einsum("...i,io->...o", xq, wq).astype(x.dtype)
 
 
+def _row_major(tree):
+    """Pin every array of ``tree`` to the row-major layout."""
+    return jax.tree.map(lambda a: with_layout_constraint(
+        a, Layout(tuple(range(a.ndim)))), tree)
+
+
 def _want_shadow(store_shadow: Optional[bool]) -> bool:
     """Shadow policy: explicit wins; default stores the int8 resident only
     off-TPU (on TPU the packed planes are what the kernels eat)."""
@@ -238,25 +245,33 @@ def serve_dense_acc(xf, container: QuantContainer, *, act_bits: int,
     """
     kind = container.kind
     n = xf.shape[-1]
+
+    def resident_mvp(xq, xs, planes, **kw):
+        # The integer region is fenced: optimization barriers stop fusion
+        # across it and row-major layout constraints stop layout choices
+        # from leaking out of it. XLA otherwise fuses and lays out the
+        # float program around a jnp oracle differently than around a
+        # kernel call, and on TPU a float reduction over another layout
+        # rounds differently. Fenced, the float program compiles the same
+        # on every backend, so served tokens stay bit-identical.
+        xi, xs = _row_major(jax.lax.optimization_barrier(
+            _row_major((xq.astype(jnp.int32), xs))))
+        acc = ppac_matmul(xi, planes, mode="mvp_multibit_resident", n=n,
+                          a_int8=container.shadow, backend=backend, **kw)
+        return _row_major(jax.lax.optimization_barrier(_row_major(acc))), xs
+
     if kind == "packed1":
         xq, xs = binarize_pm1(xf, axis=-1)          # {±1} activations
         # ±1 ≡ oddint(1): the packed1 plane serves through the same fused
         # resident kernel as packed4, with a 1x1 plane-pair schedule
-        acc = ppac_matmul(xq.astype(jnp.int32), container.wq[None],
-                          mode="mvp_multibit_resident", n=n, k_bits=1,
-                          l_bits=1, fmt_a="oddint", fmt_x="oddint",
-                          a_int8=container.shadow, backend=backend)
-        return acc, xs
+        return resident_mvp(xq, xs, container.wq[None], k_bits=1, l_bits=1,
+                            fmt_a="oddint", fmt_x="oddint")
     xq, xs = quantize(xf, act_bits, act_format, axis=-1)
     if kind == "packed4":
         a_has_mask = container.wq.shape[-3] == (container.bits or 0) + 1
-        acc = ppac_matmul(xq.astype(jnp.int32), container.wq,
-                          mode="mvp_multibit_resident", n=n,
-                          k_bits=container.bits, l_bits=act_bits,
-                          fmt_a=container.fmt, fmt_x=act_format,
-                          a_has_mask=a_has_mask, a_int8=container.shadow,
-                          backend=backend)
-        return acc, xs
+        return resident_mvp(xq, xs, container.wq, k_bits=container.bits,
+                            l_bits=act_bits, fmt_a=container.fmt,
+                            fmt_x=act_format, a_has_mask=a_has_mask)
     if kind == "int8":
         if _flight.active():
             # the int8 MXU fallback bypasses ppac_matmul; record it at its

@@ -345,7 +345,9 @@ class BitFlipDecoder:
                 y, self._h_packed, self._ht_packed, self._gamma,
                 n=code.n, n_chk=code.n_chk, max_iters=self.max_iters,
                 backend=self.backend, mesh=mesh, axis=shard_axis)
-            c, ok, iters = c[:b], ok[:b], iters[:b]
+            # the outputs are sharded over the mesh: crop the tail padding
+            # on the host
+            c, ok, iters = (np.asarray(t)[:b] for t in (c, ok, iters))
 
         ok = np.asarray(ok)
         iters = np.asarray(iters, np.int32)

@@ -3,8 +3,8 @@
 engine        — the unified dispatch surface: ``ppac_matmul(x, a, mode=...)``
                 over a registry of every Table-I operation mode, with
                 bit-identical 'pallas' / 'ref' / 'mxu' backends
-tiling        — shared machinery: pad-to-tile planning, lane-tile
-                streaming, ``row_chunk`` subrow chunking
+tiling        — shared machinery: pad-to-tile planning on the TPU block
+                rule, lane-tile streaming, the per-row popcount loop
 binary_mvp    — packed 1-bit XNOR/AND popcount matmul (modes III-A/B/D/E)
 bitserial_mvp — fused multi-bitplane MVP (mode III-C, all Table-I formats;
                 ``ppac_matmul_planes`` serves pre-packed resident weights,

@@ -15,10 +15,10 @@ From S the wrapper derives all 1-bit modes:
     GF(2)          : S_and & 1
     inner product  : 2*h̄ - N  (eq. 1)
 
-Tiling, padding, lane streaming and the ``row_chunk`` subrow chunking all
-come from :mod:`repro.kernels.tiling` — the kernel body here is just the
-per-tile accumulation of the chunked popcount sum, so arbitrarily large
-B/M/W stream through fixed VMEM tiles.
+Tiling, padding and lane streaming come from :mod:`repro.kernels.tiling`;
+the kernel body walks the tile's streamed rows one at a time (one PPAC
+array cycle each), so arbitrarily large B/M/W stream through fixed VMEM
+tiles.
 """
 from __future__ import annotations
 
@@ -28,10 +28,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..tiling import lane_stream_call, plan_tiles, subrow_popcount_sum
+from ..tiling import for_each_row, lane_stream_call, plan_tiles, popcount_row
 
 
-def _binary_matmul_kernel(x_ref, a_ref, o_ref, *, op: str, row_chunk: int):
+def _binary_matmul_kernel(x_ref, a_ref, o_ref, *, op: str):
     """x_ref: [tb, tw] uint32; a_ref: [tm, tw] uint32; o_ref: [tb, tm] int32."""
 
     @pl.when(pl.program_id(2) == 0)
@@ -39,13 +39,16 @@ def _binary_matmul_kernel(x_ref, a_ref, o_ref, *, op: str, row_chunk: int):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     bit_op = jnp.bitwise_xor if op == "xor" else jnp.bitwise_and
-    o_ref[...] += subrow_popcount_sum(x_ref[...], a_ref[...], bit_op=bit_op,
-                                      row_chunk=row_chunk)
+
+    def row(r):
+        o_ref[r, :] += popcount_row(bit_op(x_ref[r, :], a_ref[...]))
+
+    for_each_row(x_ref.shape[0], row)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("op", "block_b", "block_m", "block_w", "row_chunk", "interpret"),
+    static_argnames=("op", "block_b", "block_m", "block_w", "interpret"),
 )
 def binary_matmul_packed(
     x_packed,
@@ -53,9 +56,8 @@ def binary_matmul_packed(
     *,
     op: str = "xor",
     block_b: int = 64,
-    block_m: int = 128,
-    block_w: int = 64,
-    row_chunk: int = 8,
+    block_m: int = 256,
+    block_w: int = 256,
     interpret: bool = False,
 ):
     """S[b,m] = sum_w popcount(op(x[b,w], a[m,w])).
@@ -71,7 +73,7 @@ def binary_matmul_packed(
     assert w == w2, (w, w2)
 
     plan = plan_tiles(b, m, w, block_b=block_b, block_m=block_m,
-                      block_w=block_w, row_chunk=row_chunk)
+                      block_w=block_w)
     return lane_stream_call(
-        functools.partial(_binary_matmul_kernel, op=op, row_chunk=plan.rc),
+        functools.partial(_binary_matmul_kernel, op=op),
         x_packed, a_packed, plan, interpret=interpret)

@@ -32,10 +32,11 @@ the per-plane packed words are built inside the kernel body with one
 shift/AND per plane — no ``to_bitplanes``/``pack_bits`` XLA round trip
 around the launch.
 
-Tiling, padding, lane streaming and the ``row_chunk`` subrow chunking all
-come from :mod:`repro.kernels.tiling`: the plane stacks ride along as
-leading block dims (whole stack resident per tile), so arbitrarily large
-B/M/W stream through fixed VMEM tiles exactly like the 1-bit kernels.
+Tiling, padding and lane streaming come from :mod:`repro.kernels.tiling`:
+the plane stacks ride along as leading block dims (whole stack resident
+per tile), and the body walks the tile's streamed rows one at a time, so
+arbitrarily large B/M/W stream through fixed VMEM tiles exactly like the
+1-bit kernels. The plane-pair weights sit in scalar memory.
 """
 from __future__ import annotations
 
@@ -46,21 +47,28 @@ import jax.numpy as jnp
 
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..tiling import lane_stream_call, plan_for, subrow_popcount_sum
+from ..tiling import (
+    for_each_row,
+    lane_stream_call,
+    plan_for,
+    popcount_row,
+    smem_spec,
+)
 
 
-def _lane_popcount_rows(tile):
-    """[rows, tw] uint32 -> [rows] int32 total set bits of this lane tile."""
-    return jnp.sum(lax.population_count(tile).astype(jnp.int32), axis=-1)
+def _lane_popcounts(tile):
+    """[rows, tw] uint32 -> [rows, 1] int32 total set bits of this lane tile."""
+    return jnp.sum(lax.population_count(tile).astype(jnp.int32), axis=-1,
+                   keepdims=True)
 
 
-def _accumulate_bitserial(x_of, a_ref, w_ref, o_ref, *, k1: int, l1: int,
-                          row_chunk: int, pop_a: bool, pop_x: bool,
-                          const: bool):
-    """Shared body: x plane ``l`` is ``x_of(l)`` [tb, tw]; a_ref holds the
-    resident [k1, tm, tw] plane stack; w_ref is the extended [k1+1, l1+1]
-    weight matrix (see module docstring)."""
+def _accumulate_bitserial(x_ref, a_ref, w_ref, o_ref, *, k1: int, l1: int,
+                          pop_a: bool, pop_x: bool, const: bool):
+    """Shared body: x_ref holds the [l1, tb, tw] packed activation planes;
+    a_ref the resident [k1, tm, tw] plane stack; w_ref (scalar memory) the
+    extended [k1+1, l1+1] weight matrix (see module docstring)."""
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
@@ -70,50 +78,48 @@ def _accumulate_bitserial(x_of, a_ref, w_ref, o_ref, *, k1: int, l1: int,
         else:
             o_ref[...] = jnp.zeros_like(o_ref)
 
-    tb = x_of(0).shape[0]
-    tm = a_ref.shape[1]
-    acc = jnp.zeros((tb, tm), jnp.int32)
-    for k in range(k1):          # static unroll: K1*L1 <= ~36 "cycles"
-        a_k = a_ref[k]           # [tm, tw]
-        if pop_a:
-            acc = acc + w_ref[k, l1] * _lane_popcount_rows(a_k)[None, :]
-        for l in range(l1):
-            s_kl = subrow_popcount_sum(x_of(l), a_k,
-                                       bit_op=jnp.bitwise_and,
-                                       row_chunk=row_chunk)
-            acc = acc + w_ref[k, l] * s_kl
-    if pop_x:
-        for l in range(l1):
-            acc = acc + w_ref[k1, l] * _lane_popcount_rows(x_of(l))[:, None]
-    o_ref[...] += acc
+    if pop_a:  # a-plane popcounts: the same [1, tm] row for every x row
+        o_ref[...] += sum(w_ref[k, l1] * popcount_row(a_ref[k])
+                          for k in range(k1))
+    if pop_x:  # x-plane popcounts: the same [tb, 1] column for every a row
+        o_ref[...] += sum(w_ref[k1, l] * _lane_popcounts(x_ref[l])
+                          for l in range(l1))
+
+    def row(r):
+        acc = jnp.zeros((1, o_ref.shape[1]), jnp.int32)
+        for k in range(k1):      # static unroll: K1*L1 <= ~36 "cycles"
+            a_k = a_ref[k]
+            for l in range(l1):
+                acc += w_ref[k, l] * popcount_row(x_ref[l, r, :] & a_k)
+        o_ref[r, :] += acc
+
+    for_each_row(o_ref.shape[0], row)
 
 
 def _bitserial_kernel(x_ref, a_ref, w_ref, o_ref, *, k1: int, l1: int,
-                      row_chunk: int, pop_a: bool, pop_x: bool, const: bool):
+                      pop_a: bool, pop_x: bool, const: bool):
     """x_ref [l1, tb, tw] u32 packed planes; a_ref [k1, tm, tw] u32;
     w_ref [k1+1, l1+1] i32; o_ref [tb, tm] i32 (lane-grid accumulated)."""
-    _accumulate_bitserial(lambda l: x_ref[l], a_ref, w_ref, o_ref,
-                          k1=k1, l1=l1, row_chunk=row_chunk,
+    _accumulate_bitserial(x_ref, a_ref, w_ref, o_ref, k1=k1, l1=l1,
                           pop_a=pop_a, pop_x=pop_x, const=const)
 
 
-def _bitserial_sliced_kernel(u_ref, a_ref, w_ref, o_ref, *, k1: int, l1: int,
-                             row_chunk: int, pop_a: bool, pop_x: bool,
-                             const: bool):
+def _bitserial_sliced_kernel(u_ref, a_ref, w_ref, o_ref, x_ref, *, k1: int,
+                             l1: int, pop_a: bool, pop_x: bool, const: bool):
     """In-kernel bit-slicing body. u_ref [32, tb, tw] u32 holds level codes
     bit-transposed (u_ref[t, b, w] = level code of logical bit 32w+t); each
-    of the l1 packed activation planes is built with one shift/AND and a
-    shift-weighted reduce over the 32 bit positions — the streaming operand
-    never round-trips through XLA bitplanes."""
-    shifts = (jnp.uint32(1) << lax.broadcasted_iota(jnp.uint32, (32, 1, 1), 0))
+    of the l1 packed activation planes is built into the x_ref scratch with
+    one shift/AND and a shift-weighted reduce over the 32 bit positions —
+    the streaming operand never round-trips through XLA bitplanes. The
+    reduce runs in int32 (the 32 terms hold disjoint bits, so the sum is
+    their OR) and is bitcast back."""
+    shifts = lax.broadcasted_iota(jnp.int32, (32, 1, 1), 0).astype(jnp.uint32)
     u = u_ref[...]
-    x_planes = [
-        jnp.sum(((u >> jnp.uint32(l)) & jnp.uint32(1)) * shifts,
-                axis=0, dtype=jnp.uint32)
-        for l in range(l1)
-    ]
-    _accumulate_bitserial(lambda l: x_planes[l], a_ref, w_ref, o_ref,
-                          k1=k1, l1=l1, row_chunk=row_chunk,
+    for l in range(l1):
+        bits = ((u >> jnp.uint32(l)) & jnp.uint32(1)) << shifts
+        plane = jnp.sum(lax.bitcast_convert_type(bits, jnp.int32), axis=0)
+        x_ref[l] = lax.bitcast_convert_type(plane, jnp.uint32)
+    _accumulate_bitserial(x_ref, a_ref, w_ref, o_ref, k1=k1, l1=l1,
                           pop_a=pop_a, pop_x=pop_x, const=const)
 
 
@@ -139,7 +145,7 @@ def _normalize_weights(weights, k1: int, l1: int, pop_a, pop_x, const):
 @functools.partial(
     jax.jit,
     static_argnames=("pop_a", "pop_x", "const", "block_b", "block_m",
-                     "block_w", "row_chunk", "interpret"),
+                     "block_w", "interpret"),
 )
 def bitserial_matmul_packed(
     x_planes,
@@ -152,7 +158,6 @@ def bitserial_matmul_packed(
     block_b=None,
     block_m=None,
     block_w=None,
-    row_chunk=None,
     interpret: bool = False,
 ):
     """y[b,m] = sum_{k,l} W[k,l] * sum_w popcount(a[k,m,w] & x[l,b,w])
@@ -171,21 +176,20 @@ def bitserial_matmul_packed(
         weights, k1, l1, pop_a, pop_x, const)
 
     plan = plan_for("bitserial", b, m, w, block_b=block_b, block_m=block_m,
-                    block_w=block_w, row_chunk=row_chunk)
+                    block_w=block_w)
     return lane_stream_call(
-        functools.partial(_bitserial_kernel, k1=k1, l1=l1, row_chunk=plan.rc,
+        functools.partial(_bitserial_kernel, k1=k1, l1=l1,
                           pop_a=pop_a, pop_x=pop_x, const=const),
         x_planes, a_planes, plan,
         x_leading=l1, a_leading=k1,
-        extra_inputs=(weights,),
-        extra_specs=(pl.BlockSpec((k1 + 1, l1 + 1), lambda i, j, k: (0, 0)),),
+        extra_inputs=(weights,), extra_specs=(smem_spec(),),
         interpret=interpret)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("l_bits", "pop_a", "pop_x", "const", "block_b",
-                     "block_m", "block_w", "row_chunk", "interpret"),
+                     "block_m", "block_w", "interpret"),
 )
 def bitserial_matmul_sliced(
     u_stack,
@@ -199,7 +203,6 @@ def bitserial_matmul_sliced(
     block_b=None,
     block_m=None,
     block_w=None,
-    row_chunk=None,
     interpret: bool = False,
 ):
     """Decode fast path: same contract as :func:`bitserial_matmul_packed`
@@ -215,14 +218,12 @@ def bitserial_matmul_sliced(
         weights, k1, l_bits, pop_a, pop_x, const)
 
     plan = plan_for("bitserial_sliced", b, m, w, block_b=block_b,
-                    block_m=block_m, block_w=block_w, row_chunk=row_chunk)
+                    block_m=block_m, block_w=block_w)
     return lane_stream_call(
         functools.partial(_bitserial_sliced_kernel, k1=k1, l1=l_bits,
-                          row_chunk=plan.rc, pop_a=pop_a, pop_x=pop_x,
-                          const=const),
+                          pop_a=pop_a, pop_x=pop_x, const=const),
         u_stack, a_planes, plan,
         x_leading=32, a_leading=k1,
-        extra_inputs=(weights,),
-        extra_specs=(pl.BlockSpec((k1 + 1, l_bits + 1),
-                                  lambda i, j, k: (0, 0)),),
+        extra_inputs=(weights,), extra_specs=(smem_spec(),),
+        scratch_shapes=(pltpu.VMEM((l_bits, plan.bb, plan.bw), jnp.uint32),),
         interpret=interpret)

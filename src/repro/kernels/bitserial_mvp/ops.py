@@ -54,6 +54,7 @@ from ...core.formats import (
     unpack_bits,
     value_range,
 )
+from ...sharding.compat import split_over_mesh
 from .kernel import bitserial_matmul_packed, bitserial_matmul_sliced
 from .ref import bitserial_matmul_packed_ref
 
@@ -231,12 +232,12 @@ def levels_to_stack(u, w: int) -> jnp.ndarray:
 @functools.partial(jax.jit,
                    static_argnames=("n", "k_bits", "l_bits", "fmt_a", "fmt_x",
                                     "a_has_mask", "backend", "block_b",
-                                    "block_m", "block_w", "row_chunk"))
+                                    "block_m", "block_w"))
 def ppac_matmul_resident(x_int, a_planes, *, n: int, k_bits: int,
                          l_bits: int, fmt_a="int", fmt_x="int",
                          a_has_mask: bool = False, backend: str = "pallas",
                          a_int8=None, block_b=None, block_m=None,
-                         block_w=None, row_chunk=None):
+                         block_w=None):
     """The decode fast path: quantized [B, n] activations against resident
     packed planes, activation bit-slicing *inside* the kernel.
 
@@ -275,11 +276,11 @@ def ppac_matmul_resident(x_int, a_planes, *, n: int, k_bits: int,
         return bitserial_matmul_packed_ref(xp, ap, w)
     if backend == "pallas":
         u = levels_to_stack(to_levels(x_int, l_bits, fx), ap.shape[-1])
-        return bitserial_matmul_sliced(u, ap, w, l_bits=l_bits, pop_a=pop_a,
-                                       pop_x=pop_x, const=const,
-                                       block_b=block_b, block_m=block_m,
-                                       block_w=block_w, row_chunk=row_chunk,
-                                       interpret=_auto_interpret())
+        kernel = functools.partial(
+            bitserial_matmul_sliced, l_bits=l_bits, pop_a=pop_a, pop_x=pop_x,
+            const=const, block_b=block_b, block_m=block_m, block_w=block_w,
+            interpret=_auto_interpret())
+        return split_over_mesh(kernel, u, ap, w, x_rows=1, a_rows=1)
     raise ValueError(f"unknown backend {backend}")
 
 

@@ -26,12 +26,14 @@ def bitserial_matmul_packed_ref(x_planes, a_planes, weights):
     bits = jnp.bitwise_and(x[None, :, :, None, :], a[:, None, None, :, :])
     pc = lax.population_count(bits).astype(jnp.int32)  # [K1,L1,B,M,W]
     s = jnp.sum(pc, axis=-1)                           # [K1,L1,B,M]
-    y = jnp.einsum("kl,klbm->bm", w[:k1, :l1], s).astype(jnp.int32)
+    # weighted sums as elementwise int32 products: an integer dot may not
+    # be exact on every backend's matrix unit
+    y = jnp.sum(w[:k1, :l1, None, None] * s, axis=(0, 1))
     if w.shape == (k1 + 1, l1 + 1):
         pop_a = _popcount_rows(a)                      # [K1, M]
         pop_x = _popcount_rows(x)                      # [L1, B]
-        y = y + jnp.einsum("k,km->m", w[:k1, l1], pop_a)[None, :]
-        y = y + jnp.einsum("l,lb->b", w[k1, :l1], pop_x)[:, None]
+        y = y + jnp.sum(w[:k1, l1, None] * pop_a, axis=0)[None, :]
+        y = y + jnp.sum(w[k1, :l1, None] * pop_x, axis=0)[:, None]
         y = y + w[k1, l1]
     return y.astype(jnp.int32)
 

@@ -23,10 +23,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..tiling import lane_stream_call, plan_tiles, subrow_popcount_sum
+from ..tiling import for_each_row, lane_stream_call, plan_tiles, popcount_row
 
 
-def _gf2_matmul_kernel(x_ref, a_ref, o_ref, *, row_chunk: int):
+def _gf2_matmul_kernel(x_ref, a_ref, o_ref):
     """x_ref: [tb, tw] uint32; a_ref: [tm, tw] uint32; o_ref: [tb, tm] int32
     holding the running parity (0/1), XOR-accumulated over grid dim 2."""
 
@@ -34,25 +34,23 @@ def _gf2_matmul_kernel(x_ref, a_ref, o_ref, *, row_chunk: int):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    tile_par = subrow_popcount_sum(x_ref[...], a_ref[...],
-                                   bit_op=jnp.bitwise_and,
-                                   row_chunk=row_chunk,
-                                   postprocess=lambda p: p & 1)
-    o_ref[...] ^= tile_par
+    def row(r):
+        o_ref[r, :] ^= popcount_row(x_ref[r, :] & a_ref[...]) & 1
+
+    for_each_row(x_ref.shape[0], row)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_b", "block_m", "block_w", "row_chunk", "interpret"),
+    static_argnames=("block_b", "block_m", "block_w", "interpret"),
 )
 def gf2_matmul_packed(
     x_packed,
     a_packed,
     *,
     block_b: int = 64,
-    block_m: int = 128,
-    block_w: int = 128,  # lane tiles stay 128-multiples for native lowering
-    row_chunk: int = 8,
+    block_m: int = 256,
+    block_w: int = 256,
     interpret: bool = False,
 ):
     """y[b,m] = parity(sum_w popcount(x[b,w] & a[m,w])) — int32 in {0,1}.
@@ -67,7 +65,6 @@ def gf2_matmul_packed(
     assert w == w2, (w, w2)
 
     plan = plan_tiles(b, m, w, block_b=block_b, block_m=block_m,
-                      block_w=block_w, row_chunk=row_chunk)
-    return lane_stream_call(
-        functools.partial(_gf2_matmul_kernel, row_chunk=plan.rc),
-        x_packed, a_packed, plan, interpret=interpret)
+                      block_w=block_w)
+    return lane_stream_call(_gf2_matmul_kernel, x_packed, a_packed, plan,
+                            interpret=interpret)

@@ -37,8 +37,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from ..tiling import for_each_row, lane_tile, popcount_row
 from ..tiling import round_up as _round_up
-from ..tiling import subrow_popcount_sum
 from .ref import MASKED_SCORE
 
 _NEG_INIT = -(2**30)       # running-slot init: below every candidate score
@@ -46,49 +46,47 @@ _NEG_TAKEN = jnp.iinfo(jnp.int32).min  # extracted candidates never re-win
 _IDX_SENTINEL = 2**30      # index init / argmin mask: above every row index
 
 
-def _tile_scores(x, a, valid, *, n: int, row_chunk: int):
-    """Masked similarity scores [tb, tm] of one database tile.
-
-    Chunks the tile's row dimension via the shared
-    :func:`repro.kernels.tiling.subrow_popcount_sum` (the subrow
-    partitioning of Fig. 2, as in binary_mvp).
-    """
-    s = subrow_popcount_sum(x, a, bit_op=jnp.bitwise_xor,
-                            row_chunk=row_chunk)
-    h = n - s
+def _row_scores(x_row, a, valid, *, n: int):
+    """Masked similarity scores [1, tm] of one query against one tile."""
+    h = n - popcount_row(x_row ^ a)
     return jnp.where(valid > 0, h, MASKED_SCORE)
 
 
 def _merge_topk(run_s, run_i, tile_s, tile_i, *, k: int):
-    """k best of [running ∪ tile] by (score desc, index asc) — exact."""
-    tb = run_s.shape[0]
-    cand_s = jnp.concatenate([run_s, tile_s], axis=1)
-    cand_i = jnp.concatenate([run_i, tile_i], axis=1)
+    """k best of [running ∪ tile] by (score desc, index asc) — exact.
 
-    def select(i, carry):
-        cs, ci, outs, outi = carry
-        best = jnp.max(cs, axis=1, keepdims=True)                   # [tb, 1]
-        at_best = cs == best
-        bidx = jnp.min(jnp.where(at_best, ci, _IDX_SENTINEL),
-                       axis=1, keepdims=True)                       # [tb, 1]
-        outs = lax.dynamic_update_slice_in_dim(outs, best, i, axis=1)
-        outi = lax.dynamic_update_slice_in_dim(outi, bidx, i, axis=1)
-        taken = at_best & (ci == bidx)
-        return jnp.where(taken, _NEG_TAKEN, cs), ci, outs, outi
+    One query row: run_* [1, k], tile_* [1, tm]. Each of the k rounds
+    takes the best score left on either side, then the least index among
+    the entries that hold it, and retires that one entry."""
+    slot = lax.broadcasted_iota(jnp.int32, (1, k), 1)
 
-    _, _, outs, outi = lax.fori_loop(
-        0, k, select,
-        (cand_s, cand_i,
-         jnp.zeros((tb, k), jnp.int32), jnp.zeros((tb, k), jnp.int32)))
+    def best_of(s, i, best):
+        return jnp.min(jnp.where(s == best, i, _IDX_SENTINEL), axis=1,
+                       keepdims=True)
+
+    def select(j, carry):
+        rs, ts, outs, outi = carry
+        best = jnp.maximum(jnp.max(rs, axis=1, keepdims=True),
+                           jnp.max(ts, axis=1, keepdims=True))      # [1, 1]
+        bidx = jnp.minimum(best_of(rs, run_i, best),
+                           best_of(ts, tile_i, best))               # [1, 1]
+        outs = jnp.where(slot == j, best, outs)
+        outi = jnp.where(slot == j, bidx, outi)
+        rs = jnp.where((rs == best) & (run_i == bidx), _NEG_TAKEN, rs)
+        ts = jnp.where((ts == best) & (tile_i == bidx), _NEG_TAKEN, ts)
+        return rs, ts, outs, outi
+
+    zeros = jnp.zeros((1, k), jnp.int32)
+    _, _, outs, outi = lax.fori_loop(0, k, select,
+                                     (run_s, tile_s, zeros, zeros))
     return outs, outi
 
 
 def _hamming_topk_kernel(x_ref, a_ref, valid_ref, os_ref, oi_ref, *,
-                         n: int, k: int, row_chunk: int):
-    """x_ref [tb, tw] u32; a_ref [tm, tw] u32; valid_ref [1, tm] i32;
+                         n: int, k: int):
+    """x_ref [tb, W] u32; a_ref [tm, W] u32; valid_ref [1, tm] i32;
     os_ref/oi_ref [tb, k] i32 — the running top-k, revisited over grid dim 1.
     """
-    tb = x_ref.shape[0]
     tm = a_ref.shape[0]
     j = pl.program_id(1)
 
@@ -97,39 +95,53 @@ def _hamming_topk_kernel(x_ref, a_ref, valid_ref, os_ref, oi_ref, *,
         os_ref[...] = jnp.full_like(os_ref, _NEG_INIT)
         oi_ref[...] = jnp.full_like(oi_ref, _IDX_SENTINEL)
 
-    tile_s = _tile_scores(x_ref[...], a_ref[...], valid_ref[...],
-                          n=n, row_chunk=row_chunk)
-    tile_i = j * tm + lax.broadcasted_iota(jnp.int32, (tb, tm), 1)
-    outs, outi = _merge_topk(os_ref[...], oi_ref[...], tile_s, tile_i, k=k)
-    os_ref[...] = outs
-    oi_ref[...] = outi
+    tile_i = j * tm + lax.broadcasted_iota(jnp.int32, (1, tm), 1)
+
+    def row(r):
+        tile_s = _row_scores(x_ref[r, :], a_ref[...], valid_ref[...], n=n)
+        outs, outi = _merge_topk(os_ref[r, :], oi_ref[r, :], tile_s, tile_i,
+                                 k=k)
+        os_ref[r, :] = outs
+        oi_ref[r, :] = outi
+
+    for_each_row(x_ref.shape[0], row)
 
 
 def _hamming_threshold_kernel(x_ref, a_ref, valid_ref, o_ref, *,
-                              n: int, delta: int, row_chunk: int):
+                              n: int, delta: int):
     """o_ref [tb, tm] i32: CAM match lines (h >= δ) for live rows."""
-    tile_s = _tile_scores(x_ref[...], a_ref[...], valid_ref[...],
-                          n=n, row_chunk=row_chunk)
-    o_ref[...] = (tile_s >= delta).astype(jnp.int32)
+
+    def row(r):
+        s = _row_scores(x_ref[r, :], a_ref[...], valid_ref[...], n=n)
+        o_ref[r, :] = (s >= delta).astype(jnp.int32)
+
+    for_each_row(x_ref.shape[0], row)
 
 
-def _pad_operands(x_packed, a_packed, valid, bb, bm):
+def _pad_operands(x_packed, a_packed, valid, bb, mp):
+    """Pad queries to the batch tile and the database to ``mp`` rows; the
+    packed words stay whole (one lane block spans them)."""
     b, w = x_packed.shape
     m, w2 = a_packed.shape
     assert w == w2, (w, w2)
-    bp, mp = _round_up(b, bb), _round_up(m, bm)
-    wp = _round_up(max(w, 1), 128)
-    x_p = jnp.pad(x_packed.astype(jnp.uint32), ((0, bp - b), (0, wp - w)))
-    a_p = jnp.pad(a_packed.astype(jnp.uint32), ((0, mp - m), (0, wp - w)))
+    bp = _round_up(b, bb)
+    x_p = jnp.pad(x_packed.astype(jnp.uint32), ((0, bp - b), (0, 0)))
+    a_p = jnp.pad(a_packed.astype(jnp.uint32), ((0, mp - m), (0, 0)))
     if valid is None:
         valid = jnp.ones((m,), jnp.int32)
     v_p = jnp.pad(jnp.asarray(valid, jnp.int32)[None, :], ((0, 0), (0, mp - m)))
-    return x_p, a_p, v_p, bp, mp, wp
+    return x_p, a_p, v_p, bp
+
+
+def _db_specs(bb, bm, w):
+    return [pl.BlockSpec((bb, w), lambda i, j: (i, 0)),
+            pl.BlockSpec((bm, w), lambda i, j: (j, 0)),
+            pl.BlockSpec((1, bm), lambda i, j: (0, j))]
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n", "k", "block_q", "block_m", "row_chunk", "interpret"),
+    static_argnames=("n", "k", "block_q", "block_m", "interpret"),
 )
 def hamming_topk_packed(
     x_packed,
@@ -140,7 +152,6 @@ def hamming_topk_packed(
     k: int,
     block_q: int = 8,
     block_m: int = 256,
-    row_chunk: int = 8,
     interpret: bool = False,
 ):
     """Fused top-k: (scores [B, k], indices [B, k]) int32.
@@ -149,27 +160,17 @@ def hamming_topk_packed(
     optional). Requires k <= M. Padding lanes must be zero (xor of equal
     zeros adds 0 to the popcount, so they never change h).
     """
-    b, _ = x_packed.shape
+    b, w = x_packed.shape
     m = a_packed.shape[0]
     assert 1 <= k <= m, (k, m)
 
     bb = min(block_q, _round_up(b, 8))
-    bm = min(block_m, _round_up(m, 8))
-    bm = max(bm, _round_up(k, 8))  # a single tile must hold k candidates
-    rc = min(row_chunk, bm)
-    while bm % rc:
-        rc -= 1
-
-    x_p, a_p, v_p, bp, mp, _ = _pad_operands(x_packed, a_packed, valid, bb, bm)
-    grid = (bp // bb, mp // bm)
+    bm, mp = lane_tile(m, max(block_m, k))  # a tile must hold k candidates
+    x_p, a_p, v_p, bp = _pad_operands(x_packed, a_packed, valid, bb, mp)
     scores, idx = pl.pallas_call(
-        functools.partial(_hamming_topk_kernel, n=n, k=k, row_chunk=rc),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bb, x_p.shape[1]), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, a_p.shape[1]), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, bm), lambda i, j: (0, j)),
-        ],
+        functools.partial(_hamming_topk_kernel, n=n, k=k),
+        grid=(bp // bb, mp // bm),
+        in_specs=_db_specs(bb, bm, w),
         out_specs=(
             pl.BlockSpec((bb, k), lambda i, j: (i, 0)),
             pl.BlockSpec((bb, k), lambda i, j: (i, 0)),
@@ -185,8 +186,7 @@ def hamming_topk_packed(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n", "delta", "block_q", "block_m", "row_chunk",
-                     "interpret"),
+    static_argnames=("n", "delta", "block_q", "block_m", "interpret"),
 )
 def hamming_threshold_packed(
     x_packed,
@@ -197,30 +197,19 @@ def hamming_threshold_packed(
     delta: int,
     block_q: int = 8,
     block_m: int = 256,
-    row_chunk: int = 8,
     interpret: bool = False,
 ):
     """Fused CAM δ-match: match lines [B, M] int32 (1 iff h >= δ, row live)."""
-    b, _ = x_packed.shape
+    b, w = x_packed.shape
     m = a_packed.shape[0]
 
     bb = min(block_q, _round_up(b, 8))
-    bm = min(block_m, _round_up(m, 8))
-    rc = min(row_chunk, bm)
-    while bm % rc:
-        rc -= 1
-
-    x_p, a_p, v_p, bp, mp, _ = _pad_operands(x_packed, a_packed, valid, bb, bm)
-    grid = (bp // bb, mp // bm)
+    bm, mp = lane_tile(m, block_m)
+    x_p, a_p, v_p, bp = _pad_operands(x_packed, a_packed, valid, bb, mp)
     out = pl.pallas_call(
-        functools.partial(_hamming_threshold_kernel, n=n, delta=delta,
-                          row_chunk=rc),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bb, x_p.shape[1]), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, a_p.shape[1]), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, bm), lambda i, j: (0, j)),
-        ],
+        functools.partial(_hamming_threshold_kernel, n=n, delta=delta),
+        grid=(bp // bb, mp // bm),
+        in_specs=_db_specs(bb, bm, w),
         out_specs=pl.BlockSpec((bb, bm), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bp, mp), jnp.int32),
         interpret=interpret,

@@ -5,25 +5,30 @@ uint32 operands x [.., B, W] and a [.., M, W] are padded up to tile
 multiples, a (B/bb, M/bm, W/bw) grid walks batch × row × lane tiles with
 the lane dimension innermost, and the revisited [bb, bm] int32 output
 block accumulates one contribution per lane tile (integer add for the
-popcount modes, XOR for GF(2) parity). Inside a tile, the row dimension is
-chunked (``row_chunk``) to bound the [bb, chunk, bw] popcount intermediate
-— the TPU analogue of the paper's subrow partitioning (Fig. 2), which
-bounds adder fan-in in hardware and VMEM footprint here.
+popcount modes, XOR for GF(2) parity). Inside a tile the body walks the
+streamed rows one at a time (:func:`for_each_row`): each row is one PPAC
+array cycle, its vector broadcast against the resident [bm, bw] tile and
+every row's popcount landing on its output lane (:func:`popcount_row`).
+That keeps the per-step intermediate at one resident tile — the bound the
+paper's subrow partitioning (Fig. 2) puts on adder fan-in in hardware.
+
+Tiles obey the TPU block rule: batch tiles are multiples of 8 sublanes;
+row tiles (the output's lane dim) and lane tiles are multiples of 128 or
+the whole unpadded extent (:func:`lane_tile`).
 
 This module owns that machinery once: tile planning (:func:`plan_tiles`),
-zero-padding (:func:`pad_lanes`), the chunked popcount inner loop
-(:func:`subrow_popcount_sum`) and the canonical lane-streamed
-``pallas_call`` (:func:`lane_stream_call`). The per-mode kernels
-(``binary_mvp``, ``bitserial_mvp``, ``gf2_tiled``) are thin bodies on top;
-``hamming_topk`` reuses the planning + inner loop with its own 2-D grid
-(its output is a running top-k, not a revisited matmul block).
+zero-padding (:func:`pad_lanes`), the row loop and the canonical
+lane-streamed ``pallas_call`` (:func:`lane_stream_call`). The per-mode
+kernels (``binary_mvp``, ``bitserial_mvp``, ``gf2_tiled``) are thin bodies
+on top; ``hamming_topk`` reuses the planning + row loop with its own 2-D
+grid (its output is a running top-k, not a revisited matmul block).
 
 Tile-plan selection (:func:`plan_for`) is three-tiered: an explicit block
-override always wins; otherwise a measured autotune result from the
-persisted JSON cache (keyed on mode × logical shape × platform, refreshed
-via :func:`autotune_plan`); otherwise shape-aware defaults — decode steps
-have tiny batches, so small-B launches get a thin batch tile and a fatter
-row/lane tile instead of the generic 64-row batch block.
+override always wins; otherwise a measured autotune result from the JSON
+file ``PPAC_TILE_CACHE`` names, when it names one (keyed on mode × logical
+shape × platform, refreshed via :func:`autotune_plan`); otherwise
+shape-aware defaults — decode steps have tiny batches, so small-B
+launches get a thin batch tile.
 
 Padding is always with zero lanes, which every mode tolerates by
 construction: XOR of equal zeros and AND against zero both popcount to 0,
@@ -33,7 +38,6 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import math
 import os
 import time
 from typing import Callable, Dict, Optional
@@ -43,10 +47,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:  # TPU compiler hints (grid dimension semantics); absent on old jax
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from ..obs import ledger as _flight
 
@@ -74,7 +75,6 @@ class TilePlan:
     bb: int         # batch tile
     bm: int         # row tile
     bw: int         # lane tile
-    rc: int         # subrow chunk (divides bm)
     bp: int         # padded batch
     mp: int         # padded rows
     wp: int         # padded lanes
@@ -86,27 +86,31 @@ class TilePlan:
 
     @property
     def blocks(self) -> Dict[str, int]:
-        """The four tunable knobs, as kwargs for the kernel wrappers."""
-        return dict(block_b=self.bb, block_m=self.bm, block_w=self.bw,
-                    row_chunk=self.rc)
+        """The three tunable knobs, as kwargs for the kernel wrappers."""
+        return dict(block_b=self.bb, block_m=self.bm, block_w=self.bw)
+
+
+def lane_tile(n: int, block: int):
+    """(tile, padded extent) of a dim that lands on TPU lanes in some block
+    (the packed words of an operand, the rows of the output). Either one
+    tile spans the whole unpadded extent, or tiles are whole multiples of
+    128 lanes — the two shapes the TPU block rule admits."""
+    n = max(n, 1)
+    tile = round_up(block, LANE_MULTIPLE)
+    if n <= block or n <= tile:
+        return n, n
+    return tile, round_up(n, tile)
 
 
 def plan_tiles(b: int, m: int, w: int, *, block_b: int = 64,
-               block_m: int = 128, block_w: int = 64,
-               row_chunk: int = 8) -> TilePlan:
-    """Clamp requested block sizes to the (rounded-up) operand shape and
-    derive the padded geometry. The row tile is rounded *up* to a multiple
-    of ``row_chunk`` so the requested chunk is honored verbatim (shrinking
-    the chunk instead used to silently degrade prime row tiles to
-    ``row_chunk=1`` — a 8x fatter popcount loop)."""
-    bb = min(block_b, round_up(b, SUBLANE_MULTIPLE))
-    bm = min(block_m, round_up(m, SUBLANE_MULTIPLE))
-    bw = min(block_w, round_up(w, LANE_MULTIPLE))
-    rc = max(1, min(row_chunk, bm))
-    # honor both the chunk and the sublane layout rule at once
-    bm = round_up(bm, math.lcm(rc, SUBLANE_MULTIPLE))
-    plan = TilePlan(b, m, w, bb, bm, bw, rc,
-                    round_up(b, bb), round_up(m, bm), round_up(w, bw))
+               block_m: int = 256, block_w: int = 256) -> TilePlan:
+    """Clamp requested block sizes to the operand shape and derive the
+    padded geometry. Batch tiles are sublane multiples; row and lane tiles
+    follow :func:`lane_tile` (rows are the output block's lane dim)."""
+    bb = min(round_up(block_b, SUBLANE_MULTIPLE), round_up(b, SUBLANE_MULTIPLE))
+    bm, mp = lane_tile(m, block_m)
+    bw, wp = lane_tile(w, block_w)
+    plan = TilePlan(b, m, w, bb, bm, bw, round_up(b, bb), mp, wp)
     # flight recorder: attach the resolved plan to the launch currently
     # being recorded (no-op unless a ledger is open AND a launch is live)
     _flight.note_plan(plan)
@@ -118,33 +122,29 @@ def plan_tiles(b: int, m: int, w: int, *, block_b: int = 64,
 # ---------------------------------------------------------------------------
 
 CACHE_ENV = "PPAC_TILE_CACHE"
-_DEFAULT_CACHE = "~/.cache/ppac/tile_plans.json"
 
 
 def default_blocks(b: int, m: int, w: int) -> Dict[str, int]:
     """Shape-aware default blocks. Decode steps stream a tiny batch (a few
-    tokens) against a large resident matrix: an 8-row batch tile frees
-    VMEM for a fatter row tile, so the K·L popcount schedule amortizes
-    over more resident rows per grid step."""
+    tokens) against a large resident matrix: an 8-row batch tile keeps the
+    per-grid-step row loop short while the resident tile stays fat, so
+    the K·L popcount schedule amortizes over more resident rows."""
     if b <= 8:
-        return dict(block_b=SUBLANE_MULTIPLE, block_m=256, block_w=64,
-                    row_chunk=8)
+        return dict(block_b=SUBLANE_MULTIPLE, block_m=256, block_w=256)
     if b <= 32:
-        return dict(block_b=32, block_m=192, block_w=64, row_chunk=8)
-    return dict(block_b=64, block_m=128, block_w=64, row_chunk=8)
+        return dict(block_b=32, block_m=256, block_w=256)
+    return dict(block_b=64, block_m=256, block_w=256)
 
 
 class PlanCache:
     """Persisted (mode, shape, platform) -> block-dict autotune cache.
 
-    One tiny JSON file (``PPAC_TILE_CACHE`` env var, default
-    ``~/.cache/ppac/tile_plans.json``); loaded lazily once per process,
-    rewritten atomically on every :meth:`put`.
+    One tiny JSON file named by the ``PPAC_TILE_CACHE`` env var; loaded
+    lazily once per process, rewritten atomically on every :meth:`put`.
     """
 
-    def __init__(self, path: Optional[str] = None):
-        self.path = os.path.expanduser(
-            path or os.environ.get(CACHE_ENV, _DEFAULT_CACHE))
+    def __init__(self, path: str):
+        self.path = os.path.expanduser(path)
         self._data: Optional[Dict[str, Dict[str, int]]] = None
 
     @staticmethod
@@ -166,8 +166,7 @@ class PlanCache:
         hit = self._load().get(self.key(mode, b, m, w))
         if hit is None:
             return None
-        return {k: int(hit[k])
-                for k in ("block_b", "block_m", "block_w", "row_chunk")
+        return {k: int(hit[k]) for k in ("block_b", "block_m", "block_w")
                 if k in hit}
 
     def put(self, mode: str, b: int, m: int, w: int,
@@ -178,7 +177,7 @@ class PlanCache:
             entry["us"] = round(float(us), 2)
         data[self.key(mode, b, m, w)] = entry
         os.makedirs(os.path.dirname(self.path) or ".", exist_ok=True)
-        tmp = f"{self.path}.tmp.{os.getpid()}"
+        tmp = f"{self.path}.tmp"
         with open(tmp, "w") as f:
             json.dump(data, f, indent=1, sort_keys=True)
         os.replace(tmp, self.path)
@@ -187,9 +186,14 @@ class PlanCache:
 _CACHES: Dict[str, PlanCache] = {}
 
 
-def plan_cache() -> PlanCache:
-    """Process-wide cache for the path currently selected by the env."""
-    path = os.path.expanduser(os.environ.get(CACHE_ENV, _DEFAULT_CACHE))
+def plan_cache() -> Optional[PlanCache]:
+    """Process-wide cache for the file ``PPAC_TILE_CACHE`` names, or None
+    when it names none: launches then use the shape defaults, so no file
+    outside the caller's control changes what a run compiles."""
+    path = os.environ.get(CACHE_ENV)
+    if not path:
+        return None
+    path = os.path.expanduser(path)
     if path not in _CACHES:
         _CACHES[path] = PlanCache(path)
     return _CACHES[path]
@@ -197,40 +201,41 @@ def plan_cache() -> PlanCache:
 
 def plan_for(mode: str, b: int, m: int, w: int, *,
              block_b: Optional[int] = None, block_m: Optional[int] = None,
-             block_w: Optional[int] = None, row_chunk: Optional[int] = None,
-             use_cache: bool = True) -> TilePlan:
+             block_w: Optional[int] = None) -> TilePlan:
     """Resolve the tile plan for one launch: explicit overrides win, then
-    the autotune cache, then the decode-aware defaults."""
+    the autotune cache (when one is named), then the decode-aware
+    defaults."""
     blocks = default_blocks(b, m, w)
-    if use_cache:
-        cached = plan_cache().get(mode, b, m, w)
-        if cached:
-            blocks.update(cached)
+    cache = plan_cache()
+    if cache is not None:
+        blocks.update(cache.get(mode, b, m, w) or {})
     for name, val in (("block_b", block_b), ("block_m", block_m),
-                      ("block_w", block_w), ("row_chunk", row_chunk)):
+                      ("block_w", block_w)):
         if val is not None:
             blocks[name] = val
     return plan_tiles(b, m, w, **blocks)
 
 
-def candidate_blocks(b: int, m: int, w: int):
-    """Small measured-search space around the defaults, deduplicated by
-    resolved geometry (clamping makes many candidates collapse on small
-    shapes)."""
+def _distinct_geometries(b: int, m: int, w: int, trial):
+    """Drop block dicts that resolve to an already-seen geometry (clamping
+    makes many candidates collapse on small shapes)."""
     seen, out = set(), []
-    for bb in (SUBLANE_MULTIPLE, 32, 64):
-        for bm in (64, 128, 256, 512):
-            for bw in (32, 64, 128):
-                for rc in (4, 8, 16):
-                    plan = plan_tiles(b, m, w, block_b=bb, block_m=bm,
-                                      block_w=bw, row_chunk=rc)
-                    sig = (plan.bb, plan.bm, plan.bw, plan.rc)
-                    if sig in seen:
-                        continue
-                    seen.add(sig)
-                    out.append(dict(block_b=bb, block_m=bm, block_w=bw,
-                                    row_chunk=rc))
+    for blocks in trial:
+        plan = plan_tiles(b, m, w, **blocks)
+        sig = (plan.bb, plan.bm, plan.bw)
+        if sig not in seen:
+            seen.add(sig)
+            out.append(blocks)
     return out
+
+
+def candidate_blocks(b: int, m: int, w: int):
+    """Small measured-search space around the defaults."""
+    return _distinct_geometries(b, m, w, [
+        dict(block_b=bb, block_m=bm, block_w=bw)
+        for bb in (SUBLANE_MULTIPLE, 32, 64)
+        for bm in (128, 256, 512)
+        for bw in (128, 256, 512)])
 
 
 def quick_candidates(b: int, m: int, w: int):
@@ -238,17 +243,9 @@ def quick_candidates(b: int, m: int, w: int):
     cost per candidate dominates off-TPU, so the serving autotune sweeps
     this trimmed set by default (full sweep: :func:`candidate_blocks`)."""
     base = default_blocks(b, m, w)
-    trial = [base,
-             {**base, "block_m": 128}, {**base, "block_m": 512},
-             {**base, "block_w": 32}, {**base, "row_chunk": 16}]
-    seen, out = set(), []
-    for blocks in trial:
-        plan = plan_tiles(b, m, w, **blocks)
-        sig = (plan.bb, plan.bm, plan.bw, plan.rc)
-        if sig not in seen:
-            seen.add(sig)
-            out.append(blocks)
-    return out
+    return _distinct_geometries(b, m, w, [
+        base, {**base, "block_m": 128}, {**base, "block_m": 512},
+        {**base, "block_w": 128}])
 
 
 def autotune_plan(mode: str, b: int, m: int, w: int,
@@ -263,6 +260,9 @@ def autotune_plan(mode: str, b: int, m: int, w: int,
     candidate compiles and is discarded; the best median-of-``reps`` wins.
     """
     cache = cache or plan_cache()
+    if cache is None:
+        raise ValueError(f"autotuning persists plans: set {CACHE_ENV} to "
+                         "the JSON file that should hold them")
     best_blocks, best_us, last_err = None, None, None
     for blocks in (candidates or candidate_blocks(b, m, w)):
         plan = plan_tiles(b, m, w, **blocks)
@@ -301,30 +301,28 @@ def pad_lanes(arr, rows_to: int, lanes_to: int) -> jnp.ndarray:
     return jnp.pad(arr, pads)
 
 
-def subrow_popcount_sum(x, a, *, bit_op, row_chunk: int, postprocess=None):
-    """S[b, r] = sum_w popcount(bit_op(x[b, w], a[r, w])) over one tile.
+def popcount_row(bits):
+    """[tm, tw] uint32 -> [1, tm] int32: each row's total set bits, laid
+    along the lanes of one output row.
 
-    x: [tb, tw] uint32, a: [tm, tw] uint32 -> [tb, tm] int32. The row dim
-    is chunked (``row_chunk`` rows at a time) to bound the [tb, chunk, tw]
-    popcount intermediate — the subrow partitioning of Fig. 2.
-    ``postprocess`` maps each [tb, chunk] int32 partial (e.g. ``& 1`` for
-    GF(2) parity) before it lands in the result.
+    Called on ``bit_op(x_row, a)`` — one streamed [1, tw] vector broadcast
+    against the resident [tm, tw] tile — it is one PPAC array cycle: every
+    latch row's population count lands on that row's output lane.
     """
-    tb = x.shape[0]
-    tm = a.shape[0]
-    n_chunks = tm // row_chunk
+    pc = lax.population_count(bits).astype(jnp.int32)
+    return jnp.sum(pc, axis=-1)[None, :]
 
-    def body(i, acc):
-        a_c = lax.dynamic_slice_in_dim(a, i * row_chunk, row_chunk, axis=0)
-        bits = bit_op(x[:, None, :], a_c[None, :, :])
-        pc = lax.population_count(bits).astype(jnp.int32)  # [tb, chunk, tw]
-        part = jnp.sum(pc, axis=-1)                        # [tb, chunk]
-        if postprocess is not None:
-            part = postprocess(part)
-        return lax.dynamic_update_slice_in_dim(acc, part, i * row_chunk, axis=1)
 
-    return lax.fori_loop(0, n_chunks, body, jnp.zeros((tb, tm), jnp.int32),
-                         unroll=False)
+def for_each_row(n: int, body: Callable[[object], None]) -> None:
+    """Run ``body(rows)`` for each streamed row of the tile, ``rows`` being
+    the ``pl.ds`` that selects that one row. A loop, not an unroll, so the
+    per-step intermediate stays one [tm, tw] tile whatever the batch tile."""
+
+    def step(r, carry):
+        body(pl.ds(r, 1))
+        return carry
+
+    lax.fori_loop(0, n, step, 0)
 
 
 def _x_spec(plan: TilePlan, leading: int):
@@ -341,9 +339,14 @@ def _a_spec(plan: TilePlan, leading: int):
     return pl.BlockSpec((plan.bm, plan.bw), lambda i, j, k: (j, k))
 
 
+def smem_spec():
+    """A whole small operand (scalar coefficients) in scalar memory."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
 def lane_stream_call(kernel_body, x_packed, a_packed, plan: TilePlan, *,
                      x_leading: int = 0, a_leading: int = 0,
-                     extra_inputs=(), extra_specs=(),
+                     extra_inputs=(), extra_specs=(), scratch_shapes=(),
                      interpret: bool = False):
     """Run ``kernel_body`` on the canonical lane-streamed grid.
 
@@ -366,8 +369,8 @@ def lane_stream_call(kernel_body, x_packed, a_packed, plan: TilePlan, *,
     x_p = pad_lanes(x_packed, plan.bp, plan.wp)
     a_p = pad_lanes(a_packed, plan.mp, plan.wp)
     extra = {}
-    if pltpu is not None and not interpret:
-        extra["compiler_params"] = pltpu.TPUCompilerParams(
+    if not interpret:
+        extra["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=GRID_SEMANTICS)
     out = pl.pallas_call(
         kernel_body,
@@ -376,6 +379,7 @@ def lane_stream_call(kernel_body, x_packed, a_packed, plan: TilePlan, *,
                   *extra_specs],
         out_specs=pl.BlockSpec((plan.bb, plan.bm), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((plan.bp, plan.mp), jnp.int32),
+        scratch_shapes=list(scratch_shapes),
         interpret=interpret,
         **extra,
     )(x_p, a_p, *extra_inputs)

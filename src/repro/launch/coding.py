@@ -25,6 +25,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..core.backend import use_compile_cache
 from ..gf2.ldpc import BitFlipDecoder, LDPCCode, bsc_flip, make_array_ldpc
 from .bucketed import BucketedBatchServer
 
@@ -85,20 +86,34 @@ def main():
                     help="print the telemetry registry (Prometheus text) "
                          "after the run")
     args = ap.parse_args()
+    use_compile_cache()
+    serve_noisy_words(args.rows, args.cols, requests=args.requests,
+                      errors=args.errors, max_iters=args.max_iters,
+                      backend=args.backend, show_metrics=args.metrics)
+    print("OK")
 
-    rng = np.random.default_rng(0)
-    code = make_array_ldpc(args.rows, args.cols)
-    decoder = BitFlipDecoder(code, backend=args.backend,
-                             max_iters=args.max_iters)
+
+def serve_noisy_words(rows: int, cols: int, *, requests: int,
+                      errors: int = 1, max_iters: int = 8,
+                      backend: str = "auto", seed: int = 0,
+                      show_metrics: bool = False) -> dict:
+    """Encode ``requests`` random messages with the rows x cols array
+    code, flip ``errors`` bits per word and decode them through the
+    server; asserts every message is recovered when ``errors`` is within
+    the code's guarantee. Returns the run's counts, host seconds and the
+    kernel backend the decoder resolved."""
+    rng = np.random.default_rng(seed)
+    code = make_array_ldpc(rows, cols)
+    decoder = BitFlipDecoder(code, backend=backend, max_iters=max_iters)
     print(f"array code: n={code.n} k={code.k} rate={code.rate:.3f} "
           f"checks={code.n_chk} guaranteed_t={code.guaranteed_t}")
 
-    msgs = rng.integers(0, 2, (args.requests, code.k)).astype(np.uint8)
+    msgs = rng.integers(0, 2, (requests, code.k)).astype(np.uint8)
     codewords = code.encode(msgs, backend=decoder.backend)
-    noisy = bsc_flip(codewords, args.errors, rng)
+    noisy = bsc_flip(codewords, errors, rng)
 
     server = CodingServer(decoder)
-    for i in range(args.requests):
+    for i in range(requests):
         server.submit(DecodeRequest(i, noisy[i]))
 
     cycles0 = decoder.counter.cycles
@@ -116,13 +131,15 @@ def main():
           f"{decoder.compute_cache_cycles_per_word_iteration()} cycles/word/iter "
           f"vs PPAC {decoder.cycles_per_word_iteration()}")
     print(f"recovered {recovered}/{len(done)} messages "
-          f"({args.errors} bit errors/word)")
-    if args.errors <= code.guaranteed_t:
+          f"({errors} bit errors/word)")
+    assert len(done) == requests, (len(done), requests)
+    if errors <= code.guaranteed_t:
         assert recovered == len(done), \
             "<= t errors must always be corrected"
-    if args.metrics:
+    if show_metrics:
         print(server.metrics.prometheus_text(), end="")
-    print("OK")
+    return dict(served=len(done), recovered=recovered, seconds=dt,
+                backend=decoder.backend)
 
 
 if __name__ == "__main__":

@@ -24,6 +24,7 @@ import traceback  # noqa: E402
 import jax  # noqa: E402
 
 from ..configs.base import SHAPES, cells, load_arch  # noqa: E402
+from ..core.backend import use_compile_cache  # noqa: E402
 from ..core.cost_model import (  # noqa: E402
     TPU_HBM_BW,
     TPU_ICI_BW,
@@ -120,7 +121,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
                            seq_shard=seq_shard)
 
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         cell = build_cell(cfg, shape, mesh, tcfg=tcfg, rules=rules,
                           serve_quant=serve_quant)
         lowered = jax.jit(cell.fn, in_shardings=cell.in_shardings,
@@ -237,6 +238,7 @@ def main():
     ap.add_argument("--out", default="results/dryrun")
     ap.add_argument("--force", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     os.makedirs(args.out, exist_ok=True)
     todo = []

@@ -1,53 +1,43 @@
 """Production meshes. Import must never touch jax device state —
 everything is a function.
 
-``make_serving_mesh`` is the serving entry point: it degrades gracefully
-when the requested shape exceeds the attached devices (CI forced-host
-runs, single-chip dev boxes) by falling back to the largest valid
-submesh with a warning — a mesh mismatch should cost a log line at
-server construction, not an opaque shape error deep inside jit.
+``make_serving_mesh`` is the serving entry point: it raises at server
+construction when the requested shape exceeds the attached devices, so a
+missing device is never hidden behind a smaller mesh.
 """
 from __future__ import annotations
 
 import math
-import warnings
 from typing import Sequence, Tuple
 
 import jax
+import numpy as np
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape, axes, devices=None):
+    """A mesh whose axes are all ``Auto``: the model code places work with
+    logical-axis sharding constraints, which only bind to Auto axes.
+    ``devices``, when given, fill the mesh in order."""
+    types = (AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(tuple(shape), tuple(axes), axis_types=types)
+    return Mesh(np.asarray(devices).reshape(tuple(shape)), tuple(axes),
+                axis_types=types)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(n_data: int = 2, n_model: int = 2, *, pod: int = 0):
     """Small mesh for CPU tests (requires enough placeholder devices)."""
     if pod:
-        return jax.make_mesh((pod, n_data, n_model), ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
-
-
-def fit_mesh_shape(shape: Sequence[int], n_devices: int) -> Tuple[int, ...]:
-    """Largest valid submesh of ``shape`` that fits ``n_devices``.
-
-    Pure shape arithmetic (no device state) so it unit-tests without a
-    multi-device runtime. Axis sizes only ever shrink (an axis the
-    caller left at 1 stays 1), by repeatedly halving the largest
-    oversized axis — the power-of-two walk every TPU/CI topology uses —
-    until the product fits. Degenerate inputs clamp to 1 per axis.
-    """
-    if n_devices < 1:
-        raise ValueError(f"n_devices={n_devices} must be >= 1")
-    fitted = [max(1, int(s)) for s in shape]
-    while math.prod(fitted) > n_devices:
-        i = max(range(len(fitted)), key=lambda j: fitted[j])
-        if fitted[i] == 1:  # unreachable: prod of all-ones is 1
-            break
-        fitted[i] = max(1, fitted[i] // 2)
-    return tuple(fitted)
+        return _auto_mesh((pod, n_data, n_model), ("pod", "data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def parse_mesh_spec(spec: str) -> Tuple[int, ...]:
@@ -67,43 +57,32 @@ def carve_devices(prefill: int, decode: int,
     """Split the attached devices into disjoint prefill/decode pools.
 
     The first ``prefill`` devices feed the worker pool, the next
-    ``decode`` the resident decode mesh. When the box is too small the
-    pools overlap round-robin (with a warning) instead of raising — the
-    handoff path still runs, it just moves bytes between colocated
-    buffers. Shared by :class:`repro.launch.workers.DisaggExecutor` and
-    its degraded-mode rebuilds, so a restarted worker always lands on the
-    same carve."""
+    ``decode`` the resident decode mesh. Raises when fewer devices are
+    attached than the two pools need. Shared by
+    :class:`repro.launch.workers.DisaggExecutor` and its degraded-mode
+    rebuilds, so a restarted worker always lands on the same carve."""
     devs = list(devices) if devices is not None else list(jax.devices())
     if prefill + decode > len(devs):
-        warnings.warn(
+        raise ValueError(
             f"disaggregated serving wants {prefill}+{decode} devices but "
-            f"only {len(devs)} are attached; pools will overlap",
-            stacklevel=2)
-    pdevs = [devs[i % len(devs)] for i in range(prefill)]
-    ddevs = [devs[(prefill + i) % len(devs)] for i in range(decode)]
-    return pdevs, ddevs
+            f"only {len(devs)} are attached")
+    return devs[:prefill], devs[prefill:prefill + decode]
 
 
 def make_serving_mesh(shape: Sequence[int] = (1, 1), *, devices=None):
     """Serving mesh over ``('data', 'model')`` (or ``('pod', 'data',
-    'model')`` for 3 axes), clamped to the attached devices.
+    'model')`` for 3 axes) on the first ``prod(shape)`` devices.
 
-    When ``prod(shape)`` exceeds the device count, falls back to the
-    largest valid submesh (:func:`fit_mesh_shape`) and warns — callers
-    get a working (possibly smaller) mesh instead of a raise from inside
-    a jitted computation whose error message never mentions devices.
+    Raises when fewer devices are attached than the shape needs: a
+    smaller mesh would serve on fewer chips than the caller asked for.
     ``devices`` narrows the pool to an explicit device list (the
     disaggregated server carves prefill/decode pools this way).
     """
     devs = list(devices) if devices is not None else list(jax.devices())
-    fitted = fit_mesh_shape(shape, len(devs))
-    if fitted != tuple(shape):
-        warnings.warn(
-            f"requested mesh {tuple(shape)} needs {math.prod(shape)} "
-            f"devices but only {len(devs)} are attached; falling back to "
-            f"the largest valid submesh {fitted}", stacklevel=2)
-    axes = ("pod", "data", "model")[-len(fitted):]
-    import numpy as np
-    from jax.sharding import Mesh
-    n = math.prod(fitted)
-    return Mesh(np.asarray(devs[:n]).reshape(fitted), axes)
+    n = math.prod(shape)
+    if n > len(devs):
+        raise ValueError(
+            f"mesh {tuple(shape)} needs {n} devices but only {len(devs)} "
+            f"are attached")
+    axes = ("pod", "data", "model")[-len(shape):]
+    return _auto_mesh(shape, axes, devices=devs[:n])

@@ -23,6 +23,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..core.backend import use_compile_cache
 from ..core.ppac import PPACConfig
 from ..retrieval.index import CAMIndex
 from .bucketed import BucketedBatchServer
@@ -78,28 +79,39 @@ def main():
                     help="print the telemetry registry (Prometheus text) "
                          "after the run")
     args = ap.parse_args()
+    use_compile_cache()
+    serve_planted_lookups(args.m, args.bits, requests=args.requests,
+                          k=args.k, backend=args.backend, flip=args.flip,
+                          show_metrics=args.metrics)
+    print("OK")
 
-    rng = np.random.default_rng(0)
-    index = CAMIndex(args.bits, config=PPACConfig(),
-                     backend=args.backend, min_capacity=args.m)
+
+def serve_planted_lookups(m: int, bits: int, *, requests: int, k: int,
+                          backend: str = "auto", flip: int = 8,
+                          seed: int = 0, show_metrics: bool = False) -> dict:
+    """Load ``m`` random ``bits``-wide codes, look up ``requests`` copies
+    of planted rows with ``flip`` bits flipped, and assert recall@1 >=
+    0.99. Returns the run's counts and host-clock seconds."""
+    rng = np.random.default_rng(seed)
+    index = CAMIndex(bits, config=PPACConfig(), backend=backend,
+                     min_capacity=m)
     # bulk load random codes straight in packed form (bits = 32*W exactly)
     w = index.w
-    if args.bits == 32 * w:
-        index.add_packed(rng.integers(0, 2**32, (args.m, w), dtype=np.uint64)
+    if bits == 32 * w:
+        index.add_packed(rng.integers(0, 2**32, (m, w), dtype=np.uint64)
                          .astype(np.uint32))
     else:
-        index.add(rng.integers(0, 2, (args.m, args.bits)))
+        index.add(rng.integers(0, 2, (m, bits)))
 
-    server = RetrievalServer(index, max_k=args.k)
-    targets = rng.integers(0, args.m, args.requests)
+    server = RetrievalServer(index, max_k=k)
+    targets = rng.integers(0, m, requests)
     from ..core.formats import unpack_bits
 
-    db_bits = np.asarray(unpack_bits(index._codes[targets], args.bits))
-    for i in range(args.requests):
+    db_bits = np.asarray(unpack_bits(index._codes[targets], bits))
+    for i in range(requests):
         code = db_bits[i].copy()
-        flip = rng.choice(args.bits, size=args.flip, replace=False)
-        code[flip] ^= 1
-        server.submit(LookupRequest(i, code, k=args.k))
+        code[rng.choice(bits, size=flip, replace=False)] ^= 1
+        server.submit(LookupRequest(i, code, k=k))
 
     cycles0 = index.counter.cycles
     t0 = time.perf_counter()
@@ -113,12 +125,13 @@ def main():
           f"buckets={ {b: c for b, c in server.bucket_counts.items() if c} })")
     print(f"emulated PPAC cycles: {cycles} total, "
           f"{cycles / len(done):.1f}/query")
-    print(f"recall@1 vs planted rows ({args.flip}/{args.bits} bits flipped): "
+    print(f"recall@1 vs planted rows ({flip}/{bits} bits flipped): "
           f"{hits / len(done):.3f}")
+    assert len(done) == requests, (len(done), requests)
     assert hits / len(done) >= 0.99, "planted neighbors must be retrieved"
-    if args.metrics:
+    if show_metrics:
         print(server.metrics.prometheus_text(), end="")
-    print("OK")
+    return dict(served=len(done), recall_at_1=hits / len(done), seconds=dt)
 
 
 if __name__ == "__main__":

@@ -8,25 +8,18 @@ the PPAC quantization / cycle-accounting / autotune flags) and the
 ``BatchServer`` name for existing callers.
 
 CLI: PYTHONPATH=src python -m repro.launch.serve --arch smollm_360m \
-        --requests 12 --max-new 16 [--serve-quant] [--weight-bits 4] \
+        [--full] --requests 12 --max-new 16 [--serve-quant] [--weight-bits 4] \
         [--kv-int8] [--autotune]
 """
 from __future__ import annotations
 
 import argparse
-import dataclasses
 
-import jax
 import numpy as np
 
-from ..configs.base import load_arch
-from ..models import lm
-from ..serve.step import (
-    autotune_serving_plans,
-    convert_params_for_serving,
-    serving_cycle_report,
-)
-from .serve_lm import LMServer, Request, run_and_report
+from ..core.backend import use_compile_cache
+from ..serve.step import autotune_serving_plans
+from .serve_lm import LMServer, Request, build_lm_server, run_and_report
 
 # Back-compat: the slot-based server moved to serve_lm and grew bucketed
 # admission + donated-cache residency; the old name stays importable.
@@ -36,6 +29,8 @@ BatchServer = LMServer
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm_360m")
+    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--full", dest="smoke", action="store_false")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=12)
@@ -49,30 +44,23 @@ def main():
     ap.add_argument("--autotune", action="store_true",
                     help="measure + persist tile plans for every packed "
                          "projection shape before serving (refreshes the "
-                         "PPAC_TILE_CACHE json; meaningful on TPU)")
+                         "PPAC_TILE_CACHE json, which must be set; "
+                         "meaningful on TPU)")
     args = ap.parse_args()
 
-    cfg = load_arch(args.arch).smoke()
-    if args.kv_int8:
-        cfg = dataclasses.replace(cfg, kv_dtype="int8")
-    params, _ = lm.init(cfg, jax.random.PRNGKey(0))
-    mode = "float"
-    report = None
-    if args.serve_quant:
-        cfg = dataclasses.replace(
-            cfg, ppac=dataclasses.replace(cfg.ppac, enabled=True,
-                                          weight_bits=args.weight_bits,
-                                          act_bits=8, min_features=32,
-                                          backend="auto"))
-        params = convert_params_for_serving(params, cfg)
-        mode = "serve"
+    use_compile_cache()
+    server, report = build_lm_server(
+        args.arch, full=not args.smoke, serve_quant=args.serve_quant,
+        weight_bits=args.weight_bits, kv_int8=args.kv_int8,
+        slots=args.slots)
+    cfg = server.cfg
+    if report is not None:
         if args.autotune:
             from ..kernels.tiling import plan_cache
-            tuned = autotune_serving_plans(params, cfg, batch=args.slots,
-                                           verbose=True)
+            tuned = autotune_serving_plans(server.params, cfg,
+                                           batch=args.slots, verbose=True)
             print(f"autotuned {len(tuned)} tile plans -> "
                   f"{plan_cache().path}")
-        report = serving_cycle_report(params, cfg)
         est = report.est_us_per_token()
         # K/L from the accounting itself: packed1 binarizes activations, so
         # its bit-serial schedule is 1x1 regardless of act_bits.
@@ -89,7 +77,6 @@ def main():
                  if est is not None else ""))
 
     rng = np.random.default_rng(0)
-    server = LMServer(cfg, params, slots=args.slots, mode=mode)
     run_and_report(
         server,
         [Request(i, rng.integers(0, cfg.vocab, int(rng.integers(4, 24))),

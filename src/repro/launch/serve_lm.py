@@ -50,7 +50,7 @@ quarantines any page whose recomputed tag drifts before decode can read
 it. With no plan and no CRC flags the serving path is unchanged.
 
 CLI: PYTHONPATH=src python -m repro.launch.serve_lm --arch smollm_360m \
-        --requests 12 --max-new 16 [--serve-quant --weight-bits 4] \
+        [--full] --requests 12 --max-new 16 [--serve-quant --weight-bits 4] \
         [--kv-int8] [--temperature 0.8 --top-k 40] [--eos 0] \
         [--paged --page-size 16 --pool-pages 64 --prefix-cache] \
         [--fault-plan 'crash:prefill:0:worker=p0;flip:step:3' \
@@ -71,6 +71,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import ModelConfig, load_arch
+from ..core.backend import use_compile_cache
 from ..models import lm
 from ..obs.metrics import MetricsRegistry
 from ..obs.trace import TraceBuilder, annotate
@@ -1089,9 +1090,47 @@ def chaos_check(server: LMServer) -> List[str]:
     return problems
 
 
+def build_lm_server(arch: str, *, full: bool = False,
+                    serve_quant: bool = False, weight_bits: int = 4,
+                    kv_int8: bool = False, backend: str = "auto",
+                    spec_decode: bool = False, **server_kw):
+    """Config -> random weights -> resident serving containers -> server.
+
+    ``arch`` names a module of ``repro.configs``; ``full`` picks its
+    published widths instead of the smoke preset. Weights are random,
+    drawn from seed 0. ``serve_quant`` packs every eligible
+    projection at ``weight_bits`` (1..4 bits: packed bitplanes on the PPAC
+    kernels, 8: int8 rows) with 8-bit activations, launched on the kernel
+    ``backend`` ('auto' = Pallas on TPU). ``server_kw`` goes to
+    :class:`LMServer`. Returns (server, PPAC cycle report or None).
+    """
+    mod = load_arch(arch)
+    cfg = mod.full() if full else mod.smoke()
+    if kv_int8:
+        cfg = dataclasses.replace(cfg, kv_dtype="int8")
+    params, _ = lm.init(cfg, jax.random.PRNGKey(0))
+    mode, report = "float", None
+    if serve_quant:
+        cfg = dataclasses.replace(
+            cfg, ppac=dataclasses.replace(cfg.ppac, enabled=True,
+                                          weight_bits=weight_bits,
+                                          act_bits=8, min_features=32,
+                                          backend=backend))
+        params = convert_params_for_serving(params, cfg, draft=spec_decode)
+        mode = "serve"
+        report = serving_cycle_report(params, cfg)
+    server = LMServer(cfg, params, mode=mode, spec_decode=spec_decode,
+                      **server_kw)
+    return server, report
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm_360m")
+    ap.add_argument("--smoke", action="store_true", default=True,
+                    help="the architecture's reduced test preset (default)")
+    ap.add_argument("--full", dest="smoke", action="store_false",
+                    help="the architecture's published widths and depth")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=12)
@@ -1127,9 +1166,8 @@ def main():
                          "pages instead of re-prefilling them")
     ap.add_argument("--mesh", default=None, metavar="DxM",
                     help="shard the resident server over a device mesh, "
-                         "e.g. '2x2' (data x model); falls back to the "
-                         "largest valid submesh when fewer devices are "
-                         "attached")
+                         "e.g. '2x2' (data x model); raises when fewer "
+                         "devices are attached")
     ap.add_argument("--prefill-devices", type=int, default=0,
                     help="disaggregated serving: devices for the prefill "
                          "worker pool (disjoint from decode)")
@@ -1171,22 +1209,6 @@ def main():
                          "caught by the scrub")
     args = ap.parse_args()
 
-    cfg = load_arch(args.arch).smoke()
-    if args.kv_int8:
-        cfg = dataclasses.replace(cfg, kv_dtype="int8")
-    params, _ = lm.init(cfg, jax.random.PRNGKey(0))
-    mode, report = "float", None
-    if args.serve_quant:
-        cfg = dataclasses.replace(
-            cfg, ppac=dataclasses.replace(cfg.ppac, enabled=True,
-                                          weight_bits=args.weight_bits,
-                                          act_bits=8, min_features=32,
-                                          backend="auto"))
-        params = convert_params_for_serving(params, cfg,
-                                            draft=args.spec_decode)
-        mode = "serve"
-        report = serving_cycle_report(params, cfg)
-
     faults = None
     if args.fault_plan:
         faults = FaultPlan.parse(args.fault_plan)
@@ -1194,20 +1216,24 @@ def main():
         faults = FaultPlan.seeded(args.fault_seed,
                                   n_requests=args.requests)
 
+    use_compile_cache()
     mesh = (make_serving_mesh(parse_mesh_spec(args.mesh))
             if args.mesh else None)
-    server = LMServer(cfg, params, slots=args.slots, max_seq=args.max_seq,
-                      mode=mode, temperature=args.temperature,
-                      top_k=args.top_k, seed=args.seed, paged=args.paged,
-                      page_size=args.page_size, pool_pages=args.pool_pages,
-                      prefix_cache=args.prefix_cache,
-                      spec_decode=args.spec_decode, draft_k=args.draft_k,
-                      mesh=mesh, prefill_devices=args.prefill_devices,
-                      decode_devices=args.decode_devices,
-                      prefill_workers=args.prefill_workers,
-                      faults=faults, max_retries=args.max_retries,
-                      max_worker_restarts=args.max_worker_restarts,
-                      kv_crc=args.kv_crc, scrub_every=args.scrub_every)
+    server, report = build_lm_server(
+        args.arch, full=not args.smoke, serve_quant=args.serve_quant,
+        weight_bits=args.weight_bits, kv_int8=args.kv_int8,
+        slots=args.slots, max_seq=args.max_seq,
+        temperature=args.temperature, top_k=args.top_k, seed=args.seed,
+        paged=args.paged, page_size=args.page_size,
+        pool_pages=args.pool_pages, prefix_cache=args.prefix_cache,
+        spec_decode=args.spec_decode, draft_k=args.draft_k, mesh=mesh,
+        prefill_devices=args.prefill_devices,
+        decode_devices=args.decode_devices,
+        prefill_workers=args.prefill_workers, faults=faults,
+        max_retries=args.max_retries,
+        max_worker_restarts=args.max_worker_restarts, kv_crc=args.kv_crc,
+        scrub_every=args.scrub_every)
+    cfg = server.cfg
     rng = np.random.default_rng(0)
     run_and_report(
         server,

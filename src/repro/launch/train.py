@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from ..checkpoint.ckpt import latest_step, restore, save
 from ..configs.base import InputShape, load_arch
+from ..core.backend import use_compile_cache
 from ..data.pipeline import DataConfig, DataIterator
 from ..optim.adamw import AdamWConfig
 from ..sharding.rules import ShardingRules, fitted_shardings
@@ -88,6 +89,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=50)
     args = ap.parse_args()
+    use_compile_cache()
 
     mod = load_arch(args.arch)
     cfg = mod.smoke() if args.smoke else mod.full()

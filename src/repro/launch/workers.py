@@ -158,7 +158,7 @@ class _DecodeSide:
         while a mesh is active."""
         if self.mesh is None:
             return contextlib.nullcontext()
-        return self.mesh
+        return jax.set_mesh(self.mesh)
 
     def _tag(self):
         """Ledger worker attribution for the dispatches inside; the empty
@@ -174,7 +174,7 @@ class _DecodeSide:
         dims fall back to replicated (``fit_spec``)."""
         if self.mesh is None:
             return cache
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             sh = fitted_shardings(self.mesh, self.rules, axes, cache)
             return jax.device_put(cache, sh)
 
@@ -384,7 +384,8 @@ class PrefillWorker:
         self._fire("prefill")
         blen = int(toks.shape[0])
         t0 = time.perf_counter()
-        with self.mesh, _flight.phase("", window=0, worker=self.wid):
+        with jax.set_mesh(self.mesh), _flight.phase("", window=0,
+                                                   worker=self.wid):
             c1, _ = lm.init_cache(self.cfg, blen, self.max_seq)
             tok0, c1 = self._prefill(self.params, toks, lens, c1, key)
             tok0 = np.asarray(tok0)
@@ -405,7 +406,8 @@ class PrefillWorker:
                             np.int32(blen))
         starts = np.zeros((blen,), np.int32)
         t0 = time.perf_counter()
-        with self.mesh, _flight.phase("", window=0, worker=self.wid):
+        with jax.set_mesh(self.mesh), _flight.phase("", window=0,
+                                                   worker=self.wid):
             c1, _ = lm.init_cache(self.cfg, blen, self.max_seq,
                                   page_size=self.page_size,
                                   pool_pages=pool, **self._ckw)
@@ -425,7 +427,7 @@ class PrefillWorker:
         return out
 
     def extract_row(self, cache, row):
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             return self._extract_row(cache, jnp.int32(row))
 
     def _account(self, t0: float):

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..configs.base import ModelConfig
-from ..sharding.rules import constrain, constrain_fitted
+from ..sharding.rules import constrain
 from .layers import (
     dense_apply,
     dense_init,
@@ -661,7 +661,7 @@ def gqa_apply(p, x, cfg: ModelConfig, *, positions, cache=None, pos=None,
         # (a device-local cache-sized copy every step).
         cax = ((None, None, "kv_heads", None) if paged
                else GQA_CACHE_AXES["k"])
-        new_cache = {kk: constrain_fitted(vv, rules, *cax)
+        new_cache = {kk: constrain(vv, rules, *cax)
                      for kk, vv in new_cache.items()}
     return y, new_cache
 
@@ -835,6 +835,6 @@ def mla_apply(p, x, cfg: ModelConfig, *, positions, cache=None, pos=None,
     if cache is not None and rules is not None:
         # Same strict-aliasing contract as the GQA path (see gqa_apply).
         cax = ((None, None, None) if paged else MLA_CACHE_AXES["kv_c"])
-        new_cache = {kk: constrain_fitted(vv, rules, *cax)
+        new_cache = {kk: constrain(vv, rules, *cax)
                      for kk, vv in new_cache.items()}
     return y, new_cache

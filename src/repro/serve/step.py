@@ -37,6 +37,19 @@ from ..models import lm
 from ..sharding.rules import ShardingRules
 
 
+# Every serving program keeps each bf16 rounding the model writes. XLA may
+# otherwise skip a bf16 round trip inside a fusion, and how it fuses
+# depends on the batch shape: on TPU a row then rounds differently in a
+# batch of 8 than in a batch of 4, so a data-parallel mesh (B/n rows per
+# device) would serve other tokens than one device.
+SERVE_COMPILER_OPTIONS = {"xla_allow_excess_precision": False}
+
+
+def _serve_jit(fn, donate_argnums=()):
+    return jax.jit(fn, donate_argnums=donate_argnums,
+                   compiler_options=SERVE_COMPILER_OPTIONS)
+
+
 def _maybe_cached(factory):
     """lru-cache a jitted-entry-point factory on its hashable args.
 
@@ -61,7 +74,7 @@ def _prefill_step_cached(cfg, rules, mode, donate):
     def prefill_step(params, batch, cache, lengths=None):
         return lm.prefill(params, cfg, batch, cache, lengths=lengths,
                           mode=mode, rules=rules)
-    return jax.jit(prefill_step, donate_argnums=(2,) if donate else ())
+    return _serve_jit(prefill_step, donate_argnums=(2,) if donate else ())
 
 
 def make_prefill_step(cfg: ModelConfig, rules: Optional[ShardingRules] = None,
@@ -86,7 +99,7 @@ def _decode_step_cached(cfg, rules, mode, donate):
     def decode_step(params, tokens, cache):
         return lm.decode_step(params, cfg, tokens, cache, mode=mode,
                               rules=rules)
-    return jax.jit(decode_step, donate_argnums=(2,) if donate else ())
+    return _serve_jit(decode_step, donate_argnums=(2,) if donate else ())
 
 
 def make_decode_step(cfg: ModelConfig, rules: Optional[ShardingRules] = None,
@@ -139,7 +152,7 @@ def _decode_select_cached(cfg, rules, mode, temperature, top_k, donate):
         nxt = sample_tokens(logits[:, -1], key, temperature=temperature,
                             top_k=top_k)
         return nxt, cache
-    return jax.jit(step, donate_argnums=(2,) if donate else ())
+    return _serve_jit(step, donate_argnums=(2,) if donate else ())
 
 
 def make_decode_select_step(cfg: ModelConfig,
@@ -167,7 +180,7 @@ def _prefill_select_cached(cfg, rules, mode, temperature, top_k, paged,
             tok = sample_tokens(logits[:, -1], key, temperature=temperature,
                                 top_k=top_k)
             return tok, cache
-        return jax.jit(step, donate_argnums=(3,) if donate else ())
+        return _serve_jit(step, donate_argnums=(3,) if donate else ())
 
     def step(params, tokens, lengths, starts, slot_ids, table_rows, cache,
              key):
@@ -178,7 +191,7 @@ def _prefill_select_cached(cfg, rules, mode, temperature, top_k, paged,
         tok = sample_tokens(logits[:, -1], key, temperature=temperature,
                             top_k=top_k)
         return tok, cache
-    return jax.jit(step, donate_argnums=(6,) if donate else ())
+    return _serve_jit(step, donate_argnums=(6,) if donate else ())
 
 
 def make_prefill_select_step(cfg: ModelConfig,
@@ -249,7 +262,7 @@ def _generate_scan_cached(cfg, steps, rules, mode, temperature, top_k,
         (last, cache, _), toks = lax.scan(body, (tok0, cache, key), None,
                                           length=steps)
         return jnp.moveaxis(toks, 0, 1), cache
-    return jax.jit(gen, donate_argnums=(2,) if donate else ())
+    return _serve_jit(gen, donate_argnums=(2,) if donate else ())
 
 
 def make_generate_scan(cfg: ModelConfig, *, steps: int,
@@ -432,7 +445,7 @@ def _speculative_decode_step_cached(cfg, rules, mode, draft_k, temperature,
         return _spec_round(params, cfg, tok, cache, key, draft_k=draft_k,
                            mode=mode, rules=rules, temperature=temperature,
                            top_k=top_k)
-    return jax.jit(step, donate_argnums=(2,) if donate else ())
+    return _serve_jit(step, donate_argnums=(2,) if donate else ())
 
 
 def make_speculative_decode_step(cfg: ModelConfig,
@@ -483,7 +496,7 @@ def _speculative_scan_cached(cfg, steps, draft_k, rules, mode, temperature,
         _, cache, _, out, _ = lax.while_loop(cond, body,
                                              (tok0, cache, key, out, off))
         return out[:, :steps], cache
-    return jax.jit(gen, donate_argnums=(2,) if donate else ())
+    return _serve_jit(gen, donate_argnums=(2,) if donate else ())
 
 
 def make_speculative_scan(cfg: ModelConfig, *, steps: int, draft_k: int = 4,

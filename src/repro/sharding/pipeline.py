@@ -23,7 +23,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from .compat import shard_map
 
@@ -67,6 +67,11 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, *, mesh: Mesh,
         outs = lax.psum(outs, axis)
         return outs
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(axis), P()), out_specs=P())
+    # an all-Auto view of the mesh: the result comes back untyped by any
+    # mesh axis, so the caller's eager ops and their transposes under
+    # jax.grad need no mesh context
+    auto = Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+    fn = shard_map(body, mesh=auto, in_specs=(P(axis), P()), out_specs=P())
     y = fn(stage_params, xs)
     return y.reshape((b,) + x.shape[1:])

@@ -11,7 +11,7 @@ import dataclasses
 from typing import Dict, Optional, Tuple, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 MeshAxes = Union[None, str, Tuple[str, ...]]
 
@@ -145,34 +145,39 @@ def fitted_shardings(mesh: Mesh, rules: ShardingRules, axes_tree, shapes_tree):
     return jax.tree.map(one, axes_tree, shapes_tree, is_leaf=is_ax)
 
 
-def constrain(x, rules: ShardingRules, *logical_axes):
-    """with_sharding_constraint by logical axes (no-op outside mesh ctx)."""
-    try:
-        return jax.lax.with_sharding_constraint(x, rules.spec(logical_axes))
-    except Exception:
-        return x
-
-
 def active_mesh():
-    """The physical mesh of the enclosing ``with mesh:`` block, or None."""
-    try:
-        m = jax.interpreters.pxla.thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:
-        return None
+    """The mesh of the enclosing ``jax.set_mesh`` context, or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
-def constrain_fitted(x, rules: ShardingRules, *logical_axes):
-    """Like :func:`constrain`, but drops mesh axes that do not divide the
-    dimension (mirrors :func:`fit_spec`). Donated buffers only alias
-    strictly when the traced output sharding matches the fitted input
-    placement, so in-place cache updates must constrain with the same
-    divisibility rule the placement used. No-op outside a mesh context."""
+def _drop_manual(mesh, spec: PartitionSpec) -> PartitionSpec:
+    """``spec`` without the mesh axes the context holds Manual: inside a
+    shard_map body over some axes, only the others can be constrained."""
+    manual = {n for n, t in zip(mesh.axis_names, mesh.axis_types)
+              if t == AxisType.Manual}
+    if not manual:
+        return spec
+    out = []
+    for ax in spec:
+        axes = tuple(a for a in ((ax,) if isinstance(ax, str) else ax or ())
+                     if a not in manual)
+        out.append(None if not axes else axes[0] if len(axes) == 1 else axes)
+    return PartitionSpec(*out)
+
+
+def constrain(x, rules: Optional[ShardingRules], *logical_axes):
+    """with_sharding_constraint by logical axes; a no-op without rules or
+    outside a mesh context. Mesh axes that do not divide the dimension are
+    dropped, as :func:`fit_spec` drops them from placements: a donated
+    buffer only aliases strictly when the traced output sharding matches
+    its fitted placement, and an evenly split head or batch dim keeps the
+    per-device float program the same as on one device, where a padded
+    split would change its shapes and so its summation order. A
+    constraint the mesh rejects raises."""
     mesh = active_mesh()
     if mesh is None or rules is None:
         return x
-    try:
-        spec = fit_spec(mesh, rules.spec(logical_axes), tuple(x.shape))
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
-        return x
+    spec = fit_spec(mesh, _drop_manual(mesh, rules.spec(logical_axes)),
+                    tuple(x.shape))
+    return jax.lax.with_sharding_constraint(x, spec)

@@ -35,15 +35,14 @@ def test_binary_kernel_shapes(rng, b, m, n, op):
     assert np.array_equal(got, ref)
 
 
-@pytest.mark.parametrize("blocks", [(8, 8, 128, 8), (16, 32, 128, 16),
-                                    (64, 128, 256, 8)])
+@pytest.mark.parametrize("blocks", [(8, 8, 128), (16, 32, 128),
+                                    (64, 128, 256)])
 def test_binary_kernel_block_sweep(rng, blocks):
-    bb, bm, bw, rc = blocks
+    bb, bm, bw = blocks
     x = F.pack_bits(rng.integers(0, 2, (21, 300)))
     a = F.pack_bits(rng.integers(0, 2, (50, 300)))
     got = np.asarray(binary_matmul_packed(
-        x, a, op="xor", block_b=bb, block_m=bm, block_w=bw, row_chunk=rc,
-        interpret=True))
+        x, a, op="xor", block_b=bb, block_m=bm, block_w=bw, interpret=True))
     ref = np.asarray(binary_matmul_packed_ref(x, a, op="xor"))
     assert np.array_equal(got, ref)
 

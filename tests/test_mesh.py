@@ -1,5 +1,6 @@
-"""Serving-mesh construction: pure submesh fitting, mesh-spec parsing,
-and the graceful fallback on a real (forced-host) 4-device runtime."""
+"""Serving-mesh construction: mesh-spec parsing, and on a real
+(forced-host) 4-device runtime the exact mesh or a raise — never a
+smaller mesh than asked."""
 import subprocess
 import sys
 import textwrap
@@ -7,41 +8,7 @@ import textwrap
 import pytest
 from conftest import cpu_subproc_env
 
-from repro.launch.mesh import fit_mesh_shape, parse_mesh_spec
-
-
-def test_fit_mesh_shape_identity_when_it_fits():
-    assert fit_mesh_shape((2, 2), 4) == (2, 2)
-    assert fit_mesh_shape((1, 1), 1) == (1, 1)
-    assert fit_mesh_shape((4,), 8) == (4,)
-
-
-def test_fit_mesh_shape_halves_largest_axis():
-    # 16x16 on 4 devices: the power-of-two walk lands on 2x2
-    assert fit_mesh_shape((16, 16), 4) == (2, 2)
-    # asymmetric: the bigger axis gives first
-    assert fit_mesh_shape((8, 2), 4) == (2, 2)
-    assert fit_mesh_shape((2, 8), 4) == (2, 2)
-    # 3-axis pods shrink the same way
-    assert fit_mesh_shape((2, 16, 16), 8) == (2, 2, 2)
-
-
-def test_fit_mesh_shape_clamps_degenerate_inputs():
-    # 3 halves to 1 (the walk stays on the power-of-two lattice)
-    assert fit_mesh_shape((0, 3), 2) == (1, 1)
-    assert fit_mesh_shape((7, 1), 1) == (1, 1)
-    with pytest.raises(ValueError):
-        fit_mesh_shape((2, 2), 0)
-
-
-def test_fit_mesh_shape_axes_only_shrink():
-    # an axis the caller left at 1 must stay 1 (pure-TP and pure-DP
-    # requests keep their meaning after the fallback)
-    for shape in ((1, 8), (8, 1)):
-        fitted = fit_mesh_shape(shape, 4)
-        for orig, new in zip(shape, fitted):
-            assert new <= orig
-        assert fitted[shape.index(1)] == 1
+from repro.launch.mesh import parse_mesh_spec
 
 
 def test_parse_mesh_spec():
@@ -59,23 +26,34 @@ SUBPROC_FALLBACK = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import warnings
     import jax
-    from repro.launch.mesh import make_serving_mesh
+    from jax.sharding import AxisType
+    from repro.launch.mesh import carve_devices, make_serving_mesh
 
-    # exact fit: no warning, requested shape honored
+    # exact fit: no warning, requested shape honored, Auto axes
     with warnings.catch_warnings():
         warnings.simplefilter("error")
         mesh = make_serving_mesh((2, 2))
     assert dict(mesh.shape) == {"data": 2, "model": 2}, mesh.shape
     assert mesh.axis_names == ("data", "model")
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
 
-    # oversubscribed: falls back to the largest valid submesh with a
-    # warning instead of raising from inside a jitted computation
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        mesh = make_serving_mesh((8, 8))
-    assert dict(mesh.shape) == {"data": 2, "model": 2}, mesh.shape
-    assert any("largest valid submesh" in str(x.message) for x in w), \\
-        [str(x.message) for x in w]
+    # oversubscribed: raises instead of serving on fewer devices
+    try:
+        make_serving_mesh((8, 8))
+    except ValueError as e:
+        assert "needs 64 devices but only 4" in str(e), e
+    else:
+        raise AssertionError("oversubscribed mesh did not raise")
+
+    # the disaggregated carve raises too instead of overlapping pools
+    p, d = carve_devices(2, 2)
+    assert not set(p) & set(d) and len(p) == len(d) == 2
+    try:
+        carve_devices(3, 2)
+    except ValueError as e:
+        assert "3+2 devices but only 4" in str(e), e
+    else:
+        raise AssertionError("oversubscribed carve did not raise")
 
     # explicit device list narrows the pool (the disaggregated server
     # carves prefill/decode slices this way)

@@ -9,7 +9,7 @@ import pytest
 from repro.configs import load_arch
 from repro.launch.bucketed import bucket_for, drain_take
 from repro.launch.serve import BatchServer, Request
-from repro.launch.serve_lm import LMServer
+from repro.launch.serve_lm import LMServer, build_lm_server
 from repro.models import lm
 from repro.serve.step import greedy_generate
 
@@ -27,6 +27,23 @@ def test_server_completes_all_requests():
     assert len(done) == 5
     assert all(len(r.out) >= 5 for r in done)
     assert all(0 <= t < cfg.vocab for r in done for t in r.out)
+
+
+def test_build_lm_server_smoke_preset_packed4():
+    """The CLI's and the chip smoke's one construction path: the smoke
+    preset by default, every packed projection resident as packed4."""
+    server, report = build_lm_server("smollm_360m", serve_quant=True,
+                                     weight_bits=4, slots=2, max_seq=64)
+    assert server.cfg.d_model == load_arch("smollm_360m").smoke().d_model
+    kinds = {c.kind for c in jax.tree.leaves(
+        server.params, is_leaf=lambda x: hasattr(x, "kind"))
+        if hasattr(c, "kind")}
+    assert kinds == {"packed4"}, kinds
+    assert report is not None and report.projections
+    server.submit(Request(0, np.arange(1, 9, dtype=np.int32), max_new=3))
+    done = server.run()
+    assert len(done) == 1 and done[0].outcome == "completed"
+    assert len(done[0].out) == 3
 
 
 def test_server_single_request_matches_greedy():

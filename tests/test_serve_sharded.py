@@ -175,7 +175,8 @@ SUBPROC_DONATE = _PRELUDE % 4 + textwrap.dedent("""
         n_alias = len(re.findall(r"may-alias", hdr))
         assert n_alias >= n_leaves, (n_alias, n_leaves, hdr)
         assert "buffer_donor" not in hdr, hdr
-        assert txt.count("@Sharding") >= 1, "no sharding constraints?"
+        assert txt.count("sdy.sharding_constraint") >= 1, \
+            "no sharding constraints?"
         print("DONATE_OK", bool(kw))
     print("SHARDED_DONATION_OK")
 """)

@@ -156,15 +156,16 @@ SUBPROC_DECODE = textwrap.dedent("""
 
     mesh = make_test_mesh(1, 2)  # pure TP: 2-way 'model'
     rules = default_rules().for_mesh(mesh)
-    with mesh:
+    with jax.set_mesh(mesh):
         cache2, _ = lm.init_cache(cfg, 2, 32)
         _, cache2 = lm.prefill(params, cfg, {"tokens": tokens}, cache2,
                                rules=rules)
         dec = make_decode_step(cfg, rules=rules, donate=False)
         txt = dec.lower(params, tok, cache2).as_text()
         # the q8 decode einsums must be constrained (satellite fix):
-        # constraints lower to Sharding custom-calls in the StableHLO
-        assert txt.count("@Sharding") >= 4, txt.count("@Sharding")
+        # constraints lower to sdy.sharding_constraint ops in the StableHLO
+        n = txt.count("sdy.sharding_constraint")
+        assert n >= 4, n
         got, _ = dec(params, tok, cache2)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
                                rtol=2e-5, atol=2e-5)
@@ -180,3 +181,35 @@ def test_sharded_decode_parity_and_constraints():
                          capture_output=True, text=True, timeout=600,
                          env=cpu_subproc_env())
     assert "DECODE_SHARDED_OK" in res.stdout, res.stdout + res.stderr
+
+
+SUBPROC_SPLIT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import functools
+    import jax, jax.numpy as jnp
+    import numpy as np
+    from repro.kernels.bitserial_mvp.ops import ppac_matmul_resident
+    from repro.launch.mesh import make_serving_mesh
+
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.integers(-128, 128, (8, 100)), jnp.int32)
+    a = jnp.asarray(rng.integers(0, 2**32, (4, 64, 4), dtype=np.uint32))
+    f = jax.jit(functools.partial(ppac_matmul_resident, n=100, k_bits=4,
+                                  l_bits=8, backend="pallas"))
+    want = np.asarray(f(x, a))
+    with jax.set_mesh(make_serving_mesh((2, 2))):
+        # Mosaic kernels cannot be auto-partitioned: under a mesh the
+        # launch must be a shard_map over (data, model)
+        assert "shard_map" in str(jax.make_jaxpr(f)(x, a))
+        got = np.asarray(f(x, a))
+    assert np.array_equal(got, want)
+    print("SPLIT_OK")
+""")
+
+
+def test_pallas_launch_splits_over_mesh_bit_identically():
+    res = subprocess.run([sys.executable, "-c", SUBPROC_SPLIT],
+                         capture_output=True, text=True, timeout=600,
+                         env=cpu_subproc_env())
+    assert "SPLIT_OK" in res.stdout, res.stdout + res.stderr

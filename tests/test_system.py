@@ -16,6 +16,18 @@ from repro.serve.step import greedy_generate
 from repro.train.step import TrainConfig
 
 
+def test_chip_smoke_refuses_without_tpu():
+    """Without a TPU the chip smoke exits nonzero and prints no result."""
+    from conftest import cpu_subproc_env
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    res = subprocess.run([sys.executable, os.path.join(root, "chip_smoke.py")],
+                         capture_output=True, text=True, timeout=300,
+                         env=cpu_subproc_env())
+    assert res.returncode != 0
+    assert '"ok"' not in res.stdout, res.stdout
+    assert "found no TPU" in res.stderr, res.stderr
+
+
 def test_train_then_serve(tmp_path):
     """Full lifecycle: train a smoke model, checkpoint, reload, generate."""
     cfg = load_arch("smollm_360m").smoke()

@@ -28,16 +28,20 @@ from repro.kernels.tiling import (
     round_up,
 )
 
-# b=70 > block_b=64, m=300 > block_m=128, n=6000 -> W=188 > block_w -> the
-# default plans stream several tiles along every grid dimension.
-MULTI_TILE = (70, 300, 6000)
+# b=70 > block_b=64, m=300 > block_m=256, n=9000 -> W=282 > block_w=256 ->
+# the default plans stream several tiles along every grid dimension.
+MULTI_TILE = (70, 300, 9000)
 
 
 def test_plan_tiles_invariants():
     for b, m, w in [(1, 1, 1), (7, 9, 3), (70, 300, 188), (64, 128, 64)]:
         p = plan_tiles(b, m, w)
         assert p.bp % p.bb == 0 and p.mp % p.bm == 0 and p.wp % p.bw == 0
-        assert p.bm % p.rc == 0
+        # the TPU block rule: sublane tiles in 8s; lane-dim tiles (rows of
+        # the output, packed words of the operands) in 128s or whole
+        assert p.bb % 8 == 0
+        assert p.bm == p.mp or p.bm % 128 == 0
+        assert p.bw == p.wp or p.bw % 128 == 0
         assert p.bp >= b and p.mp >= m and p.wp >= w
         gb, gm, gw = p.grid
         assert gb * p.bb == p.bp and gm * p.bm == p.mp and gw * p.bw == p.wp
@@ -57,7 +61,7 @@ def test_binary_streamed_vs_whole_matrix(rng, op):
     x = F.pack_bits(rng.integers(0, 2, (b, n)))
     a = F.pack_bits(rng.integers(0, 2, (m, n)))
     wl = x.shape[1]
-    assert wl > 64  # more than one default lane tile
+    assert wl > 256  # more than one default lane tile
     streamed = np.asarray(binary_matmul_packed(x, a, op=op, interpret=True))
     whole = np.asarray(binary_matmul_packed(
         x, a, op=op, block_b=round_up(b, 8), block_m=round_up(m, 8),
@@ -68,7 +72,7 @@ def test_binary_streamed_vs_whole_matrix(rng, op):
 
 
 def test_bitserial_streamed_vs_whole_matrix(rng):
-    l1, k1, b, m, wl = 3, 2, 20, 140, 70  # wl > block_w=32 -> lane streaming
+    l1, k1, b, m, wl = 3, 2, 20, 300, 300  # > block_m, block_w: streaming
     xp = rng.integers(0, 2**32, (l1, b, wl), dtype=np.uint32)
     ap = rng.integers(0, 2**32, (k1, m, wl), dtype=np.uint32)
     w = rng.integers(-8, 8, (k1, l1)).astype(np.int32)
@@ -82,7 +86,7 @@ def test_bitserial_streamed_vs_whole_matrix(rng):
 
 
 def test_gf2_streamed_vs_whole_matrix(rng):
-    b, m, n = 24, 300, 9000  # W=282 > block_w=128 -> several lane tiles
+    b, m, n = 24, 300, 9000  # W=282 > block_w=256 -> several lane tiles
     x = F.pack_bits(rng.integers(0, 2, (b, n)))
     a = F.pack_bits(rng.integers(0, 2, (m, n)))
     wl = x.shape[1]
@@ -96,22 +100,20 @@ def test_gf2_streamed_vs_whole_matrix(rng):
 
 
 def test_plan_tiles_rounds_row_tile_up_to_chunk():
-    """A prime requested row tile used to silently degrade row_chunk to 1
-    (an 8x fatter popcount loop); now the tile rounds UP to honor the
-    requested chunk verbatim."""
-    p = plan_tiles(8, 100, 4, block_m=13, row_chunk=8)
-    assert p.rc == 8
-    assert p.bm == 16 and p.bm % p.rc == 0
-    # the rounded-up geometry still tiles cleanly and covers the rows
-    assert p.mp % p.bm == 0 and p.mp >= 100
-    # a chunk larger than the tile clamps to it, never to 1
-    p2 = plan_tiles(8, 4, 4, block_m=8, row_chunk=16)
-    assert p2.rc == p2.bm == 8
-    # chunks that don't divide 8 keep BOTH the chunk and the TPU sublane
-    # rule: the row tile lands on lcm(rc, 8)
-    p3 = plan_tiles(8, 100, 4, block_m=8, row_chunk=3)
-    assert p3.rc == 3 and p3.bm == 24
-    assert p3.bm % p3.rc == 0 and p3.bm % 8 == 0
+    """Row and lane tiles land on the TPU's 128-lane chunk: a request that
+    does not cover the extent rounds UP to a 128 multiple (a 64-word lane
+    tile once broke the block rule for every row wider than one tile),
+    and a tile that covers the extent is the whole unpadded extent."""
+    p = plan_tiles(8, 300, 4, block_m=13)
+    assert p.bm == 128 and p.mp == 384
+    # a rounded tile that covers the rows becomes the whole extent
+    p2 = plan_tiles(8, 100, 4, block_m=13)
+    assert p2.bm == p2.mp == 100
+    # lanes follow the same rule: 64 words round up to 128
+    p3 = plan_tiles(8, 8, 300, block_w=64)
+    assert p3.bw == 128 and p3.wp == 384
+    p4 = plan_tiles(8, 8, 80, block_w=64)
+    assert p4.bw == p4.wp == 80
 
 
 def test_prime_row_tile_result_unchanged(rng):
@@ -121,7 +123,7 @@ def test_prime_row_tile_result_unchanged(rng):
     a = F.pack_bits(rng.integers(0, 2, (37, 700)))
     ref = np.asarray(binary_matmul_packed_ref(x, a, op="xor"))
     got = np.asarray(binary_matmul_packed(x, a, op="xor", block_m=13,
-                                          row_chunk=8, interpret=True))
+                                          interpret=True))
     assert np.array_equal(got, ref)
 
 
@@ -146,13 +148,13 @@ def test_autotune_cache_roundtrip(rng, tmp_path, monkeypatch):
     """autotune_plan persists the winning blocks; plan_for and a fresh
     PlanCache instance both read them back."""
     monkeypatch.setenv("PPAC_TILE_CACHE", str(tmp_path / "plans.json"))
-    b, m, n = 4, 24, 300
+    b, m, n = 20, 24, 300
     wl = F.packed_width(n)
     xp = F.pack_bits(rng.integers(0, 2, (2, b, n)))
     ap = F.pack_bits(rng.integers(0, 2, (2, m, n)))
     w = rng.integers(-4, 4, (2, 2)).astype(np.int32)
-    candidates = [dict(block_b=8, block_m=8, block_w=32, row_chunk=4),
-                  dict(block_b=8, block_m=24, block_w=32, row_chunk=8)]
+    candidates = [dict(block_b=8, block_m=256, block_w=256),
+                  dict(block_b=32, block_m=256, block_w=256)]
 
     def run(plan):
         return bitserial_matmul_packed(xp, ap, w, interpret=True,
@@ -170,14 +172,15 @@ def test_autotune_cache_roundtrip(rng, tmp_path, monkeypatch):
     assert stored is not None
     assert plan_tiles(b, m, wl, **stored).blocks == tuned.blocks
     # explicit overrides still beat the cache
-    assert plan_for("bitserial", b, m, wl, row_chunk=2).rc == 2
+    assert plan_for("bitserial", b, m, wl, block_b=16).bb == 16
 
 
-def test_decode_defaults_use_thin_batch_tile():
-    p = plan_for("bitserial", 2, 512, 64, use_cache=False)
+def test_decode_defaults_use_thin_batch_tile(monkeypatch):
+    monkeypatch.delenv("PPAC_TILE_CACHE", raising=False)
+    p = plan_for("bitserial", 2, 512, 64)
     assert p.bb == 8          # decode-shaped: tiny batch tile
     assert p.bm >= 128        # ... traded for a fatter row tile
-    big = plan_for("bitserial", 128, 512, 64, use_cache=False)
+    big = plan_for("bitserial", 128, 512, 64)
     assert big.bb == 64
 
 
@@ -186,8 +189,8 @@ def test_binary_block_sweep_agrees(rng):
     x = F.pack_bits(rng.integers(0, 2, (13, 700)))
     a = F.pack_bits(rng.integers(0, 2, (37, 700)))
     ref = np.asarray(binary_matmul_packed_ref(x, a, op="xor"))
-    for bb, bm, bw, rc in [(8, 8, 128, 2), (16, 24, 128, 8), (64, 128, 16, 8)]:
+    for bb, bm, bw in [(8, 8, 128), (16, 24, 128), (64, 128, 16)]:
         got = np.asarray(binary_matmul_packed(
-            x, a, op="xor", block_b=bb, block_m=bm, block_w=bw, row_chunk=rc,
+            x, a, op="xor", block_b=bb, block_m=bm, block_w=bw,
             interpret=True))
-        assert np.array_equal(got, ref), (bb, bm, bw, rc)
+        assert np.array_equal(got, ref), (bb, bm, bw)
