@@ -582,7 +582,8 @@ def traced_smoke(*, arch: str = "smollm_360m", requests: int = 6,
     tests/test_obs.py, where no layer stacking is involved).
     """
     from repro.launch.serve_lm import LMServer, Request, run_and_report
-    from repro.obs import Ledger, MetricsRegistry, TraceBuilder
+    from repro.obs import Ledger, MetricsRegistry, TraceBuilder, span
+    from repro.obs.trace import attached
 
     max_seq = 64
     cfg = load_arch(arch).smoke()
@@ -599,8 +600,8 @@ def traced_smoke(*, arch: str = "smollm_360m", requests: int = 6,
         # -- golden gate: eager decode step vs the static cycle report
         cache, _ = lm.init_cache(cfg, slots, max_seq)
         toks = jnp.ones((slots, 1), jnp.int32)
-        with Ledger() as led, jax.disable_jit(), \
-                trace.span("eager_decode_golden", args=dict(slots=slots)):
+        with Ledger() as led, jax.disable_jit(), attached(trace), \
+                span("eager_decode_golden", slots=slots):
             lm.decode_step(params, cfg, toks, cache, mode="serve")
         per_layer = sum(p.cycles // p.count for p in report.projections)
         expect = slots * per_layer

@@ -27,12 +27,12 @@ server on a 2x2 (data x model) mesh and disaggregated over 2 prefill + 2
 decode chips, each compared with 1x1 token streams made in this process
 on one of the four chips. It needs four attached chips.
 
-Each phase prints its compile seconds (JAX's own compile-time events),
-requests completed and token parity; tokens/s lines are smoke readings on
-the host clock, not a benchmark. The last line of standard output is one
-JSON object, ``{"ok": true, "device": {...}}``, printed only when every
-phase passed. Without a TPU, or outside a checkout of this repository,
-the script exits nonzero and prints no result.
+Each phase prints its backend compile seconds (the ``obs`` compile
+counter), requests completed and token parity; tokens/s lines are smoke
+readings on the host clock, not a benchmark. The last line of standard
+output is one JSON object, ``{"ok": true, "device": {...}}``, printed only
+when every phase passed. Without a TPU, or outside a checkout of this
+repository, the script exits nonzero and prints no result.
 """
 from __future__ import annotations
 
@@ -64,25 +64,21 @@ def check(cond, msg: str) -> None:
         raise SmokeFailure(msg)
 
 
-class CompileClock:
-    """Seconds JAX spends compiling, from its own duration events."""
+def compile_clock():
+    """A function returning the backend compile seconds since its last
+    call, read from the ``obs`` compile counter (``lm_compile_s``, summed
+    over the spans it is labelled by)."""
+    from repro.obs.metrics import MetricsRegistry
+    from repro.obs.trace import install
+    reg = MetricsRegistry()
+    install(reg)
+    last = [0.0]
 
-    EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-              "/jax/core/compile/jaxpr_to_mlir_module_duration",
-              "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self):
-        import jax
-        self.seconds = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **_):
-        if event in self.EVENTS:
-            self.seconds += duration
-
-    def lap(self) -> float:
-        s, self.seconds = self.seconds, 0.0
+    def lap() -> float:
+        now = reg.total("lm_compile_s")
+        s, last[0] = now - last[0], now
         return s
+    return lap
 
 
 def make_requests(vocab: int, seed: int):
@@ -157,7 +153,7 @@ def phase_serve(clock, requests):
     print(f"[serve] {ARCH} full widths, packed4 "
           f"on pallas: "
           f"{len(outs)}/{len(requests)} requests completed, {toks} tokens; "
-          f"compile {clock.lap():.1f}s; {len(ledger.records)} packed launches "
+          f"compile {clock():.1f}s; {len(ledger.records)} packed launches "
           f"traced, all on pallas, 0 int8 fallback; warm rerun tokens "
           f"identical; smoke reading (host clock, not a benchmark): "
           f"cold {toks / cold_s:.1f} tok/s, warm {toks / warm_s:.1f} tok/s",
@@ -174,7 +170,7 @@ def phase_parity(clock, requests, pallas_outs):
                    f"({{request: first differing token}}): {bad}")
     print(f"[parity] ref backend: {len(outs)}/{len(requests)} requests "
           f"completed; greedy tokens identical to pallas for all "
-          f"{len(outs)} requests; compile {clock.lap():.1f}s", flush=True)
+          f"{len(outs)} requests; compile {clock():.1f}s", flush=True)
 
 
 def phase_cam(clock):
@@ -187,7 +183,7 @@ def phase_cam(clock):
     print(f"[cam] {CAM_M} x {CAM_BITS}-bit index, top-{CAM_K}: "
           f"{res['served']} lookups, "
           f"recall@1 {res['recall_at_1']:.3f} on pallas; compile "
-          f"{clock.lap():.1f}s", flush=True)
+          f"{clock():.1f}s", flush=True)
 
 
 def phase_coding(clock):
@@ -197,7 +193,7 @@ def phase_coding(clock):
           f"LDPC decodes ran on {res['backend']!r}, not the pallas kernels")
     print(f"[coding] 32x32 array code, 1 error/word: {res['served']} words, "
           f"{res['recovered']} recovered on pallas; compile "
-          f"{clock.lap():.1f}s", flush=True)
+          f"{clock():.1f}s", flush=True)
 
 
 def phase_four_chips(clock, requests):
@@ -207,7 +203,7 @@ def phase_four_chips(clock, requests):
           f"--four-chips needs 4 chips, {len(jax.devices())} attached")
     base, _, _ = serve(requests, backend="auto")
     print(f"[1x1] one of four chips: {len(base)}/{len(requests)} requests "
-          f"completed; compile {clock.lap():.1f}s", flush=True)
+          f"completed; compile {clock():.1f}s", flush=True)
     layouts = {"2x2 mesh": dict(mesh=make_serving_mesh((2, 2))),
                "2+2 disaggregated": dict(prefill_devices=2,
                                          decode_devices=2)}
@@ -220,7 +216,7 @@ def phase_four_chips(clock, requests):
                   if not bad else f"tokens differ from 1x1 ({{request: "
                                   f"first differing token}}): {bad}")
         print(f"[{name}] {len(outs)}/{len(requests)} requests completed; "
-              f"{parity}; compile {clock.lap():.1f}s; smoke reading (host "
+              f"{parity}; compile {clock():.1f}s; smoke reading (host "
               f"clock, cold): {toks / dt:.1f} tok/s", flush=True)
         if bad:
             differ.append(name)
@@ -250,7 +246,7 @@ def main() -> int:
     dev = jax.devices()[0]
     print(f"device: {dev.platform} {dev.device_kind} x{len(jax.devices())}",
           flush=True)
-    clock = CompileClock()
+    clock = compile_clock()
     requests = make_requests(load_arch(ARCH).full().vocab, args.seed)
     try:
         if args.four_chips:

@@ -59,7 +59,6 @@ CLI: PYTHONPATH=src python -m repro.launch.serve_lm --arch smollm_360m \
 from __future__ import annotations
 
 import argparse
-import contextlib
 import dataclasses
 import json
 import sys
@@ -74,7 +73,7 @@ from ..configs.base import ModelConfig, load_arch
 from ..core.backend import use_compile_cache
 from ..models import lm
 from ..obs.metrics import MetricsRegistry
-from ..obs.trace import TraceBuilder, annotate
+from ..obs.trace import TraceBuilder, attached, install, span
 from ..retrieval.prefix import PagePrefixIndex
 from ..serve.step import convert_params_for_serving, serving_cycle_report
 from .bucketed import bucket_for, drain_take
@@ -183,9 +182,11 @@ class LMServer:
             raise ValueError("--kv-crc needs a linear cache: ring pages "
                              "are rewritten in place after sealing")
         # telemetry: always-on registry (negligible cost — a few Python
-        # dict/float ops per step), optional Chrome-trace span capture
+        # dict/float ops per step), optional Chrome-trace sink of the
+        # spans (attached around each tick), process gc/compile hooks
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.trace = trace
+        install(self.metrics)
         self._key = jax.random.PRNGKey(seed)
         self.paged, self.page_size = paged, page_size
         self._cache_dtype = cache_dtype
@@ -274,20 +275,6 @@ class LMServer:
     def params(self):
         """The resident (possibly sharded) weights live on the executor."""
         return self.ex.params
-
-    # -- telemetry -----------------------------------------------------------
-
-    @contextlib.contextmanager
-    def _span(self, name: str, **args):
-        """One server-track span: Chrome-trace event (when tracing) plus a
-        jax.profiler annotation, so the same region shows up in both."""
-        with annotate(name):
-            if self.trace is not None:
-                with self.trace.span(name, track="server",
-                                     args=args or None):
-                    yield
-            else:
-                yield
 
     # -- scheduling ----------------------------------------------------------
 
@@ -419,8 +406,8 @@ class LMServer:
                 lens[i] = len(r.prompt)
             t0 = time.perf_counter()
             try:
-                with self._span("prefill_batch", batch=blen, plen=plb,
-                                fill=len(grp) / blen):
+                with span("prefill_batch", batch=blen, plen=plb,
+                          fill=len(grp) / blen, rids=[r.rid for r in grp]):
                     tok0, handle = self.ex.prefill(jnp.asarray(toks),
                                                    jnp.asarray(lens),
                                                    self._next_key())
@@ -654,15 +641,16 @@ class LMServer:
         slot_ids = np.full((blen,), self.slots, np.int32)
         rows = np.full((blen, self.n_pages), self.pool_pages, np.int32)
         for i, (r, s, mapping, _, s0) in enumerate(plans):
-            span = r.prompt[s0:] if history else r.prompt
-            toks[i, :len(span)] = span  # RIGHT-pad: bit-exact
-            lens[i] = len(span)
+            part = r.prompt[s0:] if history else r.prompt
+            toks[i, :len(part)] = part  # RIGHT-pad: bit-exact
+            lens[i] = len(part)
             starts[i] = s0
             slot_ids[i] = s
             rows[i] = self.table_np[s]
         t0 = time.perf_counter()
-        with self._span("prefill_batch", batch=blen, plen=lenb,
-                        fill=len(plans) / blen, history=history):
+        with span("prefill_batch", batch=blen, plen=lenb,
+                  fill=len(plans) / blen, history=history,
+                  rids=[p[0].rid for p in plans]):
             tok0, self.cache = self.ex.prefill_paged(
                 jnp.asarray(toks), jnp.asarray(lens), jnp.asarray(starts),
                 jnp.asarray(slot_ids), jnp.asarray(rows), self.cache,
@@ -725,17 +713,19 @@ class LMServer:
             # admission backpressured with nothing resident: a decode
             # launch would only burn a step on dead slots
             return []
-        toks = np.zeros((self.slots, 1), np.int32)
-        for s, r in enumerate(self.live):
-            if r is not None:
-                toks[s, 0] = r.out[-1]
+        tick = self._ticks
+        with span("decode.prepare", tick=tick):
+            toks = np.zeros((self.slots, 1), np.int32)
+            for s, r in enumerate(self.live):
+                if r is not None:
+                    toks[s, 0] = r.out[-1]
+            toks, key = jnp.asarray(toks), self._next_key()
         t0 = time.perf_counter()
         try:
-            with self._span("decode_step", occupied=occupied):
-                nxt, self.cache = self.ex.decode(jnp.asarray(toks),
-                                                 self.cache,
-                                                 self._next_key())
-                nxt = np.asarray(nxt)  # the only host transfer: [S] ids
+            with span("decode_step", occupied=occupied, tick=tick):
+                nxt, self.cache = self.ex.decode(toks, self.cache, key)
+                with span("decode.wait", tick=tick):
+                    nxt = np.asarray(nxt)  # the only host transfer: [S] ids
         except (InjectedFault, WorkerCrash) as e:
             # the seam fires before the donating dispatch, so the cache
             # is intact: skip this tick and redo the step (the fault is
@@ -745,27 +735,28 @@ class LMServer:
             self.metrics.counter("lm_retries").inc()
             return []
         t1 = time.perf_counter()
-        self.decode_steps += 1
-        m = self.metrics
-        m.histogram("lm_decode_step_s").record(t1 - t0)
-        m.gauge("lm_slot_occupancy").set(occupied)
-        m.histogram("lm_slot_occupancy_per_step").record(occupied)
-        m.counter("lm_tokens_generated").inc(occupied)
-        m.gauge("lm_queue_depth").set(len(self.queue))
-        retired = []
-        for s, r in enumerate(self.live):
-            if r is None:
-                continue
-            t = int(nxt[s])
-            r.out.append(t)
-            hit_eos = r.eos is not None and t == r.eos
-            if hit_eos or len(r.out) >= r.max_new:
-                r.done = True
-                r.finish_reason = "eos" if hit_eos else "length"
-                self._retire_slot(s, r, t1)
-                retired.append(r)
-        if self.paged and retired:
-            self._reclaim_pages()
+        with span("decode.retire", tick=tick):
+            self.decode_steps += 1
+            m = self.metrics
+            m.histogram("lm_decode_step_s").record(t1 - t0)
+            m.gauge("lm_slot_occupancy").set(occupied)
+            m.histogram("lm_slot_occupancy_per_step").record(occupied)
+            m.counter("lm_tokens_generated").inc(occupied)
+            m.gauge("lm_queue_depth").set(len(self.queue))
+            retired = []
+            for s, r in enumerate(self.live):
+                if r is None:
+                    continue
+                t = int(nxt[s])
+                r.out.append(t)
+                hit_eos = r.eos is not None and t == r.eos
+                if hit_eos or len(r.out) >= r.max_new:
+                    r.done = True
+                    r.finish_reason = "eos" if hit_eos else "length"
+                    self._retire_slot(s, r, t1)
+                    retired.append(r)
+            if self.paged and retired:
+                self._reclaim_pages()
         return retired
 
     def _step_spec(self) -> List[Request]:
@@ -781,55 +772,60 @@ class LMServer:
         occupied = sum(r is not None for r in self.live)
         if occupied == 0:
             return []
-        toks = np.zeros((self.slots,), np.int32)
-        for s, r in enumerate(self.live):
-            if r is not None:
-                toks[s] = r.out[-1]
+        tick = self._ticks
+        with span("decode.prepare", tick=tick):
+            toks = np.zeros((self.slots,), np.int32)
+            for s, r in enumerate(self.live):
+                if r is not None:
+                    toks[s] = r.out[-1]
+            toks, key = jnp.asarray(toks), self._next_key()
         t0 = time.perf_counter()
         try:
-            with self._span("spec_round", occupied=occupied,
-                            draft_k=self.draft_k):
+            with span("spec_round", occupied=occupied,
+                      draft_k=self.draft_k, tick=tick):
                 emitted, n_emit, self.cache = self.ex.spec_round(
-                    jnp.asarray(toks), self.cache, self._next_key())
-                emitted = np.asarray(emitted)  # [S, draft_k+1] token ids
-                n_emit = np.asarray(n_emit)    # [S] accepted prefix + 1
+                    toks, self.cache, key)
+                with span("spec_round.wait", tick=tick):
+                    emitted = np.asarray(emitted)  # [S, draft_k+1] ids
+                    n_emit = np.asarray(n_emit)    # [S] accepted prefix + 1
         except (InjectedFault, WorkerCrash) as e:
             if isinstance(e, WorkerCrash):
                 self.ex.on_worker_crash(e.wid)
             self.metrics.counter("lm_retries").inc()
             return []
         t1 = time.perf_counter()
-        self.decode_steps += 1
-        m = self.metrics
-        m.histogram("lm_decode_step_s").record(t1 - t0)
-        m.gauge("lm_slot_occupancy").set(occupied)
-        m.histogram("lm_slot_occupancy_per_step").record(occupied)
-        m.gauge("lm_queue_depth").set(len(self.queue))
-        retired = []
-        for s, r in enumerate(self.live):
-            if r is None:
-                continue
-            ne = int(n_emit[s])
-            if self.draft_k:  # per-slot acceptance telemetry
-                m.counter("lm_spec_rounds").inc()
-                m.counter("lm_spec_tokens_drafted").inc(self.draft_k)
-                m.counter("lm_spec_tokens_accepted").inc(ne - 1)
-                m.histogram("lm_spec_accept_rate").record(
-                    (ne - 1) / self.draft_k)
-            for j in range(ne):
-                t = int(emitted[s, j])
-                r.out.append(t)
-                m.counter("lm_tokens_generated").inc()
-                hit_eos = r.eos is not None and t == r.eos
-                if hit_eos or len(r.out) >= r.max_new:
-                    r.done = True
-                    r.finish_reason = "eos" if hit_eos else "length"
-                    break  # discard accepted tokens past the stop
-            if r.done:
-                self._retire_slot(s, r, t1)
-                retired.append(r)
-        if self.paged and retired:
-            self._reclaim_pages()
+        with span("decode.retire", tick=tick):
+            self.decode_steps += 1
+            m = self.metrics
+            m.histogram("lm_decode_step_s").record(t1 - t0)
+            m.gauge("lm_slot_occupancy").set(occupied)
+            m.histogram("lm_slot_occupancy_per_step").record(occupied)
+            m.gauge("lm_queue_depth").set(len(self.queue))
+            retired = []
+            for s, r in enumerate(self.live):
+                if r is None:
+                    continue
+                ne = int(n_emit[s])
+                if self.draft_k:  # per-slot acceptance telemetry
+                    m.counter("lm_spec_rounds").inc()
+                    m.counter("lm_spec_tokens_drafted").inc(self.draft_k)
+                    m.counter("lm_spec_tokens_accepted").inc(ne - 1)
+                    m.histogram("lm_spec_accept_rate").record(
+                        (ne - 1) / self.draft_k)
+                for j in range(ne):
+                    t = int(emitted[s, j])
+                    r.out.append(t)
+                    m.counter("lm_tokens_generated").inc()
+                    hit_eos = r.eos is not None and t == r.eos
+                    if hit_eos or len(r.out) >= r.max_new:
+                        r.done = True
+                        r.finish_reason = "eos" if hit_eos else "length"
+                        break  # discard accepted tokens past the stop
+                if r.done:
+                    self._retire_slot(s, r, t1)
+                    retired.append(r)
+            if self.paged and retired:
+                self._reclaim_pages()
         return retired
 
     # -- chaos tick: fault application + integrity scrub ---------------------
@@ -942,15 +938,23 @@ class LMServer:
         -> decode step. The ordering is the scrub-before-read guarantee:
         a bit flipped at this tick's fault stage is caught by this
         tick's scrub (``scrub_every=1``) before the decode step can read
-        it — corrupted tokens are never emitted silently."""
+        it — corrupted tokens are never emitted silently.
+
+        Each phase is a span (``obs.trace``) inside the tick's own, and
+        the server's ``trace`` builder, if any, is attached for the
+        tick."""
         self._ticks += 1
-        if self.faults is not None:
-            self._tick_faults()
-        if self.scrub_every and self._ticks % self.scrub_every == 0:
-            self._scrub()
-        self._expire_deadlines()
-        self._admit()
-        return self.step()
+        with attached(self.trace), span("tick", tick=self._ticks):
+            if self.faults is not None:
+                with span("sched.faults"):
+                    self._tick_faults()
+            if self.scrub_every and self._ticks % self.scrub_every == 0:
+                with span("sched.scrub"):
+                    self._scrub()
+            with span("sched.admit"):
+                self._expire_deadlines()
+                self._admit()
+            return self.step()
 
     def run(self) -> List[Request]:
         done = []

@@ -26,8 +26,11 @@ Everything device-side lives behind an executor object from this module:
 Worker attribution rides along: every executor dispatch is wrapped in a
 ``obs.ledger.phase`` carrying a worker tag (``p0``/``d0``/…), and the
 executors record per-worker labeled series (``lm_worker_dispatches``,
-``lm_prefill_s{worker=...}``, ``lm_handoff_latency``) next to the
-scheduler's unlabeled aggregates.
+``lm_handoff_latency``) next to the scheduler's unlabeled aggregates.
+Each entry point is spanned (``obs.trace.span``): ``<entry>.dispatch``
+around the jitted call until it returns, ``<entry>.wait`` around the
+blocking host transfer of its result, where the executor makes one
+(decode's is the scheduler's).
 
 Chaos hardening (PR 10): every executor accepts an optional
 :class:`repro.launch.faults.FaultPlan` and calls ``fire`` at its seams
@@ -55,6 +58,7 @@ from ..configs.base import ModelConfig
 from ..models import lm
 from ..obs import ledger as _flight
 from ..obs.metrics import MetricsRegistry
+from ..obs.trace import span
 from ..serve.step import (
     make_decode_select_step,
     make_prefill_select_step,
@@ -149,7 +153,8 @@ class _DecodeSide:
                     r = lax.dynamic_slice_in_dim(one, row, 1, axis=1)
                     return lax.dynamic_update_slice_in_dim(
                         full, r.astype(full.dtype), slot, axis=1)
-                return jax.tree.map(leaf, cache, src)
+                with jax.named_scope("kv_write"):
+                    return jax.tree.map(leaf, cache, src)
             self._write = jax.jit(write_slot, donate_argnums=(0,))
 
     def _ctx(self):
@@ -191,18 +196,16 @@ class _DecodeSide:
 
     def decode(self, toks, cache, key):
         self._fire("decode")
-        t0 = time.perf_counter()
-        with self._ctx(), self._tag():
+        with self._ctx(), self._tag(), span("decode.dispatch"):
             out = self._decode(self.params, toks, cache, key)
-        self._account("decode", t0)
+        self._account("decode")
         return out
 
     def spec_round(self, toks, cache, key):
         self._fire("decode")
-        t0 = time.perf_counter()
-        with self._ctx(), self._tag():
+        with self._ctx(), self._tag(), span("spec_round.dispatch"):
             out = self._spec(self.params, toks, cache, key)
-        self._account("decode", t0)
+        self._account("decode")
         return out
 
     def table_write(self, cache, slot_ids, rows):
@@ -213,12 +216,9 @@ class _DecodeSide:
         with self._ctx():
             return self._copy_page(cache, src, dst)
 
-    def _account(self, kind: str, t0: float):
-        m = self.metrics
-        m.counter("lm_worker_dispatches", worker=self.worker,
-                  role=self.role, kind=kind).inc()
-        m.histogram(f"lm_{kind}_worker_s", worker=self.worker,
-                    role=self.role).record(time.perf_counter() - t0)
+    def _account(self, kind: str):
+        self.metrics.counter("lm_worker_dispatches", worker=self.worker,
+                             role=self.role, kind=kind).inc()
 
     # -- integrity / recovery hooks (scheduler-facing) -----------------------
 
@@ -310,16 +310,17 @@ class LocalExecutor(_DecodeSide):
         the single-executor server); ``write_slot`` casts at the copy."""
         self._fire("prefill")
         blen = int(toks.shape[0])
-        t0 = time.perf_counter()
         with self._ctx(), self._tag():
-            c1, _ = lm.init_cache(self.cfg, blen, self.max_seq)
-            tok0, c1 = self._prefill(self.params, toks, lens, c1, key)
-            tok0 = np.asarray(tok0)
-        self._account("prefill", t0)
+            with span("prefill.dispatch"):
+                c1, _ = lm.init_cache(self.cfg, blen, self.max_seq)
+                tok0, c1 = self._prefill(self.params, toks, lens, c1, key)
+            with span("prefill.wait"):
+                tok0 = np.asarray(tok0)
+        self._account("prefill")
         return tok0, _PrefillHandle(None, c1)
 
     def write_slot(self, cache, handle: _PrefillHandle, row, slot):
-        with self._ctx():
+        with self._ctx(), span("write_slot.dispatch"):
             return self._write(cache, handle.cache, jnp.int32(row),
                                jnp.int32(slot))
 
@@ -329,12 +330,13 @@ class LocalExecutor(_DecodeSide):
         resident pools (cold prompts or prefix-hit suffixes)."""
         self._fire("prefill")
         fn = self._prefill_hit if history else self._prefill
-        t0 = time.perf_counter()
         with self._ctx(), self._tag():
-            tok0, cache = fn(self.params, toks, lens, starts, slot_ids,
-                             rows, cache, key)
-            tok0 = np.asarray(tok0)
-        self._account("prefill", t0)
+            with span("prefill_paged.dispatch"):
+                tok0, cache = fn(self.params, toks, lens, starts, slot_ids,
+                                 rows, cache, key)
+            with span("prefill_paged.wait"):
+                tok0 = np.asarray(tok0)
+        self._account("prefill")
         return tok0, cache
 
 
@@ -383,13 +385,14 @@ class PrefillWorker:
         """Contiguous prefill on this worker's devices."""
         self._fire("prefill")
         blen = int(toks.shape[0])
-        t0 = time.perf_counter()
         with jax.set_mesh(self.mesh), _flight.phase("", window=0,
                                                    worker=self.wid):
-            c1, _ = lm.init_cache(self.cfg, blen, self.max_seq)
-            tok0, c1 = self._prefill(self.params, toks, lens, c1, key)
-            tok0 = np.asarray(tok0)
-        self._account(t0)
+            with span("prefill.dispatch", worker=self.wid):
+                c1, _ = lm.init_cache(self.cfg, blen, self.max_seq)
+                tok0, c1 = self._prefill(self.params, toks, lens, c1, key)
+            with span("prefill.wait", worker=self.wid):
+                tok0 = np.asarray(tok0)
+        self._account()
         return tok0, c1
 
     def prefill_paged(self, toks, lens, slot_live, n_pages, key):
@@ -405,19 +408,20 @@ class PrefillWorker:
         slot_ids = np.where(slot_live, np.arange(blen, dtype=np.int32),
                             np.int32(blen))
         starts = np.zeros((blen,), np.int32)
-        t0 = time.perf_counter()
         with jax.set_mesh(self.mesh), _flight.phase("", window=0,
                                                    worker=self.wid):
-            c1, _ = lm.init_cache(self.cfg, blen, self.max_seq,
-                                  page_size=self.page_size,
-                                  pool_pages=pool, **self._ckw)
-            c1 = self._table_write_scratch(c1, table)
-            tok0, c1 = self._prefill(self.params, jnp.asarray(toks),
-                                     jnp.asarray(lens), jnp.asarray(starts),
-                                     jnp.asarray(slot_ids), jnp.asarray(table),
-                                     c1, key)
-            tok0 = np.asarray(tok0)
-        self._account(t0)
+            with span("prefill_paged.dispatch", worker=self.wid):
+                c1, _ = lm.init_cache(self.cfg, blen, self.max_seq,
+                                      page_size=self.page_size,
+                                      pool_pages=pool, **self._ckw)
+                c1 = self._table_write_scratch(c1, table)
+                tok0, c1 = self._prefill(
+                    self.params, jnp.asarray(toks), jnp.asarray(lens),
+                    jnp.asarray(starts), jnp.asarray(slot_ids),
+                    jnp.asarray(table), c1, key)
+            with span("prefill_paged.wait", worker=self.wid):
+                tok0 = np.asarray(tok0)
+        self._account()
         return tok0, c1
 
     @staticmethod
@@ -430,12 +434,9 @@ class PrefillWorker:
         with jax.set_mesh(self.mesh):
             return self._extract_row(cache, jnp.int32(row))
 
-    def _account(self, t0: float):
-        m = self.metrics
-        m.counter("lm_worker_dispatches", worker=self.wid,
-                  role="prefill", kind="prefill").inc()
-        m.histogram("lm_prefill_worker_s", worker=self.wid,
-                    role="prefill").record(time.perf_counter() - t0)
+    def _account(self):
+        self.metrics.counter("lm_worker_dispatches", worker=self.wid,
+                             role="prefill", kind="prefill").inc()
         if self.hb is not None:  # heartbeat per successful dispatch
             self.hb.beat(self.wid)
 
@@ -598,13 +599,14 @@ class DisaggExecutor(_DecodeSide):
         if w is None:  # degraded: prefill locally on the decode mesh
             self._fire("prefill")
             blen = int(toks.shape[0])
-            t0 = time.perf_counter()
             with self._ctx(), self._tag():
-                c1, _ = lm.init_cache(self.cfg, blen, self.max_seq)
-                tok0, c1 = self._fallback_prefill(paged=False)(
-                    self.params, toks, lens, c1, key)
-                tok0 = np.asarray(tok0)
-            self._account("prefill", t0)
+                with span("prefill.dispatch"):
+                    c1, _ = lm.init_cache(self.cfg, blen, self.max_seq)
+                    tok0, c1 = self._fallback_prefill(paged=False)(
+                        self.params, toks, lens, c1, key)
+                with span("prefill.wait"):
+                    tok0 = np.asarray(tok0)
+            self._account("prefill")
             return tok0, _PrefillHandle(None, c1)
         tok0, c1 = w.prefill(toks, lens, key)
         return tok0, _PrefillHandle(w, c1)
@@ -616,16 +618,19 @@ class DisaggExecutor(_DecodeSide):
         handles (no worker) are already on our mesh — plain local
         write, no handoff."""
         if handle.worker is None:
-            with self._ctx():
+            with self._ctx(), span("write_slot.dispatch"):
                 return self._write(cache, handle.cache, jnp.int32(row),
                                    jnp.int32(slot))
         self._fire("handoff", wid=handle.worker.wid)
         t0 = time.perf_counter()
-        row_cache = handle.worker.extract_row(handle.cache, row)
-        moved = _replicate_on(self.mesh, row_cache)
-        with self._ctx():
-            out = self._write(cache, moved, jnp.int32(0), jnp.int32(slot))
-        jax.block_until_ready(out["pos"])
+        with span("write_slot.dispatch", worker=handle.worker.wid):
+            row_cache = handle.worker.extract_row(handle.cache, row)
+            moved = _replicate_on(self.mesh, row_cache)
+            with self._ctx():
+                out = self._write(cache, moved, jnp.int32(0),
+                                  jnp.int32(slot))
+        with span("write_slot.wait", worker=handle.worker.wid):
+            jax.block_until_ready(out["pos"])
         self._handoff(t0, handle.worker.wid)
         return out
 
@@ -641,12 +646,13 @@ class DisaggExecutor(_DecodeSide):
         if w is None:  # degraded: straight through the resident table
             self._fire("prefill")
             fn = self._fallback_prefill(paged=True, history=history)
-            t0 = time.perf_counter()
             with self._ctx(), self._tag():
-                tok0, cache = fn(self.params, toks, lens, starts,
-                                 slot_ids, rows, cache, key)
-                tok0 = np.asarray(tok0)
-            self._account("prefill", t0)
+                with span("prefill_paged.dispatch"):
+                    tok0, cache = fn(self.params, toks, lens, starts,
+                                     slot_ids, rows, cache, key)
+                with span("prefill_paged.wait"):
+                    tok0 = np.asarray(tok0)
+            self._account("prefill")
             return tok0, cache
         if history:
             raise RuntimeError(

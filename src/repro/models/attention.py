@@ -17,6 +17,12 @@ the KV-memory win that makes it the natural PPAC companion for decode
 shapes. Decode writes are batched scatters (per-sequence slots), which
 lower in place when the cache pytree is donated (serve/step.py jits every
 decode entry point with ``donate_argnums`` on the cache).
+
+Named scopes mark the ops of each part in the compiled program's op
+metadata, and so in a device trace: ``proj/wqkv`` and ``proj/wo`` (the
+projections), ``kv_write`` (every cache write: contiguous update slice,
+scatter, paged scatter) and ``attn`` (the attention over the cache or
+the prompt).
 """
 from __future__ import annotations
 
@@ -38,6 +44,7 @@ from .layers import (
 )
 
 NEG_INF = -1e9
+_scope = jax.named_scope
 
 
 def _attend_chunk(qc, k, v, q_pos, k_valid, *, window: int, scale: float,
@@ -485,12 +492,14 @@ def gqa_apply(p, x, cfg: ModelConfig, *, positions, cache=None, pos=None,
     dtype = jnp.dtype(cfg.dtype)
     b, s, d = x.shape
     h, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    if "wqkv" in p:  # fused q/k/v group (serving fast path)
-        q, k, v = grouped_dense_apply(p["wqkv"], x, ppac=cfg.ppac, mode=mode)
-    else:
-        q = dense_apply(p["wq"], x, ppac=cfg.ppac, mode=mode, dtype=dtype)
-        k = dense_apply(p["wk"], x, ppac=cfg.ppac, mode=mode, dtype=dtype)
-        v = dense_apply(p["wv"], x, ppac=cfg.ppac, mode=mode, dtype=dtype)
+    with _scope("proj/wqkv"):
+        if "wqkv" in p:  # fused q/k/v group (serving fast path)
+            q, k, v = grouped_dense_apply(p["wqkv"], x, ppac=cfg.ppac,
+                                          mode=mode)
+        else:
+            q = dense_apply(p["wq"], x, ppac=cfg.ppac, mode=mode, dtype=dtype)
+            k = dense_apply(p["wk"], x, ppac=cfg.ppac, mode=mode, dtype=dtype)
+            v = dense_apply(p["wv"], x, ppac=cfg.ppac, mode=mode, dtype=dtype)
     q = q.reshape(b, s, h, hd)
     k = k.reshape(b, s, hkv, hd)
     v = v.reshape(b, s, hkv, hd)
@@ -501,12 +510,13 @@ def gqa_apply(p, x, cfg: ModelConfig, *, positions, cache=None, pos=None,
     paged = table is not None and cache is not None
     new_cache = cache
     if cache is None:
-        attn = chunked_attention(q, k, v, causal=True,
-                                 window=cfg.sliding_window,
-                                 q_chunk=cfg.q_chunk,
-                                 remat=cfg.remat != "none", rules=rules,
-                                 blocking=cfg.attn_blocking,
-                                 scores_dtype=sdt)
+        with _scope("attn"):
+            attn = chunked_attention(q, k, v, causal=True,
+                                     window=cfg.sliding_window,
+                                     q_chunk=cfg.q_chunk,
+                                     remat=cfg.remat != "none", rules=rules,
+                                     blocking=cfg.attn_blocking,
+                                     scores_dtype=sdt)
     elif history:  # paged suffix prefill after a prefix-cache hit
         assert paged and not cfg.sliding_window
         t = table.shape[1] * cache["k"].shape[1]
@@ -514,37 +524,36 @@ def gqa_apply(p, x, cfg: ModelConfig, *, positions, cache=None, pos=None,
               else as_pos_vector(lengths, b))
         row_idx = positions.astype(jnp.int32)               # [B,S] absolute
         valid = (jnp.arange(s)[None, :] < ln[:, None]) & (row_idx < t)
-        if "ks" in cache:
-            kq, ksc = _q8_kv(k)
-            vq, vsc = _q8_kv(v)
-            new_cache = {
-                "k": paged_scatter(cache["k"], table, kq, row_idx, valid),
-                "v": paged_scatter(cache["v"], table, vq, row_idx, valid),
-                "ks": paged_scatter(cache["ks"], table, ksc, row_idx, valid),
-                "vs": paged_scatter(cache["vs"], table, vsc, row_idx, valid),
-            }
-        else:
-            new_cache = {
-                "k": paged_scatter(cache["k"], table, k, row_idx, valid),
-                "v": paged_scatter(cache["v"], table, v, row_idx, valid),
-            }
-        view = {kk: paged_view(vv, table) for kk, vv in new_cache.items()}
-        kf = view["k"].astype(q.dtype)
-        vf = view["v"].astype(q.dtype)
-        if "ks" in view:
-            kf = kf * view["ks"].astype(q.dtype)
-            vf = vf * view["vs"].astype(q.dtype)
-        attn = _attend_causal_rows(q, kf, vf, row_idx, scale=hd ** -0.5,
-                                   rules=rules, scores_dtype=sdt)
+        with _scope("kv_write"):
+            if "ks" in cache:
+                kq, ksc = _q8_kv(k)
+                vq, vsc = _q8_kv(v)
+                leaves = {"k": kq, "v": vq, "ks": ksc, "vs": vsc}
+            else:
+                leaves = {"k": k, "v": v}
+            new_cache = {kk: paged_scatter(cache[kk], table, vv, row_idx,
+                                           valid)
+                         for kk, vv in leaves.items()}
+        with _scope("attn"):
+            view = {kk: paged_view(vv, table)
+                    for kk, vv in new_cache.items()}
+            kf = view["k"].astype(q.dtype)
+            vf = view["v"].astype(q.dtype)
+            if "ks" in view:
+                kf = kf * view["ks"].astype(q.dtype)
+                vf = vf * view["vs"].astype(q.dtype)
+            attn = _attend_causal_rows(q, kf, vf, row_idx, scale=hd ** -0.5,
+                                       rules=rules, scores_dtype=sdt)
     elif verify:  # speculative verify: S decode-equivalent rows at pos+i
         pos = as_pos_vector(pos, b)
         row_idx = pos[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-        if "ks" in cache:            # quantized store (same as decode)
-            kq, ksc = _q8_kv(k)
-            vq, vsc = _q8_kv(v)
-            leaves = {"k": kq, "v": vq, "ks": ksc, "vs": vsc}
-        else:
-            leaves = {"k": k, "v": v}
+        with _scope("kv_write"):
+            if "ks" in cache:            # quantized store (same as decode)
+                kq, ksc = _q8_kv(k)
+                vq, vsc = _q8_kv(v)
+                leaves = {"k": kq, "v": vq, "ks": ksc, "vs": vsc}
+            else:
+                leaves = {"k": k, "v": v}
         if cfg.sliding_window:
             # ring: window rows ride as S appended keys; each query reads
             # a per-query slot-ordered view, so softmax sums in the same
@@ -555,70 +564,79 @@ def gqa_apply(p, x, cfg: ModelConfig, *, positions, cache=None, pos=None,
             slot = row_idx % t
             j0 = jnp.mod(jnp.arange(t, dtype=jnp.int32)[None, :]
                          - pos[:, None], t)             # [B,t]
-            ext = {kk: jnp.concatenate(
-                [cache[kk], vv.astype(cache[kk].dtype)], axis=1)
-                for kk, vv in leaves.items()}
-            views = _ring_query_views(ext, j0, s, t)
-            k_valid = jnp.minimum(row_idx + 1, t)
-            attn = _verify_attend_views(q, views, k_valid, scale=hd ** -0.5,
-                                        rules=rules)
-            new_cache = {kk: _scatter_rows_multi(cache[kk], vv, slot)
-                         for kk, vv in leaves.items()}
+            with _scope("attn"):
+                ext = {kk: jnp.concatenate(
+                    [cache[kk], vv.astype(cache[kk].dtype)], axis=1)
+                    for kk, vv in leaves.items()}
+                views = _ring_query_views(ext, j0, s, t)
+                k_valid = jnp.minimum(row_idx + 1, t)
+                attn = _verify_attend_views(q, views, k_valid,
+                                            scale=hd ** -0.5, rules=rules)
+            with _scope("kv_write"):
+                new_cache = {kk: _scatter_rows_multi(cache[kk], vv, slot)
+                             for kk, vv in leaves.items()}
         elif paged:
             t = table.shape[1] * cache["k"].shape[1]
             # rows past the slot's allocation hit sentinel table entries
             # and drop; rows past the logical extent drop explicitly
             valid = row_idx < t
-            new_cache = {kk: paged_scatter(cache[kk], table, vv, row_idx,
-                                           valid)
-                         for kk, vv in leaves.items()}
-            attend = {kk: paged_view(vv, table)
-                      for kk, vv in new_cache.items()}
-            attn = _decode_attend_q8(q, attend, row_idx + 1,
-                                     scale=hd ** -0.5, rules=rules)
+            with _scope("kv_write"):
+                new_cache = {kk: paged_scatter(cache[kk], table, vv,
+                                               row_idx, valid)
+                             for kk, vv in leaves.items()}
+            with _scope("attn"):
+                attend = {kk: paged_view(vv, table)
+                          for kk, vv in new_cache.items()}
+                attn = _decode_attend_q8(q, attend, row_idx + 1,
+                                         scale=hd ** -0.5, rules=rules)
         else:
-            new_cache = {kk: _scatter_rows_multi(cache[kk], vv, row_idx)
-                         for kk, vv in leaves.items()}
-            attn = _decode_attend_q8(q, new_cache, row_idx + 1,
-                                     scale=hd ** -0.5, rules=rules)
+            with _scope("kv_write"):
+                new_cache = {kk: _scatter_rows_multi(cache[kk], vv, row_idx)
+                             for kk, vv in leaves.items()}
+            with _scope("attn"):
+                attn = _decode_attend_q8(q, new_cache, row_idx + 1,
+                                         scale=hd ** -0.5, rules=rules)
     elif s > 1:  # prefill into cache (cold: no history in the cache yet)
         psz = cache["k"].shape[1]
         t = table.shape[1] * psz if paged else cache["k"].shape[1]
         ln = (jnp.full((b,), s, jnp.int32) if lengths is None
               else as_pos_vector(lengths, b))
-        if cfg.sliding_window:
-            # ring layout: position p at slot p % t, per-sequence lengths
-            kw, vw = _ring_rows(k, ln, t), _ring_rows(v, ln, t)
-        else:
-            kw, vw = k, v
-        if "ks" in cache:
-            kq, ksc = _q8_kv(kw)
-            vq, vsc = _q8_kv(vw)
-            leaves = {"k": kq, "v": vq, "ks": ksc, "vs": vsc}
-        else:
-            leaves = {"k": kw, "v": vw}
-        if paged:
-            sw = kw.shape[1]       # t for ring layout, s for linear
-            row_idx = jnp.broadcast_to(
-                jnp.arange(sw, dtype=jnp.int32)[None, :], (b, sw))
-            # ring writes all t ring rows (never-written slots hold zeros,
-            # and every ring page is privately allocated); linear drops
-            # right-pad rows so they cannot land in shareable pages.
-            valid = (None if cfg.sliding_window
-                     else (row_idx < ln[:, None]) & (row_idx < t))
-            new_cache = {kk: paged_scatter(cache[kk], table, vv, row_idx,
-                                           valid)
-                         for kk, vv in leaves.items()}
-        else:
-            new_cache = {kk: lax.dynamic_update_slice(
-                cache[kk], vv.astype(cache[kk].dtype),
-                (0,) * cache[kk].ndim) for kk, vv in leaves.items()}
-        attn = chunked_attention(q, k, v, causal=True,
-                                 window=cfg.sliding_window,
-                                 q_chunk=cfg.q_chunk,
-                                 remat=cfg.remat != "none", rules=rules,
-                                 blocking=cfg.attn_blocking,
-                                 scores_dtype=sdt)
+        with _scope("kv_write"):
+            if cfg.sliding_window:
+                # ring layout: position p at slot p % t, per-seq lengths
+                kw, vw = _ring_rows(k, ln, t), _ring_rows(v, ln, t)
+            else:
+                kw, vw = k, v
+            if "ks" in cache:
+                kq, ksc = _q8_kv(kw)
+                vq, vsc = _q8_kv(vw)
+                leaves = {"k": kq, "v": vq, "ks": ksc, "vs": vsc}
+            else:
+                leaves = {"k": kw, "v": vw}
+            if paged:
+                sw = kw.shape[1]       # t for ring layout, s for linear
+                row_idx = jnp.broadcast_to(
+                    jnp.arange(sw, dtype=jnp.int32)[None, :], (b, sw))
+                # ring writes all t ring rows (never-written slots hold
+                # zeros, and every ring page is privately allocated);
+                # linear drops right-pad rows so they cannot land in
+                # shareable pages.
+                valid = (None if cfg.sliding_window
+                         else (row_idx < ln[:, None]) & (row_idx < t))
+                new_cache = {kk: paged_scatter(cache[kk], table, vv,
+                                               row_idx, valid)
+                             for kk, vv in leaves.items()}
+            else:
+                new_cache = {kk: lax.dynamic_update_slice(
+                    cache[kk], vv.astype(cache[kk].dtype),
+                    (0,) * cache[kk].ndim) for kk, vv in leaves.items()}
+        with _scope("attn"):
+            attn = chunked_attention(q, k, v, causal=True,
+                                     window=cfg.sliding_window,
+                                     q_chunk=cfg.q_chunk,
+                                     remat=cfg.remat != "none", rules=rules,
+                                     blocking=cfg.attn_blocking,
+                                     scores_dtype=sdt)
     else:  # decode, S == 1, per-sequence positions
         t = (table.shape[1] * cache["k"].shape[1] if paged
              else cache["k"].shape[1])
@@ -629,30 +647,33 @@ def gqa_apply(p, x, cfg: ModelConfig, *, positions, cache=None, pos=None,
         else:
             slot = pos               # linear cache
             k_valid = pos + 1
-        if "ks" in cache:            # quantized store
-            kq, ksc = _q8_kv(k)
-            vq, vsc = _q8_kv(v)
-            leaves = {"k": kq, "v": vq, "ks": ksc, "vs": vsc}
-        else:
-            leaves = {"k": k, "v": v}
-        if paged:
-            # vacant slots carry all-pad table rows, so their writes drop
-            # instead of landing in pages another sequence owns.
-            new_cache = {kk: paged_scatter(cache[kk], table, vv,
-                                           slot[:, None])
-                         for kk, vv in leaves.items()}
-            attend = {kk: paged_view(vv, table)
-                      for kk, vv in new_cache.items()}
-        else:
-            new_cache = {kk: _scatter_rows(cache[kk], vv, slot)
-                         for kk, vv in leaves.items()}
-            attend = new_cache
-        # rolling-cache entries are unordered but all within the window,
-        # so the validity mask alone is the correct attention mask.
-        attn = _decode_attend_q8(q, attend, k_valid, scale=hd ** -0.5,
-                                 rules=rules)
+        with _scope("kv_write"):
+            if "ks" in cache:            # quantized store
+                kq, ksc = _q8_kv(k)
+                vq, vsc = _q8_kv(v)
+                leaves = {"k": kq, "v": vq, "ks": ksc, "vs": vsc}
+            else:
+                leaves = {"k": k, "v": v}
+            if paged:
+                # vacant slots carry all-pad table rows, so their writes
+                # drop instead of landing in pages another sequence owns.
+                new_cache = {kk: paged_scatter(cache[kk], table, vv,
+                                               slot[:, None])
+                             for kk, vv in leaves.items()}
+            else:
+                new_cache = {kk: _scatter_rows(cache[kk], vv, slot)
+                             for kk, vv in leaves.items()}
+        with _scope("attn"):
+            attend = ({kk: paged_view(vv, table)
+                       for kk, vv in new_cache.items()} if paged
+                      else new_cache)
+            # rolling-cache entries are unordered but all within the
+            # window, so the validity mask alone is the correct mask.
+            attn = _decode_attend_q8(q, attend, k_valid, scale=hd ** -0.5,
+                                     rules=rules)
     attn = attn.reshape(b, s, h * hd).astype(dtype)
-    y = dense_apply(p["wo"], attn, ppac=cfg.ppac, mode=mode, dtype=dtype)
+    with _scope("proj/wo"):
+        y = dense_apply(p["wo"], attn, ppac=cfg.ppac, mode=mode, dtype=dtype)
     if cache is not None and rules is not None:
         # Pin the updated leaves to the fitted resident-cache placement:
         # left to propagation, GSPMD pushes the projection shardings onto
@@ -748,18 +769,24 @@ def mla_apply(p, x, cfg: ModelConfig, *, positions, cache=None, pos=None,
               else as_pos_vector(lengths, b))
         row_idx = positions.astype(jnp.int32)               # [B,S] absolute
         valid = (jnp.arange(s)[None, :] < ln[:, None]) & (row_idx < t)
-        ckp = paged_scatter(cache["kv_c"], table, kv_c, row_idx, valid)
-        crp = paged_scatter(cache["k_rope"], table, k_r, row_idx, valid)
+        with _scope("kv_write"):
+            ckp = paged_scatter(cache["kv_c"], table, kv_c, row_idx, valid)
+            crp = paged_scatter(cache["k_rope"], table, k_r, row_idx, valid)
         new_cache = {"kv_c": ckp, "k_rope": crp}
-        ckv = paged_view(ckp, table).astype(dtype)          # [B,T,lora]
-        crv = paged_view(crp, table).astype(dtype)          # [B,T,dr]
-        k_n = dense_apply(p["w_uk"], ckv, dtype=dtype).reshape(b, t, h, dn)
-        vv = dense_apply(p["w_uv"], ckv, dtype=dtype).reshape(b, t, h, dv)
-        k_full = jnp.concatenate(
-            [k_n, jnp.broadcast_to(crv[:, :, None, :], (b, t, h, dr))], -1)
-        q_full = jnp.concatenate([q_n, q_r], -1)
-        attn = _attend_causal_rows(q_full, k_full, vv, row_idx, scale=scale,
-                                   rules=rules, scores_dtype=sdt)
+        with _scope("attn"):
+            ckv = paged_view(ckp, table).astype(dtype)      # [B,T,lora]
+            crv = paged_view(crp, table).astype(dtype)      # [B,T,dr]
+            k_n = dense_apply(p["w_uk"], ckv,
+                              dtype=dtype).reshape(b, t, h, dn)
+            vv = dense_apply(p["w_uv"], ckv,
+                             dtype=dtype).reshape(b, t, h, dv)
+            k_full = jnp.concatenate(
+                [k_n, jnp.broadcast_to(crv[:, :, None, :], (b, t, h, dr))],
+                -1)
+            q_full = jnp.concatenate([q_n, q_r], -1)
+            attn = _attend_causal_rows(q_full, k_full, vv, row_idx,
+                                       scale=scale, rules=rules,
+                                       scores_dtype=sdt)
     elif cache is None or (s > 1 and not verify):
         # Non-absorbed (train/prefill) path: materialize K/V.
         k_n = dense_apply(p["w_uk"], kv_c, dtype=dtype).reshape(b, s, h, dn)
@@ -767,11 +794,12 @@ def mla_apply(p, x, cfg: ModelConfig, *, positions, cache=None, pos=None,
         k_full = jnp.concatenate(
             [k_n, jnp.broadcast_to(k_r[:, :, None, :], (b, s, h, dr))], -1)
         q_full = jnp.concatenate([q_n, q_r], -1)
-        attn = chunked_attention(q_full, k_full, v, causal=True,
-                                 q_chunk=cfg.q_chunk, scale=scale,
-                                 remat=cfg.remat != "none", rules=rules,
-                                 blocking=cfg.attn_blocking,
-                                 scores_dtype=sdt)
+        with _scope("attn"):
+            attn = chunked_attention(q_full, k_full, v, causal=True,
+                                     q_chunk=cfg.q_chunk, scale=scale,
+                                     remat=cfg.remat != "none", rules=rules,
+                                     blocking=cfg.attn_blocking,
+                                     scores_dtype=sdt)
         new_cache = cache
         if paged:
             t = table.shape[1] * cache["kv_c"].shape[1]
@@ -780,19 +808,23 @@ def mla_apply(p, x, cfg: ModelConfig, *, positions, cache=None, pos=None,
             row_idx = jnp.broadcast_to(
                 jnp.arange(s, dtype=jnp.int32)[None, :], (b, s))
             valid = (row_idx < ln[:, None]) & (row_idx < t)
-            new_cache = {
-                "kv_c": paged_scatter(cache["kv_c"], table, kv_c, row_idx,
-                                      valid),
-                "k_rope": paged_scatter(cache["k_rope"], table, k_r,
-                                        row_idx, valid),
-            }
+            with _scope("kv_write"):
+                new_cache = {
+                    "kv_c": paged_scatter(cache["kv_c"], table, kv_c,
+                                          row_idx, valid),
+                    "k_rope": paged_scatter(cache["k_rope"], table, k_r,
+                                            row_idx, valid),
+                }
         elif cache is not None:
-            new_cache = {
-                "kv_c": lax.dynamic_update_slice(
-                    cache["kv_c"], kv_c.astype(cache["kv_c"].dtype), (0, 0, 0)),
-                "k_rope": lax.dynamic_update_slice(
-                    cache["k_rope"], k_r.astype(cache["k_rope"].dtype), (0, 0, 0)),
-            }
+            with _scope("kv_write"):
+                new_cache = {
+                    "kv_c": lax.dynamic_update_slice(
+                        cache["kv_c"], kv_c.astype(cache["kv_c"].dtype),
+                        (0, 0, 0)),
+                    "k_rope": lax.dynamic_update_slice(
+                        cache["k_rope"], k_r.astype(cache["k_rope"].dtype),
+                        (0, 0, 0)),
+                }
     else:
         # Absorbed decode: score against the compressed cache directly,
         # at per-sequence write positions. The same path serves the
@@ -804,34 +836,43 @@ def mla_apply(p, x, cfg: ModelConfig, *, positions, cache=None, pos=None,
         if paged:
             t = table.shape[1] * cache["kv_c"].shape[1]
             valid = row_idx < t    # unallocated pages drop via sentinel
-            ckp = paged_scatter(cache["kv_c"], table, kv_c, row_idx, valid)
-            crp = paged_scatter(cache["k_rope"], table, k_r, row_idx, valid)
+            with _scope("kv_write"):
+                ckp = paged_scatter(cache["kv_c"], table, kv_c, row_idx,
+                                    valid)
+                crp = paged_scatter(cache["k_rope"], table, k_r, row_idx,
+                                    valid)
             new_cache = {"kv_c": ckp, "k_rope": crp}
-            ck = paged_view(ckp, table)
-            cr = paged_view(crp, table)
+            with _scope("attn"):
+                ck = paged_view(ckp, table)
+                cr = paged_view(crp, table)
         else:
-            ck = _scatter_rows_multi(cache["kv_c"], kv_c, row_idx)
-            cr = _scatter_rows_multi(cache["k_rope"], k_r, row_idx)
+            with _scope("kv_write"):
+                ck = _scatter_rows_multi(cache["kv_c"], kv_c, row_idx)
+                cr = _scatter_rows_multi(cache["k_rope"], k_r, row_idx)
             new_cache = {"kv_c": ck, "k_rope": cr}
         t = ck.shape[1]
-        w_uk = p["w_uk"]["w"].astype(dtype).reshape(m.kv_lora_rank, h, dn)
-        # absorb: q' = q_n @ w_uk^T  -> [B,S,H,lora]
-        q_abs = jnp.einsum("bshd,lhd->bshl", q_n, w_uk)
-        scores = (jnp.einsum("bshl,btl->bhst", q_abs, ck,
+        with _scope("attn"):
+            w_uk = p["w_uk"]["w"].astype(dtype).reshape(
+                m.kv_lora_rank, h, dn)
+            # absorb: q' = q_n @ w_uk^T  -> [B,S,H,lora]
+            q_abs = jnp.einsum("bshd,lhd->bshl", q_n, w_uk)
+            scores = (jnp.einsum("bshl,btl->bhst", q_abs, ck,
+                                 preferred_element_type=jnp.float32)
+                      + jnp.einsum("bshd,btd->bhst", q_r, cr,
+                                   preferred_element_type=jnp.float32)) * scale
+            k_pos = jnp.arange(t)
+            mask = k_pos[None, None, :] <= row_idx[:, :, None]     # [B,S,T]
+            scores = jnp.where(mask[:, None, :, :], scores, NEG_INF)
+            wts = jax.nn.softmax(scores, axis=-1)
+            ctx = jnp.einsum("bhst,btl->bshl", wts.astype(ck.dtype), ck,
                              preferred_element_type=jnp.float32)
-                  + jnp.einsum("bshd,btd->bhst", q_r, cr,
-                               preferred_element_type=jnp.float32)) * scale
-        k_pos = jnp.arange(t)
-        mask = k_pos[None, None, :] <= row_idx[:, :, None]     # [B,S,T]
-        scores = jnp.where(mask[:, None, :, :], scores, NEG_INF)
-        wts = jax.nn.softmax(scores, axis=-1)
-        ctx = jnp.einsum("bhst,btl->bshl", wts.astype(ck.dtype), ck,
-                         preferred_element_type=jnp.float32)
-        w_uv = p["w_uv"]["w"].astype(jnp.float32).reshape(m.kv_lora_rank, h, dv)
-        attn = jnp.einsum("bshl,lhv->bshv", ctx, w_uv)
+            w_uv = p["w_uv"]["w"].astype(jnp.float32).reshape(
+                m.kv_lora_rank, h, dv)
+            attn = jnp.einsum("bshl,lhv->bshv", ctx, w_uv)
 
     attn = attn.reshape(b, s, h * dv).astype(dtype)
-    y = dense_apply(p["wo"], attn, ppac=cfg.ppac, mode=mode, dtype=dtype)
+    with _scope("proj/wo"):
+        y = dense_apply(p["wo"], attn, ppac=cfg.ppac, mode=mode, dtype=dtype)
     if cache is not None and rules is not None:
         # Same strict-aliasing contract as the GQA path (see gqa_apply).
         cax = ((None, None, None) if paged else MLA_CACHE_AXES["kv_c"])

@@ -149,11 +149,17 @@ def mlp_init(key, d: int, d_ff: int):
 
 
 def mlp_apply(p, x, cfg: ModelConfig, *, mode: str = "float"):
+    """SwiGLU; the up+gate and down projections run under the named
+    scopes ``proj/wig`` and ``proj/down``."""
     dtype = jnp.dtype(cfg.dtype)
-    if "wig" in p:  # fused up+gate group (serving fast path)
-        h, g = grouped_dense_apply(p["wig"], x, ppac=cfg.ppac, mode=mode)
-    else:
-        h = dense_apply(p["wi"], x, ppac=cfg.ppac, mode=mode, dtype=dtype)
-        g = dense_apply(p["wg"], x, ppac=cfg.ppac, mode=mode, dtype=dtype)
+    with jax.named_scope("proj/wig"):
+        if "wig" in p:  # fused up+gate group (serving fast path)
+            h, g = grouped_dense_apply(p["wig"], x, ppac=cfg.ppac, mode=mode)
+        else:
+            h = dense_apply(p["wi"], x, ppac=cfg.ppac, mode=mode,
+                            dtype=dtype)
+            g = dense_apply(p["wg"], x, ppac=cfg.ppac, mode=mode,
+                            dtype=dtype)
     h = jax.nn.silu(g.astype(jnp.float32)).astype(dtype) * h
-    return dense_apply(p["wo"], h, ppac=cfg.ppac, mode=mode, dtype=dtype)
+    with jax.named_scope("proj/down"):
+        return dense_apply(p["wo"], h, ppac=cfg.ppac, mode=mode, dtype=dtype)

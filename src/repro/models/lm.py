@@ -312,6 +312,17 @@ def _run_layers(params, cfg: ModelConfig, h, *, positions, caches=None,
     return h, new_caches, aux
 
 
+def _lm_head(params, cfg: ModelConfig, h):
+    """Logits (fp32) of the final hidden states, under the named scope
+    ``lm_head``: the tied embedding or the head projection."""
+    with jax.named_scope("lm_head"):
+        if cfg.tie_embeddings:
+            return unembed_apply(params["embed"], h,
+                                 dtype=jnp.dtype(cfg.dtype))
+        return dense_apply(params["lm_head"], h,
+                           dtype=jnp.dtype(cfg.dtype)).astype(jnp.float32)
+
+
 def forward(params, cfg: ModelConfig, batch, *, mode: str = "float",
             rules: Optional[ShardingRules] = None):
     """Training/eval forward: batch -> (logits [B,S,V] fp32, aux)."""
@@ -332,11 +343,7 @@ def forward(params, cfg: ModelConfig, batch, *, mode: str = "float",
     aux = {k: aux[k] + a2[k] for k in aux}
     h = rmsnorm_apply(params["final_norm"], h, eps=cfg.norm_eps,
                       dtype=jnp.dtype(cfg.dtype))
-    if cfg.tie_embeddings:
-        logits = unembed_apply(params["embed"], h, dtype=jnp.dtype(cfg.dtype))
-    else:
-        logits = dense_apply(params["lm_head"], h,
-                             dtype=jnp.dtype(cfg.dtype)).astype(jnp.float32)
+    logits = _lm_head(params, cfg, h)
     if rules:
         logits = constrain(logits, rules, "batch", None, "vocab")
     return logits, aux
@@ -502,18 +509,14 @@ def prefill(params, cfg: ModelConfig, batch, cache, *, lengths=None,
     h = rmsnorm_apply(params["final_norm"], h, eps=cfg.norm_eps,
                       dtype=jnp.dtype(cfg.dtype))
     h_last = jnp.take_along_axis(h, (ln - 1)[:, None, None], axis=1)
-    if cfg.tie_embeddings:
-        logits = unembed_apply(params["embed"], h_last,
-                               dtype=jnp.dtype(cfg.dtype))
-    else:
-        logits = dense_apply(params["lm_head"], h_last,
-                             dtype=jnp.dtype(cfg.dtype)).astype(jnp.float32)
+    logits = _lm_head(params, cfg, h_last)
     end = ln if start is None else start + ln
-    if slot_ids is None:
-        new["pos"] = end
-    else:
-        new["pos"] = pos0.at[jnp.asarray(slot_ids, jnp.int32)].set(
-            end, mode="drop")
+    with jax.named_scope("kv_write"):
+        if slot_ids is None:
+            new["pos"] = end
+        else:
+            new["pos"] = pos0.at[jnp.asarray(slot_ids, jnp.int32)].set(
+                end, mode="drop")
     return logits, new
 
 
@@ -545,12 +548,9 @@ def decode_step(params, cfg: ModelConfig, tokens, cache, *,
     new.update(ncaches)
     h = rmsnorm_apply(params["final_norm"], h, eps=cfg.norm_eps,
                       dtype=jnp.dtype(cfg.dtype))
-    if cfg.tie_embeddings:
-        logits = unembed_apply(params["embed"], h, dtype=jnp.dtype(cfg.dtype))
-    else:
-        logits = dense_apply(params["lm_head"], h,
-                             dtype=jnp.dtype(cfg.dtype)).astype(jnp.float32)
-    new["pos"] = pos + 1
+    logits = _lm_head(params, cfg, h)
+    with jax.named_scope("kv_write"):
+        new["pos"] = pos + 1
     return logits, new
 
 
@@ -607,12 +607,9 @@ def verify(params, cfg: ModelConfig, tokens, cache, *, mode: str = "float",
     new.update(ncaches)
     h = rmsnorm_apply(params["final_norm"], h, eps=cfg.norm_eps,
                       dtype=jnp.dtype(cfg.dtype))
-    if cfg.tie_embeddings:
-        logits = unembed_apply(params["embed"], h, dtype=jnp.dtype(cfg.dtype))
-    else:
-        logits = dense_apply(params["lm_head"], h,
-                             dtype=jnp.dtype(cfg.dtype)).astype(jnp.float32)
-    new["pos"] = pos + s
+    logits = _lm_head(params, cfg, h)
+    with jax.named_scope("kv_write"):
+        new["pos"] = pos + s
     return logits, new
 
 

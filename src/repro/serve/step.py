@@ -137,11 +137,14 @@ def sample_tokens(logits, key, *, temperature: float = 0.0, top_k: int = 0):
     temperature == 0 -> greedy argmax (key unused); otherwise softmax
     sampling at ``temperature``, optionally restricted to the ``top_k``
     highest-scoring tokens. Static python knobs: each setting is its own
-    compiled program, fused into the decode step / scan body."""
-    if temperature <= 0.0:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = _scale_logits(logits, temperature=temperature, top_k=top_k)
-    return jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
+    compiled program, fused into the decode step / scan body, under the
+    named scope ``sample``."""
+    with jax.named_scope("sample"):
+        if temperature <= 0.0:
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        scaled = _scale_logits(logits, temperature=temperature, top_k=top_k)
+        return jax.random.categorical(key, scaled,
+                                      axis=-1).astype(jnp.int32)
 
 
 @_maybe_cached

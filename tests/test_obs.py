@@ -23,7 +23,8 @@ from repro.core.engine import (
     serve_dense_grouped,
 )
 from repro.kernels.engine import ppac_matmul
-from repro.obs import Ledger, MetricsRegistry, TraceBuilder
+from repro.obs import Ledger, MetricsRegistry, TraceBuilder, span
+from repro.obs.trace import attached
 from repro.obs import ledger as obs_ledger
 
 
@@ -218,10 +219,10 @@ def test_trace_export_valid_and_monotonic():
         weight_bits=4)
     x = jnp.asarray(rng.standard_normal((2, 64)).astype(np.float32))
     trace = TraceBuilder()
-    with Ledger() as led:
-        with trace.span("step", args=dict(i=0)):
+    with Ledger() as led, attached(trace):
+        with span("step", i=0):
             serve_dense(x, c, act_bits=4, backend="mxu")
-        with trace.span("step", args=dict(i=1)):
+        with span("step", i=1):
             serve_dense(x, c, act_bits=4, backend="mxu")
     trace.add_ledger(led)
 
