@@ -8,8 +8,8 @@ tiling        — shared machinery: pad-to-tile planning on the TPU block
 binary_mvp    — packed 1-bit XNOR/AND popcount matmul (modes III-A/B/D/E)
 bitserial_mvp — fused multi-bitplane MVP (mode III-C, all Table-I formats;
                 ``ppac_matmul_planes`` serves pre-packed resident weights,
-                ``ppac_matmul_resident`` is the zero-repack decode fast
-                path with in-kernel activation bit-slicing)
+                ``ppac_matmul_resident`` is the zero-repack serving fast
+                path: in-kernel activation slicing, MXU contraction)
 hamming_topk  — fused streaming Hamming top-k / CAM δ-match (mode III-A
                 associative retrieval at scale; never materializes [B, M])
 gf2_tiled     — tiled GF(2) matmul with XOR-parity accumulation across

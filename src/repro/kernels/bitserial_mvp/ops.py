@@ -10,11 +10,12 @@ already decomposed into packed bitplane lanes (the resident weight layout
 of ``core.engine.pack_weight_for_serving``) and only the L-bit vector batch
 is decomposed on the fly — the matrix is weight-stationary, exactly the
 paper's premise of a static A with streaming x (§IV-A).
-``ppac_matmul_resident`` is its decode fast path: the streaming operand is
-the quantized integer activation batch itself, bit-sliced *inside* the
-Pallas body (no ``to_bitplanes``/``pack_bits`` XLA round trip), and the
-optional ``a_int8`` shadow gives the MXU lowering a load-time resident
-operand too.
+``ppac_matmul_resident`` is its serving fast path: the streaming operand
+is the quantized integer activation batch itself, as level codes sliced
+*inside* the Pallas body (no ``to_bitplanes``/``pack_bits`` XLA round
+trip); the formats' plane factors let that body contract on the MXU, and
+the optional ``a_int8`` shadow gives the 'mxu' lowering a load-time
+resident operand too.
 
 Weight-matrix construction. For an operand with format f and L bits, the
 value decomposes over logical planes b_l in {0,1} as
@@ -78,12 +79,30 @@ def format_needs_mask(f) -> bool:
     return _operand_decomposition(f, 1)[1] != 0
 
 
+def plane_factors(fmt_a, k_bits: int, fmt_x, l_bits: int, *,
+                  a_has_mask: bool = False):
+    """(wa, wx) as tuples of ints: the per-plane weights of the resident
+    operand (a stored mask plane's offset appended) and of the streamed
+    one. :func:`extended_weights` builds its plane-pair block as their
+    outer product, which is what lets the sliced kernel contract both
+    operands' integers on the MXU."""
+    wa, ca = _operand_decomposition(fmt_a, k_bits)
+    wx, _ = _operand_decomposition(fmt_x, l_bits)
+    if a_has_mask:
+        if ca == 0:
+            raise ValueError(f"format {fmt(fmt_a)} carries no offset; "
+                             "no resident mask plane expected")
+        wa = np.concatenate([wa, [ca]])
+    return tuple(int(v) for v in wa), tuple(int(v) for v in wx)
+
+
 def extended_weights(fmt_a, k_bits: int, fmt_x, l_bits: int, *, n: int,
                      a_has_mask: bool = False):
     """Build the extended [K1+1, L+1] weight matrix + static term flags.
 
     Returns (w_ext int32 numpy, k1, pop_a, pop_x, const):
-      w_ext[:K1, :L]  plane-pair AND-popcount weights
+      w_ext[:K1, :L]  plane-pair AND-popcount weights, outer(wa, wx) of
+                      :func:`plane_factors`
       w_ext[:K1, L]   coefficients on in-kernel popcount(a_plane_k)[m]
       w_ext[K1, :L]   coefficients on in-kernel popcount(x_plane_l)[b]
       w_ext[K1, L]    constant ca*cx*n, added once per output block
@@ -93,14 +112,10 @@ def extended_weights(fmt_a, k_bits: int, fmt_x, l_bits: int, *, n: int,
     ordinary pair weights and its pop_a column carries the corner term
     (popcount of the mask plane is n, yielding ca*cx*n exactly).
     """
-    wa, ca = _operand_decomposition(fmt_a, k_bits)
-    wx, cx = _operand_decomposition(fmt_x, l_bits)
-    if a_has_mask:
-        if ca == 0:
-            raise ValueError(f"format {fmt(fmt_a)} carries no offset; "
-                             "no resident mask plane expected")
-        wa = np.concatenate([wa, [ca]])
-        ca = 0
+    wa, wx = map(np.asarray, plane_factors(fmt_a, k_bits, fmt_x, l_bits,
+                                           a_has_mask=a_has_mask))
+    ca = 0 if a_has_mask else _operand_decomposition(fmt_a, k_bits)[1]
+    cx = _operand_decomposition(fmt_x, l_bits)[1]
     k1, l1 = len(wa), len(wx)
     w = np.zeros((k1 + 1, l1 + 1), np.int64)
     w[:k1, :l1] = np.outer(wa, wx)
@@ -243,8 +258,13 @@ def ppac_matmul_resident(x_int, a_planes, *, n: int, k_bits: int,
 
     Bit-identical to :func:`ppac_matmul_planes` (tested); differences are
     purely in data movement:
-      * 'pallas' streams L-bit level codes and builds the packed planes
-        per tile in the kernel body — no to_bitplanes/pack_bits round trip;
+      * 'pallas' streams L-bit level codes into the sliced kernel with the
+        plane factors (wa, wx) of the formats (:func:`plane_factors`,
+        checked against :func:`extended_weights`' plane-pair block). The
+        block is rank one for every Table-I pair, so the kernel runs its
+        MXU body: the integers of both operands are rebuilt per bit
+        position in VMEM from the resident planes and contracted exactly
+        on the MXU (int8 -> int32, or bf16 -> f32 under 2^24 per dot);
       * 'mxu' consumes ``a_int8`` — the int8 shadow materialized at load
         time by ``pack_weight_for_serving`` — instead of unpacking the
         planes per call (falls back to the legacy unpack when absent);
@@ -275,11 +295,15 @@ def ppac_matmul_resident(x_int, a_planes, *, n: int, k_bits: int,
         xp = pack_bits(to_bitplanes(x_int, l_bits, fx))
         return bitserial_matmul_packed_ref(xp, ap, w)
     if backend == "pallas":
+        factors = plane_factors(fa, k_bits, fx, l_bits, a_has_mask=a_has_mask)
+        k1, l1 = len(factors[0]), len(factors[1])
+        assert np.array_equal(w_ext[:k1, :l1], np.outer(*factors)), \
+            "plane-pair block is not outer(wa, wx)"
         u = levels_to_stack(to_levels(x_int, l_bits, fx), ap.shape[-1])
         kernel = functools.partial(
-            bitserial_matmul_sliced, l_bits=l_bits, pop_a=pop_a, pop_x=pop_x,
-            const=const, block_b=block_b, block_m=block_m, block_w=block_w,
-            interpret=_auto_interpret())
+            bitserial_matmul_sliced, l_bits=l_bits, plane_factors=factors,
+            pop_a=pop_a, pop_x=pop_x, const=const, block_b=block_b,
+            block_m=block_m, block_w=block_w, interpret=_auto_interpret())
         return split_over_mesh(kernel, u, ap, w, x_rows=1, a_rows=1)
     raise ValueError(f"unknown backend {backend}")
 

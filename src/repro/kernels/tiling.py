@@ -5,12 +5,16 @@ uint32 operands x [.., B, W] and a [.., M, W] are padded up to tile
 multiples, a (B/bb, M/bm, W/bw) grid walks batch × row × lane tiles with
 the lane dimension innermost, and the revisited [bb, bm] int32 output
 block accumulates one contribution per lane tile (integer add for the
-popcount modes, XOR for GF(2) parity). Inside a tile the body walks the
-streamed rows one at a time (:func:`for_each_row`): each row is one PPAC
-array cycle, its vector broadcast against the resident [bm, bw] tile and
-every row's popcount landing on its output lane (:func:`popcount_row`).
-That keeps the per-step intermediate at one resident tile — the bound the
-paper's subrow partitioning (Fig. 2) puts on adder fan-in in hardware.
+popcount modes, XOR for GF(2) parity). Inside a tile the vector-unit
+bodies walk the streamed rows one at a time (:func:`for_each_row`): each
+row is one PPAC array cycle, its vector broadcast against the resident
+[bm, bw] tile and every row's popcount landing on its output lane
+(:func:`popcount_row`). That keeps the per-step intermediate at one
+resident tile — the bound the paper's subrow partitioning (Fig. 2) puts on
+adder fan-in in hardware. The one exception is the serving projection
+(``bitserial_matmul_sliced`` given plane factors): its MXU body rebuilds
+the tile's integers per bit position and contracts them on the MXU, with
+no row loop (see ``bitserial_mvp/kernel.py``).
 
 Tiles obey the TPU block rule: batch tiles are multiples of 8 sublanes;
 row tiles (the output's lane dim) and lane tiles are multiples of 128 or
@@ -124,11 +128,18 @@ def plan_tiles(b: int, m: int, w: int, *, block_b: int = 64,
 CACHE_ENV = "PPAC_TILE_CACHE"
 
 
-def default_blocks(b: int, m: int, w: int) -> Dict[str, int]:
+def default_blocks(b: int, m: int, w: int, mode: str = "") -> Dict[str, int]:
     """Shape-aware default blocks. Decode steps stream a tiny batch (a few
     tokens) against a large resident matrix: an 8-row batch tile keeps the
     per-grid-step row loop short while the resident tile stays fat, so
-    the K·L popcount schedule amortizes over more resident rows."""
+    the K·L popcount schedule amortizes over more resident rows.
+
+    ``bitserial_sliced`` rebuilds its weight tile's integers once per grid
+    step, so a prefill launch takes a 256-row batch tile (each weight tile
+    rebuilt once per 256 rows, not per 64), with 128-lane tiles that keep
+    the double-buffered [32, 256, 128] level-code block at 8 MiB of VMEM."""
+    if mode == "bitserial_sliced" and b > 64:
+        return dict(block_b=256, block_m=256, block_w=128)
     if b <= 8:
         return dict(block_b=SUBLANE_MULTIPLE, block_m=256, block_w=256)
     if b <= 32:
@@ -205,7 +216,7 @@ def plan_for(mode: str, b: int, m: int, w: int, *,
     """Resolve the tile plan for one launch: explicit overrides win, then
     the autotune cache (when one is named), then the decode-aware
     defaults."""
-    blocks = default_blocks(b, m, w)
+    blocks = default_blocks(b, m, w, mode)
     cache = plan_cache()
     if cache is not None:
         blocks.update(cache.get(mode, b, m, w) or {})
@@ -238,11 +249,11 @@ def candidate_blocks(b: int, m: int, w: int):
         for bw in (128, 256, 512)])
 
 
-def quick_candidates(b: int, m: int, w: int):
+def quick_candidates(b: int, m: int, w: int, mode: str = ""):
     """A handful of variations around the shape defaults — the compile
     cost per candidate dominates off-TPU, so the serving autotune sweeps
     this trimmed set by default (full sweep: :func:`candidate_blocks`)."""
-    base = default_blocks(b, m, w)
+    base = default_blocks(b, m, w, mode)
     return _distinct_geometries(b, m, w, [
         base, {**base, "block_m": 128}, {**base, "block_m": 512},
         {**base, "block_w": 128}])
@@ -347,7 +358,7 @@ def smem_spec():
 def lane_stream_call(kernel_body, x_packed, a_packed, plan: TilePlan, *,
                      x_leading: int = 0, a_leading: int = 0,
                      extra_inputs=(), extra_specs=(), scratch_shapes=(),
-                     interpret: bool = False):
+                     name: Optional[str] = None, interpret: bool = False):
     """Run ``kernel_body`` on the canonical lane-streamed grid.
 
     Pads the operands per ``plan``, streams x tiles along grid dims (0, 2)
@@ -380,6 +391,7 @@ def lane_stream_call(kernel_body, x_packed, a_packed, plan: TilePlan, *,
         out_specs=pl.BlockSpec((plan.bb, plan.bm), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((plan.bp, plan.mp), jnp.int32),
         scratch_shapes=list(scratch_shapes),
+        name=name,
         interpret=interpret,
         **extra,
     )(x_p, a_p, *extra_inputs)

@@ -688,7 +688,8 @@ def autotune_serving_plans(params, cfg: ModelConfig, *, batch: int,
 
         plan = tiling.autotune_plan(
             "bitserial_sliced", batch, d_out, w, run,
-            candidates=tiling.quick_candidates(batch, d_out, w), reps=2)
+            candidates=tiling.quick_candidates(batch, d_out, w,
+                                               "bitserial_sliced"), reps=2)
         tuned[key] = plan.blocks
         if verbose:
             print(f"autotuned bitserial_sliced b={batch} m={d_out} w={w} "

@@ -5,6 +5,9 @@ single whole-matrix launch (block sizes >= the padded operand — exactly
 the pre-refactor whole-matrix behavior) on shapes spanning several tiles
 in every grid dimension, and both must match the jnp oracles.
 """
+import functools
+
+import jax
 import numpy as np
 import pytest
 
@@ -12,11 +15,19 @@ from repro.core import formats as F
 from repro.kernels.binary_mvp.kernel import binary_matmul_packed
 from repro.kernels.binary_mvp.ref import binary_matmul_packed_ref
 from repro.kernels.bitserial_mvp.kernel import (
+    MXU_KERNEL_NAME,
     bitserial_matmul_packed,
     bitserial_matmul_sliced,
 )
-from repro.kernels.bitserial_mvp.ops import levels_to_stack
-from repro.kernels.bitserial_mvp.ref import bitserial_matmul_packed_ref
+from repro.kernels.bitserial_mvp.ops import (
+    extended_weights,
+    levels_to_stack,
+    plane_factors,
+)
+from repro.kernels.bitserial_mvp.ref import (
+    bitserial_matmul_packed_ref,
+    integer_matmul_ref,
+)
 from repro.kernels.gf2_tiled.kernel import gf2_matmul_packed
 from repro.kernels.gf2_tiled.ref import gf2_matmul_packed_ref
 from repro.kernels.tiling import (
@@ -144,6 +155,63 @@ def test_bitserial_sliced_matches_packed(rng):
     assert np.array_equal(sliced, ref)
 
 
+def _format_operand(rng, f, bits, shape):
+    lo, hi = F.value_range(f, bits)
+    v = rng.integers(lo, hi + 1, shape)
+    return np.clip(v | 1, lo, hi) if f == "oddint" else v
+
+
+# (fmt_a, k_bits, fmt_x, l_bits, stored mask plane): every pair the serving
+# path builds — packed4 int/uint, packed4 oddint with its mask plane, and
+# the packed1 ±1 rung as 1-bit oddint
+SERVED_PAIRS = [("int", 4, "int", 8, False), ("uint", 4, "uint", 8, False),
+                ("oddint", 4, "oddint", 8, True),
+                ("oddint", 1, "oddint", 1, False)]
+# (b, m, n, blocks): a decode batch with 2 batch, 3 row and 3 lane tiles;
+# a prefill batch on the mode's own default plan (2 x 2 x 2 tiles)
+MXU_SHAPES = [(64, 300, 9000, dict(block_b=32, block_m=128, block_w=128)),
+              (264, 300, 4200, {})]
+
+
+@pytest.mark.parametrize("shape", MXU_SHAPES, ids=["decode", "prefill"])
+@pytest.mark.parametrize("pair", SERVED_PAIRS,
+                         ids=["int4x8", "uint4x8", "oddint4x8mask",
+                              "packed1"])
+def test_bitserial_mxu_body_matches_loop(rng, pair, shape):
+    """With plane factors the sliced kernel runs its MXU body: bit-identical
+    to the vector-unit loop, to the packed-plane oracle and to the integer
+    product, for every format pair the serving path hands it."""
+    fa, kb, fx, lb, mask = pair
+    b, m, n, blocks = shape
+    a = _format_operand(rng, fa, kb, (m, n))
+    x = _format_operand(rng, fx, lb, (b, n))
+    planes = F.to_bitplanes(a, kb, fa)
+    if mask:
+        planes = np.concatenate([planes, np.ones((1, m, n), np.uint8)])
+    ap = F.pack_bits(planes)
+    w_ext, k1, pop_a, pop_x, const = extended_weights(
+        fa, kb, fx, lb, n=n, a_has_mask=mask)
+    factors = plane_factors(fa, kb, fx, lb, a_has_mask=mask)
+    assert np.array_equal(w_ext[:k1, :lb], np.outer(*factors))
+
+    u = levels_to_stack(F.to_levels(x, lb, fx), F.packed_width(n))
+    kw = dict(l_bits=lb, pop_a=pop_a, pop_x=pop_x, const=const,
+              interpret=True, **blocks)
+    with_factors = functools.partial(bitserial_matmul_sliced,
+                                     plane_factors=factors, **kw)
+    assert f"name={MXU_KERNEL_NAME}" in str(
+        jax.make_jaxpr(with_factors)(u, ap, w_ext))
+    mxu = np.asarray(with_factors(u, ap, w_ext))
+    loop = np.asarray(bitserial_matmul_sliced(u, ap, w_ext, **kw))
+    xp = F.pack_bits(F.to_bitplanes(x, lb, fx))
+    ref = np.concatenate([  # the oracle's [K1, L1, rows, m, W] in row chunks
+        np.asarray(bitserial_matmul_packed_ref(xp[:, r:r + 16], ap, w_ext))
+        for r in range(0, b, 16)])
+    assert np.array_equal(mxu, loop)
+    assert np.array_equal(mxu, ref)
+    assert np.array_equal(mxu, np.asarray(integer_matmul_ref(x, a)))
+
+
 def test_autotune_cache_roundtrip(rng, tmp_path, monkeypatch):
     """autotune_plan persists the winning blocks; plan_for and a fresh
     PlanCache instance both read them back."""
@@ -182,6 +250,11 @@ def test_decode_defaults_use_thin_batch_tile(monkeypatch):
     assert p.bm >= 128        # ... traded for a fatter row tile
     big = plan_for("bitserial", 128, 512, 64)
     assert big.bb == 64
+    # the sliced kernel's MXU body takes a prefill batch in 256-row tiles;
+    # its loop body keeps the thin ones
+    assert plan_for("bitserial_sliced", 1024, 5120, 30).bb == 256
+    assert plan_for("bitserial_sliced", 64, 5120, 30).bb == 64
+    assert plan_for("bitserial_sliced_loop", 1024, 5120, 30).bb == 64
 
 
 def test_binary_block_sweep_agrees(rng):

@@ -13,6 +13,7 @@ The topology is described inside a fixture, never at import time: only
 the process that describes it loads the TPU compiler library.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,9 +25,11 @@ from repro.core.formats import packed_width
 from repro.gf2.ldpc import make_array_ldpc
 from repro.kernels.binary_mvp.kernel import binary_matmul_packed
 from repro.kernels.bitserial_mvp.kernel import (
+    MXU_KERNEL_NAME,
     bitserial_matmul_packed,
     bitserial_matmul_sliced,
 )
+from repro.kernels.bitserial_mvp.ops import plane_factors
 from repro.kernels.gf2_tiled.kernel import gf2_matmul_packed
 from repro.kernels.hamming_topk.kernel import hamming_topk_packed
 
@@ -38,6 +41,7 @@ _QKV = (_CFG.n_heads + 2 * _CFG.n_kv_heads) * _CFG.head_dim   # 1600
 _PROJ = {"wqkv": (_D, _QKV), "wo": (_CFG.n_heads * _CFG.head_dim, _D),
          "wig": (_D, 2 * _F), "wd": (_F, _D)}
 _DECODE_B, _PREFILL_B = 8, 4 * 256   # 8 slots; 4 prompts x 256 rows
+_SLOTS_B = 64                        # the decode batch of 64 serving slots
 _K_BITS, _L_BITS = 4, 8              # --weight-bits 4, act_bits 8
 _CAM_M, _CAM_BITS, _CAM_K = 65536, 256, 4
 
@@ -74,16 +78,41 @@ def _compile(fn, sharding, *shapes):
 U32, I32 = jnp.uint32, jnp.int32
 
 
-@pytest.mark.parametrize("batch", [_DECODE_B, _PREFILL_B])
+# a projection launch as the compiled module shows it: the int32 result,
+# then the level-code stack and the resident plane stack, in that order
+_LAUNCH = re.compile(r"%(\S+) = s32\[(\d+),(\d+)\]\S* custom-call\(.*"
+                     r"custom_call_target=\"tpu_custom_call\"")
+_U32_3D = re.compile(r"u32\[(\d+),(\d+),(\d+)\]")
+
+
+@pytest.mark.parametrize("batch", [_DECODE_B, _SLOTS_B, _PREFILL_B])
 @pytest.mark.parametrize("proj", sorted(_PROJ))
 def test_bitserial_sliced_compiles(one_chip, proj, batch):
-    """The decode fast path: level codes bit-sliced inside the kernel."""
+    """The decode fast path: level codes bit-sliced inside the kernel, on
+    the vector-unit body (a plain plane-pair matrix) and on the MXU body
+    (the int/int plane factors the server passes). The MXU launch keeps
+    the signature a device trace finds projections by: one custom call,
+    s32[rows, m] out, u32[32, rows, w] level codes then u32[k, m, w]
+    planes in."""
     n, m = _PROJ[proj]
     w = packed_width(n)
-    fn = jax.jit(functools.partial(bitserial_matmul_sliced, l_bits=_L_BITS,
-                                   pop_a=False, pop_x=False, const=False))
-    _compile(fn, one_chip, ((32, batch, w), U32), ((_K_BITS, m, w), U32),
-             ((_K_BITS + 1, _L_BITS + 1), I32))
+    shapes = (((32, batch, w), U32), ((_K_BITS, m, w), U32),
+              ((_K_BITS + 1, _L_BITS + 1), I32))
+    kw = dict(l_bits=_L_BITS, pop_a=False, pop_x=False, const=False)
+    _compile(jax.jit(functools.partial(bitserial_matmul_sliced, **kw)),
+             one_chip, *shapes)
+    factors = plane_factors("int", _K_BITS, "int", _L_BITS)
+    text = _compile(jax.jit(functools.partial(
+        bitserial_matmul_sliced, plane_factors=factors, **kw)),
+        one_chip, *shapes).as_text()
+    calls = [ln for ln in text.splitlines() if _LAUNCH.search(ln)]
+    assert len(calls) == 1, calls
+    name, rows, mp = _LAUNCH.search(calls[0]).groups()
+    assert name.startswith(MXU_KERNEL_NAME)
+    stack, planes = _U32_3D.findall(calls[0])[:2]
+    assert int(rows) >= batch and int(mp) >= m
+    assert stack == ("32", rows, planes[2])
+    assert planes[:2] == (str(_K_BITS), mp) and int(planes[2]) >= w
 
 
 @pytest.mark.parametrize("proj", ["wig", "wd"])
