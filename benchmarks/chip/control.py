@@ -34,9 +34,10 @@ import harness  # noqa: E402
 
 def control(cell: dict, kept: dict, ctrl_bits: int = 4) -> dict:
     """The program's and the control's checks on one kept run."""
-    _, spec, cfg, m = harness.cell_model(cell)
-    recs = harness.reference_gaps(kept["records"], cfg, m, kept["seed"],
-                                  spec["max_seq"], ctrl_bits=ctrl_bits)
+    _, spec, cfg, m, reference = harness.cell_model(cell)
+    recs = harness.reference_gaps(kept["records"], reference, cfg, m,
+                                  kept["seed"], spec["max_seq"],
+                                  ctrl_bits=ctrl_bits)
     limits = harness.check_limit(cell)
     prog, prog_ok = harness.judge(recs, limits, key="gap")
     ctrl, ctrl_ok = harness.judge(recs, limits, key="ctrl")

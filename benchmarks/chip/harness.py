@@ -4,7 +4,8 @@ Everything that belongs to one configuration, one traffic mix or one
 per-layer metric lives in a file of its own under this directory, found
 by the names in ``BENCHMARK.json``:
 
-* ``configs/<config>.json``   model numbers (as run) and serving bits,
+* ``configs/<config>.json``   model numbers (as run), block keys, serving
+                              bits and the plain reference it names,
 * ``traffic/<mix>.json``      loop, lengths, rate and serving shape,
 * ``metrics/<metric>.py``     one reader per per-layer metric,
 * ``checks/<cell>.json``      the limit of the output comparison.
@@ -23,6 +24,7 @@ import gc
 import importlib.util
 import json
 import os
+import re
 import sys
 import time
 from typing import Callable, Dict, List, Optional
@@ -39,7 +41,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 import loadgen  # noqa: E402
-import ref  # noqa: E402
 import tails  # noqa: E402
 import weights  # noqa: E402
 import work  # noqa: E402
@@ -51,10 +52,18 @@ FIELDS = {"hidden_size": "d_model", "intermediate_size": "d_ff",
           "num_hidden_layers": "n_layers", "vocab_size": "vocab",
           "rms_norm_eps": "norm_eps", "layer_norm_eps": "norm_eps",
           "rope_theta": "rope_theta", "tie_word_embeddings": "tie_embeddings"}
-# keys whose only value the served block supports
-FIXED = {"partial_rotary_factor": 1.0, "qk_layernorm": False,
-         "use_parallel_residual": False, "use_qkv_bias": False,
-         "hidden_act": "silu"}
+# block keys: source key -> ModelConfig field. A stated key reaches the
+# field where the program's ModelConfig has it; where it has not, the key
+# must hold the llama block's value (LLAMA), or the config is refused.
+BLOCK_KEYS = {"partial_rotary_factor": "rotary_fraction",
+              "qk_layernorm": "qk_norm",
+              "use_parallel_residual": "parallel_residual",
+              "use_qkv_bias": "qkv_bias"}
+# the norm key a file states names its norm (field ``norm``)
+NORM_KEYS = {"rms_norm_eps": "rms", "layer_norm_eps": "layer"}
+LLAMA = {"norm": "rms", "rotary_fraction": 1.0, "qk_norm": False,
+         "parallel_residual": False, "qkv_bias": False}
+DEFAULT_REFERENCE = "ref.py"
 
 COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",)
 # JAX's persistent compile cache: a fixed path inside the checkout, in a
@@ -109,17 +118,44 @@ def cell(name: str) -> dict:
 
 # -- configuration -------------------------------------------------------------
 
+def stated_block(conf: dict) -> Dict[str, tuple]:
+    """ModelConfig field -> (source key, value) of every block key the
+    file states."""
+    out = {field: (key, conf[key]) for key, field in BLOCK_KEYS.items()
+           if key in conf}
+    norms = [k for k in NORM_KEYS if k in conf]
+    if len(norms) > 1:
+        raise BenchError(f"{conf['name']}: {' and '.join(norms)} state two "
+                         f"norms")
+    if norms:
+        out["norm"] = (norms[0], NORM_KEYS[norms[0]])
+    return out
+
+
 def model_config(conf: dict):
     """The program's ModelConfig for a config file: the architecture's
-    published preset with every numbered key the file states."""
-    from repro.configs.base import load_arch
-    for k, v in FIXED.items():
-        if k in conf and conf[k] != v:
-            raise BenchError(f"{conf['name']}: the served block supports "
-                             f"only {k}={v!r}, the file says {conf[k]!r}")
+    published preset with every numbered key and every block key the
+    file states. A block key whose field the ModelConfig lacks must hold
+    the llama value; else :class:`BenchError` names the key."""
+    from repro.configs.base import ModelConfig, load_arch
+    name = conf["name"]
+    if conf.get("hidden_act", "silu") != "silu":
+        raise BenchError(f"{name}: hidden_act={conf['hidden_act']!r}; the "
+                         f"served MLP is SwiGLU (silu)")
+    have = {f.name for f in dataclasses.fields(ModelConfig)}
+    stated = stated_block(conf)
+    for field, (key, value) in stated.items():
+        if field not in have and value != LLAMA[field]:
+            raise BenchError(
+                f"{name}: {key} states {field}={value!r}, which the "
+                f"program's ModelConfig has no field {field!r} to carry; "
+                f"it would be served as {field}={LLAMA[field]!r}")
     cfg = load_arch(conf["arch"]).full()
     over = {FIELDS[k]: conf[k] for k in FIELDS if k in conf}
-    over["head_dim"] = conf["hidden_size"] // conf["num_attention_heads"]
+    over.update({field: value for field, (_, value) in stated.items()
+                 if field in have})
+    over["head_dim"] = conf.get(
+        "head_dim", conf["hidden_size"] // conf["num_attention_heads"])
     cfg = dataclasses.replace(cfg, **over)
     s = conf["serving"]
     return dataclasses.replace(
@@ -133,18 +169,57 @@ def model_config(conf: dict):
 
 
 def model_numbers(cfg) -> Dict:
-    """The numbers ``work.py`` and ``ref.py`` need, from the config as run."""
+    """The numbers ``work.py`` and the reference need, from the config as
+    run: widths, depth and bits, and the block's value of every block
+    field (the llama value where the ModelConfig has no such field)."""
     return dict(d_model=cfg.d_model, n_heads=cfg.n_heads,
                 n_kv_heads=cfg.n_kv_heads, head_dim=cfg.hd, d_ff=cfg.d_ff,
                 vocab=cfg.vocab, n_layers=cfg.n_layers,
                 norm_eps=cfg.norm_eps, rope_theta=cfg.rope_theta,
                 tie_embeddings=cfg.tie_embeddings,
-                weight_bits=cfg.ppac.weight_bits, act_bits=cfg.ppac.act_bits)
+                weight_bits=cfg.ppac.weight_bits, act_bits=cfg.ppac.act_bits,
+                **{f: getattr(cfg, f, v) for f, v in LLAMA.items()})
+
+
+def load_reference(name: str):
+    """The plain reference module ``name``, a file under this directory
+    (as ``ref.py`` or ``testdata/ref_x.py``), loaded once a process."""
+    here = os.path.realpath(HERE)
+    path = os.path.realpath(os.path.join(here, name))
+    if (os.path.commonpath([path, here]) != here
+            or not path.endswith(".py") or not os.path.isfile(path)):
+        raise BenchError(f"reference {name!r} is no module file of the "
+                         f"benchmark's directory")
+    modname = "chipbench_reference_" + re.sub(r"\W", "_", name[:-3])
+    if modname not in sys.modules:
+        spec = importlib.util.spec_from_file_location(modname, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[modname] = mod
+    return sys.modules[modname]
+
+
+def check_reference(conf: dict, m: Dict, reference) -> None:
+    """Refuse a config whose block its reference does not model, naming
+    the key: the reference's ``BLOCK`` gives the value it models of each
+    block field (the llama value where it is silent)."""
+    stated = stated_block(conf)
+    for field, llama in LLAMA.items():
+        models = reference.BLOCK.get(field, llama)
+        if m[field] != models:
+            key = stated.get(field, (f"{field} (from the "
+                                     f"{conf['arch']} preset)",))[0]
+            raise BenchError(
+                f"{conf['name']}: {key} gives {field}={m[field]!r}, but "
+                f"its reference {conf.get('reference', DEFAULT_REFERENCE)}"
+                f" models {field}={models!r}")
 
 
 def cell_model(cell: dict):
-    """(config file, traffic file, ModelConfig, model numbers) of a cell.
-    A sequence may not run past the model's stated context."""
+    """(config file, traffic file, ModelConfig, model numbers, reference
+    module) of a cell. A sequence may not run past the model's stated
+    context, and the reference the file names (``"reference"``, default
+    ``ref.py``) has to model the file's block."""
     conf = load_json(ROOT, cell["config_entry"]["file"])
     spec = loadgen.load_traffic(cell["traffic"])
     ctx = conf.get("max_position_embeddings")
@@ -152,7 +227,10 @@ def cell_model(cell: dict):
         raise BenchError(f"{cell['name']}: max_seq {spec['max_seq']} is "
                          f"past the model's context of {ctx}")
     cfg = model_config(conf)
-    return conf, spec, cfg, model_numbers(cfg)
+    m = model_numbers(cfg)
+    reference = load_reference(conf.get("reference", DEFAULT_REFERENCE))
+    check_reference(conf, m, reference)
+    return conf, spec, cfg, m, reference
 
 
 # -- instrumentation ----------------------------------------------------------
@@ -243,7 +321,8 @@ class Run:
     def __init__(self, cell: dict, seed: int, *, t_start: float,
                  server_hook: Optional[Callable] = None):
         self.cell, self.seed, self.t_start = cell, int(seed), t_start
-        self.conf, self.spec, self.cfg, self.m = cell_model(cell)
+        self.conf, self.spec, self.cfg, self.m, self.reference = \
+            cell_model(cell)
         self.traffic = loadgen.Traffic(self.spec, self.seed, self.cfg.vocab)
         self.tracks: Dict[int, tails.Track] = {}
         self.requests: Dict[int, object] = {}
@@ -505,24 +584,29 @@ def sample(reqs: List[object], k: int, seed: int, stream: int
     return [longest] + [rest[i] for i in sorted(pick)]
 
 
-def reference_gaps(records: List[Dict], cfg, m: Dict, seed: int,
-                   length: int, *, ctrl_bits: int = 0) -> List[Dict]:
+def reference_gaps(records: List[Dict], reference, cfg, m: Dict,
+                   seed: int, length: int, *, ctrl_bits: int = 0
+                   ) -> List[Dict]:
     """Run the plain reference once over each record's prompt and served
-    tokens (the program's state must already be freed) and set its
-    ``gap``: the widest gap between the reference's best logit and its
-    logit for a served token. With ``ctrl_bits`` also its ``ctrl``: the
-    same gap for the token that the reference at ``ctrl_bits``-bit
-    activations puts first at each of those positions (the control)."""
-    params = weights.float_params(cfg, seed)
-    for rec in records:
-        gap, ctrl, n = ref.served_gaps(params, m, rec["prompt"],
-                                       rec["served"], length=length,
-                                       ctrl_bits=ctrl_bits)
+    tokens (the program's state must already be freed), layer by layer
+    over all records, on the seed's float weights made in pieces, and
+    set each record's ``gap``: the widest gap between the reference's
+    best logit and its logit for a served token. With ``ctrl_bits`` also
+    its ``ctrl``: the same gap for the token that the reference at
+    ``ctrl_bits``-bit activations puts first at each of those positions
+    (the control)."""
+    t0 = time.perf_counter()
+    out = reference.served_gaps(
+        weights.FloatWeights(cfg, seed), m,
+        [(rec["prompt"], rec["served"]) for rec in records],
+        length=length, ctrl_bits=ctrl_bits)
+    for rec, (gap, ctrl, n) in zip(records, out):
         rec["gap"], rec["tokens"] = gap, n
         if ctrl_bits:
             rec["ctrl"] = ctrl
-    del params
     gc.collect()
+    log(f"reference: {time.perf_counter() - t0:.3f} s over "
+        f"{len(records)} requests")
     return records
 
 
@@ -632,8 +716,8 @@ def execute(cell: dict, seed: int, seconds: float, trace: bool, *,
                  if tr.outcome in ("failed", "shed")) + \
         int(e2e.get("n_missing", 0))
     run.free()
-    recs = reference_gaps(run.compared(), run.cfg, run.m, run.seed,
-                          run.spec["max_seq"])
+    recs = reference_gaps(run.compared(), run.reference, run.cfg, run.m,
+                          run.seed, run.spec["max_seq"])
     checks, correct = judge(recs, check_limit(cell))
     if keep is not None:
         with open(keep, "w") as f:
