@@ -132,17 +132,24 @@ def stated_block(conf: dict) -> Dict[str, tuple]:
     return out
 
 
+def program_preset(arch: str):
+    """The program's published ModelConfig of architecture ``arch``. Its
+    fields are the ones a block key can reach."""
+    from repro.configs.base import load_arch
+    return load_arch(arch).full()
+
+
 def model_config(conf: dict):
     """The program's ModelConfig for a config file: the architecture's
     published preset with every numbered key and every block key the
     file states. A block key whose field the ModelConfig lacks must hold
     the llama value; else :class:`BenchError` names the key."""
-    from repro.configs.base import ModelConfig, load_arch
     name = conf["name"]
     if conf.get("hidden_act", "silu") != "silu":
         raise BenchError(f"{name}: hidden_act={conf['hidden_act']!r}; the "
                          f"served MLP is SwiGLU (silu)")
-    have = {f.name for f in dataclasses.fields(ModelConfig)}
+    cfg = program_preset(conf["arch"])
+    have = {f.name for f in dataclasses.fields(cfg)}
     stated = stated_block(conf)
     for field, (key, value) in stated.items():
         if field not in have and value != LLAMA[field]:
@@ -150,7 +157,6 @@ def model_config(conf: dict):
                 f"{name}: {key} states {field}={value!r}, which the "
                 f"program's ModelConfig has no field {field!r} to carry; "
                 f"it would be served as {field}={LLAMA[field]!r}")
-    cfg = load_arch(conf["arch"]).full()
     over = {FIELDS[k]: conf[k] for k in FIELDS if k in conf}
     over.update({field: value for field, (_, value) in stated.items()
                  if field in have})

@@ -14,7 +14,10 @@ takes nothing the program made: it quantizes the float weights itself.
 refuses a configuration whose block differs. A configuration names its
 reference in its file (``"reference"``, default ``ref.py``), and a
 reference of another block is a module of its own beside this one, with
-the same ``BLOCK`` and :func:`served_gaps`.
+its ``BLOCK`` and a ``served_gaps``: it writes down its block's
+:func:`prepare`, :func:`layer` and :func:`logits`, and hands them to this
+module's :func:`served_gaps`, which drives them layer by layer, with
+:func:`quantize`, :func:`qproj` and :func:`rope` to build on.
 
 It runs layer by layer over every compared request together: layer l
 for every request, then layer l + 1, with only layer l's weights made
@@ -26,7 +29,7 @@ the next precision down.
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Sequence, Tuple
+from typing import Callable, Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -52,7 +55,6 @@ def quantize(x, bits: int, axis: int):
     return jnp.clip(jnp.round(x / s), -hi - 1, hi), s
 
 
-@functools.partial(jax.jit, static_argnames="bits")
 def prepare(p, *, bits: int):
     """One layer's float weights -> the weights the layer reads: every
     projection as int8 levels and float32 per-output-channel scales,
@@ -110,10 +112,18 @@ def layer(h, p, c: Dict, *, act_bits: int):
     return h + proj(jax.nn.silu(gate) * up, p["mlp.wo"])
 
 
-@functools.partial(jax.jit, static_argnames=("cfg_items", "act_bits"))
-def _layer(h, p, *, cfg_items, act_bits):
+# each runs one block's function, given as a static argument, in a
+# jitted call of its own
+
+@functools.partial(jax.jit, static_argnames=("fn", "bits"))
+def _prepare(p, *, fn, bits):
+    return fn(p, bits=bits)
+
+
+@functools.partial(jax.jit, static_argnames=("fn", "cfg_items", "act_bits"))
+def _layer(h, p, *, fn, cfg_items, act_bits):
     with jax.default_matmul_precision("highest"):
-        return layer(h, p, dict(cfg_items), act_bits=act_bits)
+        return fn(h, p, dict(cfg_items), act_bits=act_bits)
 
 
 @jax.jit
@@ -129,17 +139,17 @@ def logits(h, top, c: Dict):
     return h @ top["lm_head"]["w"]
 
 
-@functools.partial(jax.jit, static_argnames=("cfg_items", "ctrl"))
-def _gaps(h, low, top, targets, mask, *, cfg_items, ctrl):
+@functools.partial(jax.jit, static_argnames=("fn", "cfg_items", "ctrl"))
+def _gaps(h, low, top, targets, mask, *, fn, cfg_items, ctrl):
     c = dict(cfg_items)
     with jax.default_matmul_precision("highest"):
-        ref = logits(h, top, c)
+        ref = fn(h, top, c)
         best = ref.max(-1)
         served = jnp.take_along_axis(ref, targets[:, None], -1)[:, 0]
         gap = jnp.where(mask, best - served, 0.0)
         if not ctrl:
             return gap, jnp.zeros_like(gap)
-        pick = jnp.argmax(logits(low, top, c), -1)
+        pick = jnp.argmax(fn(low, top, c), -1)
     ctrl_gap = best - jnp.take_along_axis(ref, pick[:, None], -1)[:, 0]
     return gap, jnp.where(mask, ctrl_gap, 0.0)
 
@@ -163,7 +173,8 @@ def _rows(prompt, served, length: int):
 
 
 def served_gaps(weights, c: Dict, requests: Sequence[Tuple], *,
-                length: int, ctrl_bits: int = 0
+                length: int, ctrl_bits: int = 0, prepare: Callable = prepare,
+                layer: Callable = layer, logits: Callable = logits
                 ) -> List[Tuple[float, object, int]]:
     """Widest gaps of served requests against the reference.
 
@@ -177,24 +188,30 @@ def served_gaps(weights, c: Dict, requests: Sequence[Tuple], *,
     ``ctrl_bits`` it also reads, at the same positions, the gap of the
     token the reference at ``ctrl_bits``-bit activations puts first (the
     control). Returns, per request, (max served gap, max control gap or
-    None, tokens compared)."""
+    None, tokens compared).
+
+    ``prepare``, ``layer`` and ``logits`` are the block's equations (this
+    module's llama block by default): one layer's float weights to what
+    the layer reads, one layer over a sequence, and the final norm with
+    the LM head. Each must be a module-level function, so that the same
+    function finds its compiled program again."""
     items = tuple(sorted(c.items()))
     rows = [_rows(p, s, length) for p, s in requests]
     top = weights.top()
     hs = [_embed(top["embed"]["table"], jnp.asarray(t)) for t, _, _ in rows]
     lows = list(hs) if ctrl_bits else []
     for i in range(c["n_layers"]):
-        p = prepare(weights.layer(i), bits=c["weight_bits"])
-        hs = [_layer(h, p, cfg_items=items, act_bits=c["act_bits"])
+        p = _prepare(weights.layer(i), fn=prepare, bits=c["weight_bits"])
+        hs = [_layer(h, p, fn=layer, cfg_items=items, act_bits=c["act_bits"])
               for h in hs]
-        lows = [_layer(h, p, cfg_items=items, act_bits=ctrl_bits)
+        lows = [_layer(h, p, fn=layer, cfg_items=items, act_bits=ctrl_bits)
                 for h in lows]
         del p
     out = []
     for j, (_, targets, mask) in enumerate(rows):
         gap, ctrl = _gaps(hs[j], lows[j] if ctrl_bits else hs[j], top,
                           jnp.asarray(targets), jnp.asarray(mask),
-                          cfg_items=items, ctrl=bool(ctrl_bits))
+                          fn=logits, cfg_items=items, ctrl=bool(ctrl_bits))
         gap, ctrl = np.asarray(gap), np.asarray(ctrl)
         out.append((float(gap.max()),
                     float(ctrl.max()) if ctrl_bits else None,

@@ -8,11 +8,13 @@ inside the window, and ``control.py`` puts the reference at 4-bit
 activations in the program's place and sends its tokens through the
 same comparison.
 
-Readings at this size (CPU, 2 s windows, 8 requests compared, seeds 1,
-2, 3, 2**31 + 5): the program's widest gap 0.0070-0.0218, the
-control's (the reference at 4-bit activations) 0.219-0.419. ``LIMIT``
-sits between them: 3.7 times the highest program reading, 2.7 times
-below the lowest control reading.
+Readings at this size (CPU, 1.5 s windows, seeds 1, 2, 3, 2**31 + 5,
+norm scales drawn about 1): the program's widest gap 0.0044-0.0184, the
+control's (the reference at 4-bit activations) 0.241-0.612 over the
+finished requests and 0.173-0.260 over the window's admissions; with
+every served norm scale reset to 1, 0.188-0.239 and 0.115-0.190.
+``LIMIT`` sits between them: 4.3 times the highest program reading, 2.4
+times below the lowest reading that has to fail.
 """
 import json
 import os
@@ -123,12 +125,35 @@ def altered_token(server):
     ex.decode = decode
 
 
+def norm_scales_ignored(server):
+    """Every norm scale the executor serves is reset to 1: a program
+    that ignores its norm scales."""
+    ex = server.ex
+    reset = []
+
+    def one(path, x):
+        if getattr(path[-1], "key", None) != "scale":
+            return x
+        reset.append(jax.tree_util.keystr(path))
+        return jnp.ones_like(x)
+    ex.params = jax.tree_util.tree_map_with_path(one, ex.params)
+    assert reset
+
+
 @pytest.mark.parametrize("fault", [state_unchanged, half_batch,
                                    altered_token])
 def test_fault_under_the_timed_path_is_not_correct(fault):
     out = run(fault)
     assert out["correct"] is False
     assert out["checks"]["max_logit_gap"]["value"] > LIMIT
+
+
+@pytest.mark.parametrize("seed", SEEDS + (3,))
+def test_norm_scales_reset_to_one_is_not_correct(seed):
+    out = run(norm_scales_ignored, seed=seed)
+    assert out["correct"] is False
+    assert max(out["checks"][k]["value"] for k in
+               ("max_logit_gap", "window_logit_gap")) > LIMIT
 
 
 def test_fault_in_window_admissions_is_not_correct(monkeypatch):

@@ -43,13 +43,13 @@ def one_call_float_params(cfg, seed):
 
     def fill(key, i, path, sds):
         name = str(getattr(path[-1], "key", path[-1]))
+        x = jax.random.normal(jax.random.fold_in(key, i), sds.shape,
+                              sds.dtype)
         if name == "scale":
-            return jnp.ones(sds.shape, sds.dtype)
+            return x * weights.SIGMA + 1.0
         if name == "b":
-            return jnp.zeros(sds.shape, sds.dtype)
-        std = 0.02 if name == "table" else sds.shape[-2] ** -0.5
-        return jax.random.normal(jax.random.fold_in(key, i), sds.shape,
-                                 sds.dtype) * std
+            return x * weights.SIGMA
+        return x * (0.02 if name == "table" else sds.shape[-2] ** -0.5)
 
     def make(key):
         return jax.tree_util.tree_unflatten(
@@ -116,6 +116,33 @@ def test_counts_carry_into_the_high_word():
     got = (np.asarray(hi).astype(np.uint64) << np.uint64(32)) | \
         np.asarray(lo).astype(np.uint64)
     assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("path,shape", [
+    (("layers", "attn", "gate", "w"), (2, 64)),
+    (("head_gain",), (64,))])
+def test_layout_refuses_a_leaf_it_cannot_draw(path, shape, monkeypatch):
+    """A leaf that is no norm scale, bias or table, with one dimension
+    past the layer axis, has no fan-in: it is refused by its path."""
+    from repro.models import lm
+    sds = jax.ShapeDtypeStruct
+    tree = {"embed": {"table": sds((256, 64), jnp.float32)},
+            "layers": {"ln1": {"scale": sds((2, 64), jnp.float32)},
+                       "attn": {"wq": {"w": sds((2, 64, 64), jnp.float32),
+                                       "b": sds((2, 64), jnp.float32)}}}}
+    node = tree
+    for k in path[:-1]:
+        node = node.setdefault(k, {})
+    node[path[-1]] = sds(shape, jnp.float32)
+    monkeypatch.setattr(lm, "abstract_init", lambda cfg: (tree, None))
+    with pytest.raises(ValueError, match="/".join(path)):
+        weights.layout(smoke_cfg())
+    del node[path[-1]]
+    got = {x.path: (x.mean, x.std) for x in weights.layout(smoke_cfg())}
+    assert got == {("embed", "table"): (0.0, 0.02),
+                   ("layers", "ln1", "scale"): (1.0, weights.SIGMA),
+                   ("layers", "attn", "wq", "w"): (0.0, 0.125),
+                   ("layers", "attn", "wq", "b"): (0.0, weights.SIGMA)}
 
 
 # -- the one-piece reference it replaced --------------------------------------
@@ -212,3 +239,25 @@ def test_layer_by_layer_reference_gives_the_one_piece_gaps(kept, variant):
         # program, as the check needs
         assert max(r["ctrl"] for r in recs) > \
             4 * max(r["gap"] for r in recs)
+
+
+def _no_layer(h, p, c, *, act_bits):
+    """A block whose layers pass the stream through."""
+    return h
+
+
+def test_served_gaps_drives_the_block_it_is_given(kept):
+    """``served_gaps`` runs the block's functions it is handed: the llama
+    block's own give its default gaps, exactly; another block other
+    gaps."""
+    cfg = smoke_cfg()
+    m = harness.model_numbers(cfg)
+    reqs = [(r["prompt"], r["served"]) for r in kept["records"]]
+
+    def gaps(**block):
+        return ref.served_gaps(weights.FloatWeights(cfg, SEED), m, reqs,
+                               length=96, ctrl_bits=4, **block)
+    default = gaps()
+    assert gaps(prepare=ref.prepare, layer=ref.layer,
+                logits=ref.logits) == default
+    assert gaps(layer=_no_layer) != default
