@@ -4,10 +4,10 @@ caches, slot-based admission/eviction, bucketed prefill.
 The serving loop the kernel work of PRs 3-4 was building toward — the LM
 itself served to many concurrent users:
 
-  * a resident cache pytree sized [slots, max_seq, ...] lives on device
-    for the whole server lifetime; every jitted entry point *donates* it
-    (``donate_argnums``), so per-token cache updates are in-place
-    scatters, never whole-cache copies,
+  * a resident cache pytree of ``slots`` sequences of ``max_seq`` rows
+    lives on device for the whole server lifetime; every jitted entry
+    point *donates* it (``donate_argnums``), so per-token cache updates
+    are in-place writes, never whole-cache copies,
   * decode runs as ONE fused step over all slots with per-sequence
     positions (``cache['pos']: [S]``) — sequences at different depths
     (admitted mid-flight) share the step bit-exactly with solo decoding,

@@ -11,7 +11,7 @@ Everything device-side lives behind an executor object from this module:
     :func:`repro.launch.specs.serving_param_shardings`) and the resident
     slot cache shards slot-parallel over 'data' (DP). Every jitted entry
     point still donates the cache pytree, so the PR 4–7 invariants
-    (donation aliasing, zero weight-repack, in-place scatter) hold
+    (donation aliasing, zero weight-repack, in-place cache writes) hold
     unchanged on the sharded path.
 
   * :class:`DisaggExecutor` — disaggregated serving: a pool of

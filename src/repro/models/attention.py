@@ -5,23 +5,32 @@ Memory-efficient chunked attention: queries are processed in chunks via
 remat of the chunk body — required for the 32k prefill shapes on a real
 chip and for bounded compile-time memory on the dry-run.
 
-KV caches are plain pytrees: {"k": [B,T,Hkv,D], "v": [B,T,Hkv,Dv]} with a
-*per-sequence* write position ``pos: [B]`` — mixed-progress batches (the
-continuous-batching server admits new prompts mid-flight) decode with one
-fused step. Sliding-window attention uses a rolling (ring) cache of size
+KV caches are plain pytrees with a *per-sequence* write position
+``pos: [B]`` — mixed-progress batches (the continuous-batching server
+admits new prompts mid-flight) decode with one fused step. A contiguous
+GQA cache is stored position-minor: {"k": [B,Hkv,D,T], "v": [B,Hkv,Dv,T]}
+(int8 scales [B,Hkv,1,T]), the layout the chip keeps it in — (D, T)
+fills whole (16, 128) tiles, where [B,T,Hkv,D] pads the few KV heads to
+a sublane tile. Its writes are selects against the position axis (a
+row at or past T matches nothing and drops) or, for prefill into a
+fresh cache, an update slice, never a scatter: a TPU scatter asks for
+the written window (Hkv, D) minor and so for a padded copy of the
+cache. Sliding-window attention uses a rolling (ring) cache of size
 ``window`` for decode: position ``p`` always lives at slot ``p % window``,
 in prefill and decode alike, so decode can roll straight out of any
-prefill length (bounds long-context memory). MLA caches the compressed
-(kv_lora + rope) stream and decodes via the absorbed-projection trick —
-the KV-memory win that makes it the natural PPAC companion for decode
-shapes. Decode writes are batched scatters (per-sequence slots), which
-lower in place when the cache pytree is donated (serve/step.py jits every
-decode entry point with ``donate_argnums`` on the cache).
+prefill length (bounds long-context memory). Paged pools keep
+[P, psz, Hkv, D] rows (a position-minor page would pad ``psz`` to 128
+lanes) and write by scatter; decode reads their gathered view in the
+position-minor order. MLA caches the compressed (kv_lora + rope) stream
+[B,T,*] and decodes via the absorbed-projection trick — the KV-memory
+win that makes it the natural PPAC companion for decode shapes. Every
+write lowers in place when the cache pytree is donated (serve/step.py
+jits every decode entry point with ``donate_argnums`` on the cache).
 
 Named scopes mark the ops of each part in the compiled program's op
 metadata, and so in a device trace: ``proj/wqkv`` and ``proj/wo`` (the
-projections), ``kv_write`` (every cache write: contiguous update slice,
-scatter, paged scatter) and ``attn`` (the attention over the cache or
+projections), ``kv_write`` (every cache write: position select, update
+slice, paged scatter) and ``attn`` (the attention over the cache or
 the prompt).
 """
 from __future__ import annotations
@@ -196,20 +205,22 @@ def gqa_init(key, cfg: ModelConfig):
 
 def gqa_cache_init(cfg: ModelConfig, batch: int, max_seq: int,
                    dtype=jnp.bfloat16):
+    """Position-minor leaves [B, Hkv, D, T] (module docstring)."""
     t = min(max_seq, cfg.sliding_window) if cfg.sliding_window else max_seq
-    shape = (batch, t, cfg.n_kv_heads, cfg.hd)
+    shape = (batch, cfg.n_kv_heads, cfg.hd, t)
     if cfg.kv_dtype == "int8":
         # per-(token, head) max-scaled int8 store — 2x smaller cache, the
         # decode memory-roofline lever paired with PPAC resident weights
+        sshape = (batch, cfg.n_kv_heads, 1, t)
         return {"k": jnp.zeros(shape, jnp.int8),
                 "v": jnp.zeros(shape, jnp.int8),
-                "ks": jnp.zeros(shape[:3] + (1,), jnp.bfloat16),
-                "vs": jnp.zeros(shape[:3] + (1,), jnp.bfloat16)}
+                "ks": jnp.zeros(sshape, jnp.bfloat16),
+                "vs": jnp.zeros(sshape, jnp.bfloat16)}
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def gqa_cache_axes(cfg: ModelConfig):
-    ax = ("batch", "kv_seq", "kv_heads", None)
+    ax = GQA_CACHE_AXES["k"]
     if cfg.kv_dtype == "int8":
         return {"k": ax, "v": ax, "ks": ax, "vs": ax}
     return {"k": ax, "v": ax}
@@ -250,13 +261,19 @@ def gqa_paged_cache_axes(cfg: ModelConfig):
 def paged_view(pool, table):
     """Gather a pool [P, psz, ...] through table [B, n] -> [B, n*psz, ...].
 
-    The per-slot logical view decode/suffix attention runs against —
-    identical, row for row, to what a contiguous [B, T, ...] cache would
-    hold (unallocated table entries read page 0; those rows sit beyond
-    every validity/causality mask, so their values never contribute)."""
+    The per-slot logical view suffix attention runs against — identical,
+    row for row, to the rows a contiguous cache holds (unallocated table
+    entries read page 0; those rows sit beyond every validity/causality
+    mask, so their values never contribute)."""
     b, n = table.shape
     rows = jnp.take(pool, table, axis=0, mode="clip")   # [B, n, psz, ...]
     return rows.reshape((b, n * pool.shape[1]) + pool.shape[2:])
+
+
+def paged_decode_view(pool, table):
+    """``paged_view`` in the contiguous cache's position-minor order
+    [B, Hkv, *, T], the layout decode attention reads."""
+    return jnp.moveaxis(paged_view(pool, table), 1, -1)
 
 
 def paged_scatter(pool, table, rows, row_idx, valid=None):
@@ -309,8 +326,8 @@ def _attend_causal_rows(q, k, v, q_pos, *, scale, rules=None,
     return out
 
 
-GQA_CACHE_AXES = {"k": ("batch", "kv_seq", "kv_heads", None),
-                  "v": ("batch", "kv_seq", "kv_heads", None)}
+GQA_CACHE_AXES = {"k": ("batch", "kv_heads", None, "kv_seq"),
+                  "v": ("batch", "kv_heads", None, "kv_seq")}
 
 
 def _q8_kv(x):
@@ -329,16 +346,26 @@ def as_pos_vector(pos, batch: int):
     return pos
 
 
-def _scatter_rows(cache_leaf, rows, slot):
-    """Write rows [B,1,...] at per-sequence slots [B] of cache [B,T,...]."""
-    b = cache_leaf.shape[0]
-    return cache_leaf.at[jnp.arange(b), slot].set(
-        rows[:, 0].astype(cache_leaf.dtype), mode="drop")
+def _select_rows(cache_leaf, rows, row_idx):
+    """Write rows [B,S,Hkv,*] at per-sequence positions ``row_idx`` [B,S]
+    of a position-minor cache leaf [B,Hkv,*,T]: one select against the
+    position axis per written row, so the write fuses with the leaf's
+    readers and updates a donated buffer in place. A row at or past T
+    matches no position and drops (a verify window running past the
+    cache, a slot past its last row)."""
+    cols = jnp.moveaxis(rows.astype(cache_leaf.dtype), 1, -1)  # [B,Hkv,*,S]
+    at = jnp.arange(cache_leaf.shape[-1], dtype=jnp.int32)
+    for i in range(row_idx.shape[1]):
+        hit = at[None, :] == row_idx[:, i:i + 1]                 # [B,T]
+        cache_leaf = jnp.where(hit[:, None, None, :], cols[..., i:i + 1],
+                               cache_leaf)
+    return cache_leaf
 
 
 def _scatter_rows_multi(cache_leaf, rows, row_idx):
-    """Write rows [B,S,...] at per-sequence rows [B,S] of cache [B,T,...].
-    Out-of-range rows (a verify window running past the cache) drop."""
+    """Write rows [B,S,...] at per-sequence rows [B,S] of cache [B,T,...]
+    (the MLA stream). Out-of-range rows (a verify window running past
+    the cache) drop."""
     b = cache_leaf.shape[0]
     return cache_leaf.at[jnp.arange(b)[:, None], row_idx].set(
         rows.astype(cache_leaf.dtype), mode="drop")
@@ -375,8 +402,10 @@ def _decode_attend_q8(q, cache, k_valid, *, scale, rules=None):
 
     ``k_valid: [B]`` — per-sequence count of valid cache slots (mixed-
     progress batches decode at different positions in one fused step).
+    The cache leaves are position-minor ([B,g,D,T], scales [B,g,1,T]) and
+    both einsums contract on that layout as stored: no transposed copy.
     The per-(t,g) scales factor out of both einsums, so no dequantized
-    [B,T,G,D] tensor is materialized:
+    [B,G,D,T] tensor is materialized:
         scores = (q · ki) * ks ;  out = ((w*vs) · vi)
     Like ``_attend_prepped``, every head-indexed einsum is constrained to
     the 'model' axis (the grouped dim g carries the kv-head sharding).
@@ -384,27 +413,29 @@ def _decode_attend_q8(q, cache, k_valid, *, scale, rules=None):
     b, s, h, d = q.shape          # s == 1 decode; s > 1 verifies a window
     ki, vi = cache["k"], cache["v"]
     ks, vs = cache.get("ks"), cache.get("vs")
-    t, g = ki.shape[1], ki.shape[2]
+    g, t = ki.shape[1], ki.shape[-1]
     rep = h // g
     qg = q.reshape(b, s, g, rep, d)
     if rules is not None:
         qg = constrain(qg, rules, "batch", None, "act_heads", None, None)
-    scores = jnp.einsum("bsgrd,btgd->bgrst", qg, ki.astype(q.dtype),
+    # [B,g,S,r,T]: the query axes keep q's order (the CPU backend runs
+    # no bf16 dot whose output transposes them)
+    scores = jnp.einsum("bsgrd,bgdt->bgsrt", qg, ki.astype(q.dtype),
                         preferred_element_type=jnp.float32) * scale
     if rules is not None:
         scores = constrain(scores, rules, "batch", "act_heads", None, None,
                            None)
     if ks is not None:
-        scores = scores * ks[..., 0].transpose(0, 2, 1)[:, :, None, None, :]
+        scores = scores * ks[:, :, None]                 # [B,g,1,1,T]
     kv = jnp.asarray(k_valid, jnp.int32)
     kv = kv[:, None] if kv.ndim == 1 else kv           # [B,S] counts
     mask = jnp.arange(t)[None, None, :] < kv[:, :, None]   # [B,S,T]
-    scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
+    scores = jnp.where(mask[:, None, :, None, :], scores, NEG_INF)
     w = jax.nn.softmax(scores, axis=-1)
     wv = w.astype(q.dtype)
     if vs is not None:
-        wv = wv * vs[..., 0].transpose(0, 2, 1)[:, :, None, None, :]
-    out = jnp.einsum("bgrst,btgv->bsgrv", wv, vi.astype(q.dtype),
+        wv = wv * vs[:, :, None]
+    out = jnp.einsum("bgsrt,bgvt->bsgrv", wv, vi.astype(q.dtype),
                      preferred_element_type=jnp.float32)
     if rules is not None:
         out = constrain(out, rules, "batch", None, "act_heads", None, None)
@@ -413,7 +444,7 @@ def _decode_attend_q8(q, cache, k_valid, *, scale, rules=None):
 
 def _verify_attend_views(q, views, k_valid, *, scale, rules=None):
     """``_decode_attend_q8`` against per-query cache views: leaves are
-    [B,S,T,g,*] — query i sees its OWN snapshot of the ring (slots a later
+    [B,S,g,*,T] — query i sees its OWN snapshot of the ring (slots a later
     window row will overwrite still hold their pre-window content). Same
     einsum contractions, scale ordering and count masking as decode, with
     one extra query-indexed key axis, so each row of the window reproduces
@@ -422,25 +453,25 @@ def _verify_attend_views(q, views, k_valid, *, scale, rules=None):
     b, s, h, d = q.shape
     ki, vi = views["k"], views["v"]
     ks, vs = views.get("ks"), views.get("vs")
-    t, g = ki.shape[2], ki.shape[3]
+    g, t = ki.shape[2], ki.shape[-1]
     rep = h // g
     qg = q.reshape(b, s, g, rep, d)
     if rules is not None:
         qg = constrain(qg, rules, "batch", None, "act_heads", None, None)
-    scores = jnp.einsum("bsgrd,bstgd->bgrst", qg, ki.astype(q.dtype),
+    scores = jnp.einsum("bsgrd,bsgdt->bgsrt", qg, ki.astype(q.dtype),
                         preferred_element_type=jnp.float32) * scale
     if rules is not None:
         scores = constrain(scores, rules, "batch", "act_heads", None, None,
                            None)
     if ks is not None:
-        scores = scores * ks[..., 0].transpose(0, 3, 1, 2)[:, :, None, :, :]
+        scores = scores * ks.transpose(0, 2, 1, 3, 4)       # [B,g,S,1,T]
     mask = jnp.arange(t)[None, None, :] < k_valid[:, :, None]   # [B,S,T]
-    scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
+    scores = jnp.where(mask[:, None, :, None, :], scores, NEG_INF)
     w = jax.nn.softmax(scores, axis=-1)
     wv = w.astype(q.dtype)
     if vs is not None:
-        wv = wv * vs[..., 0].transpose(0, 3, 1, 2)[:, :, None, :, :]
-    out = jnp.einsum("bgrst,bstgv->bsgrv", wv, vi.astype(q.dtype),
+        wv = wv * vs.transpose(0, 2, 1, 3, 4)
+    out = jnp.einsum("bgsrt,bsgvt->bsgrv", wv, vi.astype(q.dtype),
                      preferred_element_type=jnp.float32)
     if rules is not None:
         out = constrain(out, rules, "batch", None, "act_heads", None, None)
@@ -448,22 +479,23 @@ def _verify_attend_views(q, views, k_valid, *, scale, rules=None):
 
 
 def _ring_query_views(ext, j0, n_q: int, t: int):
-    """Per-query ring views from extended leaves [B, t+S, ...]: query i's
-    slot s reads window row ``j0[b,s]`` (appended at t+j0) once that row
-    exists for i (``j0 <= i`` — covering both in-window replacement and
-    window expiry of the slot's old content), else the untouched ring row.
-    Returns leaves [B, n_q, t, ...]."""
+    """Per-query ring views from extended position-minor leaves
+    [B, g, *, t+S]: query i's slot s reads window row ``j0[b,s]``
+    (appended at t+j0) once that row exists for i (``j0 <= i`` — covering
+    both in-window replacement and window expiry of the slot's old
+    content), else the untouched ring row. Returns leaves
+    [B, n_q, g, *, t]."""
     b = j0.shape[0]
     qi = jnp.arange(n_q, dtype=jnp.int32)[None, :, None]         # [1,S,1]
     idx = jnp.where(j0[:, None, :] <= qi, t + j0[:, None, :],
                     jnp.arange(t, dtype=jnp.int32)[None, None, :])  # [B,S,t]
-    flat = idx.reshape(b, n_q * t)
     out = {}
     for kk, leaf in ext.items():
-        rows = jnp.take_along_axis(
-            leaf, flat.reshape((b, n_q * t) + (1,) * (leaf.ndim - 2)),
-            axis=1)
-        out[kk] = rows.reshape((b, n_q, t) + leaf.shape[2:])
+        shape = (b, n_q) + leaf.shape[1:-1]
+        out[kk] = jnp.take_along_axis(
+            jnp.broadcast_to(leaf[:, None], shape + leaf.shape[-1:]),
+            jnp.broadcast_to(idx[:, :, None, None, :], shape + (t,)),
+            axis=-1)
     return out
 
 
@@ -559,21 +591,22 @@ def gqa_apply(p, x, cfg: ModelConfig, *, positions, cache=None, pos=None,
             # a per-query slot-ordered view, so softmax sums in the same
             # key order as the decode steps being replaced.
             assert not paged, "spec verify: paged ring caches unsupported"
-            t = cache["k"].shape[1]
+            t = cache["k"].shape[-1]
             assert s <= t, "verify window must fit the sliding window"
             slot = row_idx % t
             j0 = jnp.mod(jnp.arange(t, dtype=jnp.int32)[None, :]
                          - pos[:, None], t)             # [B,t]
             with _scope("attn"):
                 ext = {kk: jnp.concatenate(
-                    [cache[kk], vv.astype(cache[kk].dtype)], axis=1)
+                    [cache[kk], jnp.moveaxis(vv.astype(cache[kk].dtype),
+                                             1, -1)], axis=-1)
                     for kk, vv in leaves.items()}
                 views = _ring_query_views(ext, j0, s, t)
                 k_valid = jnp.minimum(row_idx + 1, t)
                 attn = _verify_attend_views(q, views, k_valid,
                                             scale=hd ** -0.5, rules=rules)
             with _scope("kv_write"):
-                new_cache = {kk: _scatter_rows_multi(cache[kk], vv, slot)
+                new_cache = {kk: _select_rows(cache[kk], vv, slot)
                              for kk, vv in leaves.items()}
         elif paged:
             t = table.shape[1] * cache["k"].shape[1]
@@ -585,20 +618,20 @@ def gqa_apply(p, x, cfg: ModelConfig, *, positions, cache=None, pos=None,
                                                row_idx, valid)
                              for kk, vv in leaves.items()}
             with _scope("attn"):
-                attend = {kk: paged_view(vv, table)
+                attend = {kk: paged_decode_view(vv, table)
                           for kk, vv in new_cache.items()}
                 attn = _decode_attend_q8(q, attend, row_idx + 1,
                                          scale=hd ** -0.5, rules=rules)
         else:
             with _scope("kv_write"):
-                new_cache = {kk: _scatter_rows_multi(cache[kk], vv, row_idx)
+                new_cache = {kk: _select_rows(cache[kk], vv, row_idx)
                              for kk, vv in leaves.items()}
             with _scope("attn"):
                 attn = _decode_attend_q8(q, new_cache, row_idx + 1,
                                          scale=hd ** -0.5, rules=rules)
     elif s > 1:  # prefill into cache (cold: no history in the cache yet)
-        psz = cache["k"].shape[1]
-        t = table.shape[1] * psz if paged else cache["k"].shape[1]
+        t = (table.shape[1] * cache["k"].shape[1] if paged
+             else cache["k"].shape[-1])
         ln = (jnp.full((b,), s, jnp.int32) if lengths is None
               else as_pos_vector(lengths, b))
         with _scope("kv_write"):
@@ -628,7 +661,8 @@ def gqa_apply(p, x, cfg: ModelConfig, *, positions, cache=None, pos=None,
                              for kk, vv in leaves.items()}
             else:
                 new_cache = {kk: lax.dynamic_update_slice(
-                    cache[kk], vv.astype(cache[kk].dtype),
+                    cache[kk], jnp.moveaxis(vv.astype(cache[kk].dtype),
+                                            1, -1),
                     (0,) * cache[kk].ndim) for kk, vv in leaves.items()}
         with _scope("attn"):
             attn = chunked_attention(q, k, v, causal=True,
@@ -639,7 +673,7 @@ def gqa_apply(p, x, cfg: ModelConfig, *, positions, cache=None, pos=None,
                                      scores_dtype=sdt)
     else:  # decode, S == 1, per-sequence positions
         t = (table.shape[1] * cache["k"].shape[1] if paged
-             else cache["k"].shape[1])
+             else cache["k"].shape[-1])
         pos = as_pos_vector(pos, b)
         if cfg.sliding_window:
             slot = pos % t           # rolling (ring) cache
@@ -661,10 +695,10 @@ def gqa_apply(p, x, cfg: ModelConfig, *, positions, cache=None, pos=None,
                                                slot[:, None])
                              for kk, vv in leaves.items()}
             else:
-                new_cache = {kk: _scatter_rows(cache[kk], vv, slot)
+                new_cache = {kk: _select_rows(cache[kk], vv, slot[:, None])
                              for kk, vv in leaves.items()}
         with _scope("attn"):
-            attend = ({kk: paged_view(vv, table)
+            attend = ({kk: paged_decode_view(vv, table)
                        for kk, vv in new_cache.items()} if paged
                       else new_cache)
             # rolling-cache entries are unordered but all within the

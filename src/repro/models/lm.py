@@ -236,22 +236,35 @@ def _run_layers(params, cfg: ModelConfig, h, *, positions, caches=None,
                 layer_offset=0, table=None, history=False, verify=False):
     """Scan (or unroll, for hybrid) the stacked blocks; returns
     (h, new_caches, aux). ``table`` (paged caches) is shared by every
-    layer, so it rides as a closure capture, not a scan input."""
+    layer, so it rides as a closure capture, not a scan input.
+
+    The stacked caches ride in the scan's carry, with the layer index as
+    the scan input: each layer reads ``stack[l]`` and writes back into
+    the stack at ``l``, so the donated buffer is updated in place (as
+    ``xs``/``ys`` the scan would slice each layer out and restack it
+    into a second whole-cache buffer)."""
     _, bapply = _block_fns(cfg)
     aux = AUX0()
 
     def body(carry, xs):
-        hh, ax = carry
+        hh, ax, stack = carry
         if caches is None:
-            lp = xs
-            lc = None
+            lp, lc = xs, None
         else:
-            lp, lc = xs
+            lp, l = xs
+            lc = jax.tree.map(
+                lambda c: lax.dynamic_index_in_dim(c, l, keepdims=False),
+                stack)
         hh, nc, a2 = bapply(lp, hh, cfg, positions=positions, cache=lc,
                             pos=pos, lengths=lengths, mode=mode, rules=rules,
                             table=table, history=history, verify=verify)
         ax = {k: ax[k] + a2[k] for k in ax}
-        return (hh, ax), (nc if caches is not None else 0)
+        if caches is not None:
+            with jax.named_scope("kv_write"):
+                stack = jax.tree.map(
+                    lambda c, n: lax.dynamic_update_index_in_dim(c, n, l, 0),
+                    stack, nc)
+        return (hh, ax, stack), None
 
     if cfg.remat == "dots":
         body_fn = jax.checkpoint(
@@ -305,11 +318,20 @@ def _run_layers(params, cfg: ModelConfig, h, *, positions, caches=None,
                        "shared": stack(new_caches["shared"])}, aux
         return h, None, aux
 
-    xs = params["layers"] if caches is None else (params["layers"],
-                                                  caches["layers"])
-    (h, aux), ncs = lax.scan(body_fn, (h, aux), xs)
-    new_caches = None if caches is None else {"layers": ncs}
-    return h, new_caches, aux
+    if caches is None:
+        (h, aux, _), _ = lax.scan(body_fn, (h, aux, None), params["layers"])
+        return h, None, aux
+    n = jax.tree.leaves(params["layers"])[0].shape[0]
+    (h, aux, stack), _ = lax.scan(
+        body_fn, (h, aux, caches["layers"]),
+        (params["layers"], jnp.arange(n, dtype=jnp.int32)))
+    if rules is not None:
+        # the carry's placement is left to propagation, which may split a
+        # paged pool otherwise than the donated input: pin it
+        axes = _layer_cache_axes(cfg, paged=table is not None)
+        stack = {k: constrain(v, rules, "layers", *axes[k])
+                 for k, v in stack.items()}
+    return h, {"layers": stack}, aux
 
 
 def _lm_head(params, cfg: ModelConfig, h):
@@ -351,15 +373,24 @@ def forward(params, cfg: ModelConfig, batch, *, mode: str = "float",
 
 # -- caches -------------------------------------------------------------------
 
+def _layer_cache_axes(cfg: ModelConfig, paged: bool = False):
+    """Logical axes of one layer's cache leaves (a scanned block's)."""
+    if cfg.family in ("ssm", "hybrid"):
+        return ssm_mod.MAMBA2_CACHE_AXES
+    if paged:
+        return (attn_mod.MLA_PAGED_CACHE_AXES if cfg.mla
+                else attn_mod.gqa_paged_cache_axes(cfg))
+    return attn_mod.MLA_CACHE_AXES if cfg.mla else attn_mod.gqa_cache_axes(cfg)
+
+
 def _layer_cache_init(cfg: ModelConfig, batch: int, max_seq: int, dtype):
     if cfg.family in ("ssm", "hybrid"):
-        return (ssm_mod.mamba2_cache_init(cfg, batch, dtype),
-                ssm_mod.MAMBA2_CACHE_AXES)
-    if cfg.mla:
-        return (attn_mod.mla_cache_init(cfg, batch, max_seq, dtype),
-                attn_mod.MLA_CACHE_AXES)
-    return (attn_mod.gqa_cache_init(cfg, batch, max_seq, dtype),
-            attn_mod.gqa_cache_axes(cfg))
+        single = ssm_mod.mamba2_cache_init(cfg, batch, dtype)
+    elif cfg.mla:
+        single = attn_mod.mla_cache_init(cfg, batch, max_seq, dtype)
+    else:
+        single = attn_mod.gqa_cache_init(cfg, batch, max_seq, dtype)
+    return single, _layer_cache_axes(cfg)
 
 
 def paged_extent(cfg: ModelConfig, max_seq: int) -> int:
@@ -399,14 +430,10 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
         n_pages = extent // page_size
         if pool_pages is None:
             pool_pages = batch * n_pages
-        if cfg.mla:
-            single = attn_mod.mla_paged_cache_init(cfg, pool_pages,
-                                                   page_size, dtype)
-            axes1 = attn_mod.MLA_PAGED_CACHE_AXES
-        else:
-            single = attn_mod.gqa_paged_cache_init(cfg, pool_pages,
-                                                   page_size, dtype)
-            axes1 = attn_mod.gqa_paged_cache_axes(cfg)
+        init1 = (attn_mod.mla_paged_cache_init if cfg.mla
+                 else attn_mod.gqa_paged_cache_init)
+        single = init1(cfg, pool_pages, page_size, dtype)
+        axes1 = _layer_cache_axes(cfg, paged=True)
     else:
         single, axes1 = _layer_cache_init(cfg, batch, max_seq, dtype)
 
@@ -617,30 +644,28 @@ def rollback_ring_cache(cfg: ModelConfig, prev, cache, start, new_pos,
                         window: int):
     """Undo a ring cache's rejected verify rows.
 
-    A verify over rows ``start + i`` scattered ALL its window's rows into
+    A verify over rows ``start + i`` wrote ALL its window's rows into
     the ring (slot ``(start+i) % t``); rows at positions >= ``new_pos``
     (the accepted end) were rejected, and — unlike linear/paged caches,
     where stale rows sit beyond every mask — their slots must get their
     pre-round content back (``prev``, the cache snapshot from before
     drafting: draft-rung KV writes polluted the same slots). Restores
-    every KV leaf's rejected slots and sets ``pos = new_pos``.
+    the rejected slots of every KV leaf ([L,B,Hkv,*,t], position minor)
+    with one select and sets ``pos = new_pos``.
     """
     start = jnp.asarray(start, jnp.int32)
     new_pos = jnp.asarray(new_pos, jnp.int32)
-    b = start.shape[0]
     s = window
     row = start[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]  # [B,S]
-    bi = jnp.arange(b)[:, None]
+    rejected = row >= new_pos[:, None]
 
     def one(pv, nv):
         if pv.ndim < 4:          # pos [B] / table [B,n] leaves
             return nv
-        t = pv.shape[2]
-        slot = row % t
-        # restore-only-rejected: kept rows route to the OOB slot and drop
-        idx = jnp.where(row < new_pos[:, None], t, slot)
-        rows = pv[:, bi, slot]                       # [L,B,S,...] pre-round
-        return nv.at[:, bi, idx].set(rows, mode="drop")
+        t = pv.shape[-1]
+        back = jnp.any(rejected[:, :, None]
+                       & (row[:, :, None] % t == jnp.arange(t)), axis=1)
+        return jnp.where(back[None, :, None, None, :], pv, nv)   # [B,t]
 
     out = jax.tree.map(one, prev, dict(cache))
     out["pos"] = new_pos

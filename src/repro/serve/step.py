@@ -2,7 +2,8 @@
 
 Generation is *device-resident*: every jitted entry point donates the KV
 cache pytree (``donate_argnums``), so per-step cache writes lower to
-in-place ``dynamic_update_slice``/scatter instead of whole-cache copies —
+in-place selects, update slices or (paged) scatters instead of
+whole-cache copies —
 the data-movement tax the paper's weight-stationary premise (§III) exists
 to avoid, and exactly the invariant tests/test_generate.py asserts on the
 lowered HLO (every cache leaf carries an aliasing attribute). On top of
@@ -108,8 +109,8 @@ def make_decode_step(cfg: ModelConfig, rules: Optional[ShardingRules] = None,
     """(params, tokens, cache) -> (logits, cache), cache donated.
 
     Donation is what makes the per-layer cache update an in-place
-    scatter: without it XLA must copy every [B,T,H,D] cache leaf per
-    layer per token to preserve the (dead) input buffers."""
+    write: without it XLA must copy every cache leaf per layer per
+    token to preserve the (dead) input buffers."""
     if not jit:
         def decode_step(params, tokens, cache):
             return lm.decode_step(params, cfg, tokens, cache, mode=mode,
@@ -564,8 +565,11 @@ def convert_params_for_serving(params, cfg: ModelConfig, *,
     """Replace large projection weights with resident PPAC containers.
 
     Only 2-D weight leaves under eligible projection names are converted
-    (embeddings, norms, SSD internals stay float). Works on stacked
-    (scan) params by vmapping the packer over the layer axis.
+    (norms, SSD internals stay float). Works on stacked (scan) params by
+    vmapping the packer over the layer axis. The embedding table is
+    stored in the compute dtype: both of its readers (the token lookup
+    and the tied head) cast it there first, so the served values are the
+    same, and no step writes a compute-dtype copy of the whole table.
 
     With ``group`` (the default), same-input projection trios/pairs
     (wq/wk/wv -> ``wqkv``, wi/wg -> ``wig``) whose members are ALL
@@ -635,7 +639,11 @@ def convert_params_for_serving(params, cfg: ModelConfig, *,
                 out[k] = {**v, "w": pack_leaf(v["w"])}
         return out
 
-    return walk(params)
+    out = walk(params)
+    if "embed" in out:
+        out["embed"] = {**out["embed"], "table": out["embed"]["table"]
+                        .astype(jnp.dtype(cfg.dtype))}
+    return out
 
 
 # -- tile-plan autotuning ------------------------------------------------------

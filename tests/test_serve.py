@@ -37,8 +37,16 @@ def test_convert_params_replaces_projections():
         served, is_leaf=lambda x: isinstance(x, QuantContainer))
         if isinstance(x := l, QuantContainer)]
     assert len(containers) > 0
-    # embeddings/norms untouched
-    assert served["embed"]["table"].dtype == params["embed"]["table"].dtype
+    # the embedding table held in the compute dtype both its readers cast
+    # it to, with the same values; norms untouched
+    table = params["embed"]["table"]
+    assert served["embed"]["table"].dtype == jnp.dtype(cfg.dtype)
+    assert np.array_equal(np.asarray(served["embed"]["table"]),
+                          np.asarray(table.astype(cfg.dtype)))
+    norm = params["final_norm"]["scale"]
+    assert served["final_norm"]["scale"].dtype == norm.dtype
+    assert np.array_equal(np.asarray(served["final_norm"]["scale"]),
+                          np.asarray(norm))
     # packed4 halves the `in` dim
     c = containers[0]
     assert c.kind == "packed4"

@@ -7,12 +7,16 @@ each kernel compiles with ``interpret=False`` for one chip of a described
 ``v5e:2x2`` topology (no chip attached), at the widths the main path
 serves: smollm-360m's ``full()`` projections at a decode batch and one
 prefill batch, the CAM lookup of the retrieval server's README deployment
-and the coding server's default array code.
+and the coding server's default array code. The serving decode step
+compiles whole at the decode-backlog cell's size, to show that it
+updates the resident KV cache in place.
 
 The topology is described inside a fixture, never at import time: only
 the process that describes it loads the TPU compiler library.
 """
+import dataclasses
 import functools
+import math
 import re
 
 import jax
@@ -32,6 +36,11 @@ from repro.kernels.bitserial_mvp.kernel import (
 from repro.kernels.bitserial_mvp.ops import plane_factors
 from repro.kernels.gf2_tiled.kernel import gf2_matmul_packed
 from repro.kernels.hamming_topk.kernel import hamming_topk_packed
+from repro.models import lm
+from repro.serve.step import (
+    convert_params_for_serving,
+    make_decode_select_step,
+)
 
 _CFG = load_arch("smollm_360m").full()
 _D, _F = _CFG.d_model, _CFG.d_ff                    # 960, 2560
@@ -42,6 +51,7 @@ _PROJ = {"wqkv": (_D, _QKV), "wo": (_CFG.n_heads * _CFG.head_dim, _D),
          "wig": (_D, 2 * _F), "wd": (_F, _D)}
 _DECODE_B, _PREFILL_B = 8, 4 * 256   # 8 slots; 4 prompts x 256 rows
 _SLOTS_B = 64                        # the decode batch of 64 serving slots
+_CACHE_ROWS = 2048                   # and each slot's cache rows
 _K_BITS, _L_BITS = 4, 8              # --weight-bits 4, act_bits 8
 _CAM_M, _CAM_BITS, _CAM_K = 65536, 256, 4
 
@@ -149,3 +159,102 @@ def test_gf2_compiles(one_chip):
     w = packed_width(code.n)
     _compile(jax.jit(gf2_matmul_packed), one_chip, ((64, w), U32),
              ((code.n_chk, w), U32))
+
+
+# an instruction, the type of its result and its opcode
+_INST = re.compile(r"^(?:ROOT )?%[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(")
+_SHAPE = re.compile(r"\b[a-z]+\d+\[([\d,]*)\](\{[^}]*\})?")  # bf16[64,5]{1,0}
+_COMPUTATION = re.compile(r"^(?:ENTRY )?(%[\w.\-]+) .*\{$")
+# opcodes that move data: anywhere, never on a whole layer's cache leaf
+_MOVES = ("copy", "copy-start", "copy-done", "scatter", "transpose")
+# opcodes of an unfused instruction that pass a buffer on without moving it
+_PASS = ("parameter", "get-tuple-element", "tuple", "bitcast", "while",
+         "conditional", "call")
+
+
+def _holds_cache_layer(dims: str, layer) -> bool:
+    """Whether a result shape holds a whole layer's cache leaf: every
+    one of its dims, in any order (a relayout, a restack)."""
+    have = [int(d) for d in dims.split(",") if d]
+    for d in layer:
+        if d not in have:
+            return False
+        have.remove(d)
+    return True
+
+
+def _instructions(text: str):
+    """(unfused, result, opcode, line) of every instruction of an
+    optimized program; an instruction is fused when its computation is
+    a fusion's body."""
+    fused = set(re.findall(r" fusion\(.*?calls=(%[\w.\-]+)", text))
+    comp = None
+    for ln in text.splitlines():
+        if (c := _COMPUTATION.match(ln)):
+            comp = c.group(1)
+        elif (m := _INST.match(ln.strip())):
+            yield comp not in fused, m.group(1), m.group(2), ln.strip()
+
+
+def _cache_moves(text: str, layer, stack: str, resident: str):
+    """Instructions that copy, transpose or scatter a whole layer's cache
+    leaf (in any layout, fused or not), and unfused instructions that
+    make a buffer holding one other than by passing a buffer on or by
+    writing the ``stack`` in place in its ``resident`` layout."""
+    out = []
+    for unfused, result, op, ln in _instructions(text):
+        held = [(dims, lay) for dims, lay in _SHAPE.findall(result)
+                if _holds_cache_layer(dims, layer)]
+        if not held or (unfused and op in _PASS):
+            continue
+        in_place = (op in ("fusion", "dynamic-update-slice")
+                    and all(h == (stack, resident) for h in held))
+        if op in _MOVES or (unfused and not in_place):
+            out.append(ln[:160])
+    return out
+
+
+def test_decode_step_updates_cache_in_place(one_chip):
+    """The serving decode step for smollm-360m at its published widths,
+    64 slots of 2048 rows, a bf16 cache and 4-bit served projections
+    reads and writes the donated cache where it lies: temporaries under
+    one layer's K leaf; no copy, transpose or scatter of a layer's cache
+    leaf (in any layout, fused or not) anywhere in the optimized
+    program, and no unfused instruction that makes a buffer holding one
+    but the in-place write of the stack in its resident layout; every
+    cache argument aliased to an output."""
+    cfg = dataclasses.replace(
+        _CFG, dtype="bfloat16", kv_dtype="bfloat16",
+        ppac=dataclasses.replace(_CFG.ppac, enabled=True,
+                                 weight_bits=_K_BITS, act_bits=_L_BITS,
+                                 weight_format="int", act_format="int",
+                                 min_features=32, backend="pallas"))
+    params = jax.eval_shape(lambda k: convert_params_for_serving(
+        lm.init(cfg, k)[0], cfg), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(
+        lambda: lm.init_cache(cfg, _SLOTS_B, _CACHE_ROWS)[0])
+    on_chip = functools.partial(jax.tree.map, lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=one_chip))
+    compiled = make_decode_select_step(cfg, mode="serve").lower(
+        on_chip(params), *on_chip((
+            jax.ShapeDtypeStruct((_SLOTS_B, 1), I32), cache,
+            jax.ShapeDtypeStruct((2,), U32)))).compile()
+    k = cache["layers"]["k"]
+    layer_bytes = math.prod(k.shape[1:]) * k.dtype.itemsize   # 83.9 MB
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < layer_bytes, (temp, layer_bytes)
+    text = compiled.as_text()
+    entry = next(ln for ln in text.splitlines() if ln.startswith("ENTRY"))
+    stack = ",".join(map(str, k.shape))
+    resident = {lay for _, res, op, _ in _instructions(text)
+                if op == "parameter" for dims, lay in _SHAPE.findall(res)
+                if dims == stack}
+    assert len(resident) == 1, resident
+    moves = _cache_moves(text, k.shape[1:], stack, resident.pop())
+    assert not moves, moves
+    aliased = {int(i) for i in re.findall(
+        r"\{[\d,]*\}: \((\d+),", text.split("\n", 1)[0])}
+    names = re.findall(r"[(,] ?([\w.]+): ", entry)
+    cached = [i for i, n in enumerate(names) if n.startswith("cache")]
+    assert len(cached) == len(jax.tree.leaves(cache)), names
+    assert set(cached) <= aliased, (cached, aliased)
